@@ -8,6 +8,40 @@ backend, device kind and interpret-mode flag ride with the data.
 
 from __future__ import annotations
 
+import os
+
+#: the checkout root (this file lives in ``<root>/benchmarks/``)
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing else is set.  Otherwise the cache lives in ``.jax_cache/``
+    at the checkout root: a fixed path, so a later process in the same
+    checkout finds what an earlier one compiled.  Entry points call
+    this at start-up, before their first compile; importing the library
+    never does.
+    """
+    import jax
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def pallas_interpret() -> bool:
+    """Whether Pallas kernels must run in interpret mode here: only on a
+    CPU backend.  On a TPU they compile, and a kernel the compiler
+    refuses fails loudly instead of falling back to the interpreter."""
+    import jax
+
+    return jax.default_backend() == "cpu"
+
 
 def bench_env(interpret: bool = False) -> dict:
     """Backend/platform facts for a bench record (cheap, no device

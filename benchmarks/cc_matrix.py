@@ -24,12 +24,16 @@ def run_matrix(quick: bool = False,
     """Execute the registry product; returns the BENCH record.
 
     ``use_kernels="mega"`` runs the same matrix through the whole-step
-    megakernel (interpret mode off-TPU): the stage codes are traced
+    megakernel (interpret mode on a CPU backend only): the stage codes are traced
     data *inside* the kernel, so the full combination product must
     still resolve to exactly one executable build.
     """
     from repro.core import CCSpec, ScenarioSpec, Sweep, cc
     from repro.core.experiments import SWEEP_EXEC_CACHE
+    try:
+        from ._env import bench_env, pallas_interpret
+    except ImportError:              # `python benchmarks/cc_matrix.py`
+        from _env import bench_env, pallas_interpret
 
     from repro.core import DCQCNParams, SimParams
 
@@ -53,11 +57,11 @@ def run_matrix(quick: bool = False,
     scn = ScenarioSpec.paper_incast(roll=0, t_start=0.1e-3,
                                     label="hol")
     n_steps = (N_STEPS_QUICK if quick else N_STEPS) * 4
+    interpret = bool(use_kernels) and pallas_interpret()
     misses0 = SWEEP_EXEC_CACHE.stats().misses
     t0 = time.perf_counter()
     res = Sweep.grid(configs=configs, scenarios={"hol": scn}).run(
-        n_steps=n_steps, use_kernels=use_kernels,
-        interpret=bool(use_kernels))
+        n_steps=n_steps, use_kernels=use_kernels, interpret=interpret)
     wall = time.perf_counter() - t0
     compiles = SWEEP_EXEC_CACHE.stats().misses - misses0
     points = []
@@ -70,13 +74,9 @@ def run_matrix(quick: bool = False,
             "marks": row["marks"],
             "cnps": row["cnps"],
         })
-    try:
-        from ._env import bench_env
-    except ImportError:              # `python benchmarks/cc_matrix.py`
-        from _env import bench_env
     return {
         "unix_time": int(time.time()),
-        **bench_env(interpret=bool(use_kernels)),
+        **bench_env(interpret=interpret),
         "quick": quick,
         "use_kernels": str(use_kernels),
         "n_steps": n_steps,

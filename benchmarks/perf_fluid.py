@@ -7,7 +7,7 @@ F/L scaling curve of single-device grid points:
     (``scat`` = legacy scatter baseline, ``fused`` = sorted-incidence
     one-pass reduction with the dense-CSR tiles when load skew allows)
     plus the ``mega`` whole-step kernel (one launch per trace window,
-    interpret mode on CPU)
+    interpret mode on a CPU backend only)
   * compile seconds per engine (first call minus steady state)
   * incidence shape per point (F, L, K, H, rows = N = F*K*H,
     ``dense_rows`` = max per-link contributors)
@@ -88,6 +88,15 @@ def _grid(quick: bool):
     return points
 
 
+def _interpret() -> bool:
+    """Interpret mode for the mega cells: on a CPU backend only."""
+    try:
+        from ._env import pallas_interpret
+    except ImportError:              # `python benchmarks/perf_fluid.py`
+        from _env import pallas_interpret
+    return pallas_interpret()
+
+
 def _bench_point(spec, n_steps: int, engine: str) -> dict:
     import jax
     from repro.core import PAPER_CONFIG
@@ -99,7 +108,7 @@ def _bench_point(spec, n_steps: int, engine: str) -> dict:
     st0 = init_state(scn, cfg)
     k = 10
     if engine == "mega":
-        block = make_block_fn(scn, cfg, k, interpret=True)
+        block = make_block_fn(scn, cfg, k, interpret=_interpret())
         fn = jax.jit(lambda st: decimating_scan(
             None, st, n_steps // k, k, cfg.sim.dt, block_fn=block))
     else:
@@ -137,7 +146,7 @@ def _ops_per_step(spec, k: int = 10) -> dict:
     st0 = init_state(scn, cfg)
     step = make_step_fn(scn, cfg)
     ref_eqns = len(jax.make_jaxpr(step)(st0).eqns)
-    block = make_block_fn(scn, cfg, k, interpret=True)
+    block = make_block_fn(scn, cfg, k, interpret=_interpret())
     blk_eqns = len(jax.make_jaxpr(block)(st0).eqns)
     return {"ref": ref_eqns, "mega_block": blk_eqns,
             "mega": round(blk_eqns / k, 2),
@@ -188,10 +197,8 @@ def run_perf(quick: bool = False) -> dict:
         from _env import bench_env
     return {
         "unix_time": int(time.time()),
-        # mega cells run the Pallas interpreter off-TPU (noted in their
-        # sub-records); the scat/fused cells this record gates on are
-        # compiled, so the top-level flag reflects those.
-        **bench_env(interpret=False),
+        # the mega cells' mode; the scat/fused cells never interpret
+        **bench_env(interpret=_interpret()),
         "quick": quick,
         "points": points,
     }

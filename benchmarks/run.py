@@ -54,13 +54,18 @@ from __future__ import annotations
 
 import argparse
 import time
+import traceback
 
 
 def _section(name: str, fn):
+    """Run one section; a failure becomes a ``<name>.ERROR`` row (with
+    its traceback on stderr) so later sections still run, and the
+    caller exits non-zero on it."""
     t0 = time.perf_counter()
     try:
         rows = fn()
-    except Exception as e:   # noqa: BLE001 — a bench must not kill the run
+    except Exception as e:   # noqa: BLE001 — reported, then exit != 0
+        traceback.print_exc()
         rows = [(f"{name}.ERROR", 0.0, repr(e)[:120])]
     dt = time.perf_counter() - t0
     rows.append((f"{name}.section_wall_s", dt * 1e6, f"{dt:.1f}s"))
@@ -173,6 +178,11 @@ def main() -> None:
                     help="with --scale/--perf/--cc-matrix/--serve/"
                          "--tune/--fleet: CI-sized run")
     args = ap.parse_args()
+    if __package__:
+        from ._env import use_compile_cache
+    else:                    # `python benchmarks/run.py` (no package ctx)
+        from _env import use_compile_cache
+    use_compile_cache()
     if args.smoke:
         raise SystemExit(smoke())
 
@@ -251,6 +261,8 @@ def main() -> None:
     all_rows += _section("cosim", cosim.main)
     all_rows += _section("train", bench_train_step)
     _print_rows(all_rows)
+    if any(".ERROR" in r[0] for r in all_rows):
+        raise SystemExit(1)
 
 
 if __name__ == "__main__":

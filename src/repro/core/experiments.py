@@ -575,15 +575,14 @@ def _sweep_scan_fn(n_samples: int, trace_every: int, dt: float,
 
     if mesh is None:
         return scan_fn
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     run_spec = P(tuple(mesh.axis_names))     # leading run axis sharded
-    return shard_map(
+    return jax.shard_map(
         scan_fn, mesh=mesh,
         in_specs=(run_spec, run_spec, run_spec),
         # decimating_scan returns (final [R, ...], traces [T, R, ...])
         out_specs=(run_spec, P(None, *run_spec)),
-        check_rep=False)
+        check_vma=False)
 
 
 def _sweep_executable(static: tuple, args: tuple):

@@ -8,7 +8,6 @@ Public surface:
   * pipeline: pipeline_apply
 """
 
-from . import _compat  # noqa: F401  (installs jax API shims; must be first)
 from .sharding import (DEFAULT_RULES, logical_sharding, pspec, shard,
                        sweep_mesh)
 

@@ -80,12 +80,9 @@ def logical_sharding(dims, shape, mesh) -> NamedSharding:
 
 
 def _ambient_mesh():
-    try:
-        from jax.interpreters import pxla
-        m = pxla.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:  # noqa: BLE001 — any jax-internal drift => no mesh
-        return None
+    """The mesh of the enclosing ``jax.set_mesh`` scope, or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def sweep_mesh(n_devices: int | None = None, axis: str = "run"):
@@ -108,11 +105,15 @@ def shard(x, *dims):
     """Constrain ``x``'s sharding by logical dims under the ambient mesh.
 
     Inside a ``with jax.set_mesh(mesh):`` scope this lowers to
-    with_sharding_constraint; with no mesh (unit tests, single device)
-    it is the identity, so model code can call it unconditionally.
+    with_sharding_constraint, or to ``reshard`` on a mesh whose axes
+    are all Explicit (where a constraint may only assert); with no mesh
+    (unit tests, single device) it is the identity, so model code can
+    call it unconditionally.
     """
     mesh = _ambient_mesh()
     if mesh is None:
         return x
-    return jax.lax.with_sharding_constraint(
-        x, logical_sharding(dims, x.shape, mesh))
+    spec = pspec(dims, x.shape, DEFAULT_RULES, mesh)
+    if all(t == jax.sharding.AxisType.Explicit for t in mesh.axis_types):
+        return jax.sharding.reshard(x, spec)
+    return jax.lax.with_sharding_constraint(x, spec)

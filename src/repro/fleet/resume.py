@@ -60,19 +60,23 @@ class FleetJournal:
         path = os.path.join(self.directory, "plan.json")
         doc = {"digest": plan.digest,
                "shards": [s.digest for s in plan.shards]}
-        if os.path.exists(path):
-            with open(path) as f:
-                have = json.load(f)
-            if have["digest"] != plan.digest:
-                raise ValueError(
-                    f"journal {self.directory} is bound to plan "
-                    f"{have['digest'][:16]}…, not {plan.digest[:16]}… — "
-                    f"refusing to mix results of different plans")
-        else:
-            tmp = path + ".tmp"
+        if not os.path.exists(path):
+            # per-process temp name: processes of one fleet bind the
+            # same journal concurrently, and a shared name lets one
+            # process's replace consume the other's temp file
+            tmp = f"{path}.{os.getpid()}.tmp"
             with open(tmp, "w") as f:
                 json.dump(doc, f, indent=1)
             os.replace(tmp, path)
+        # read back even after writing: a concurrent binder's plan may
+        # have landed last
+        with open(path) as f:
+            have = json.load(f)
+        if have["digest"] != plan.digest:
+            raise ValueError(
+                f"journal {self.directory} is bound to plan "
+                f"{have['digest'][:16]}…, not {plan.digest[:16]}… — "
+                f"refusing to mix results of different plans")
         self._plan_digest = plan.digest
 
     # -- completion ---------------------------------------------------------

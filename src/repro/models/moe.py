@@ -141,7 +141,7 @@ def _dispatch_sort(p: dict, cfg: ModelConfig, x, gate_w, gate_idx,
         # xr [t, d]; widx/wval [t, k]
         flat_e = widx.reshape(tk)                    # expert of each pair
         flat_w = wval.reshape(tk)
-        flat_tok = jnp.repeat(jnp.arange(t), k)
+        flat_tok = jnp.broadcast_to(jnp.arange(t)[:, None], (t, k)).reshape(tk)
         order = jnp.argsort(flat_e, stable=True)     # token-order stable
         se, stok, sw = flat_e[order], flat_tok[order], flat_w[order]
         # rank within expert segment = running index - segment start

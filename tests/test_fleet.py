@@ -378,6 +378,9 @@ def _child_env() -> dict:
         "src")
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (src, env.get("PYTHONPATH")) if p)
+    # the parent may hold an accelerator; the children must never reach
+    # for one
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 

@@ -16,18 +16,14 @@ one VC and at several:
 
 Each sampled point runs the full 36-combo product as ONE Sweep launch
 (the stage registry is traced data), so the harness scales by
-scenarios, not by configs.  Runs under hypothesis when available, else
-the deterministic fallback sweep (tests/_hypothesis_fallback.py).
+scenarios, not by configs.
 """
 
 import jax
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # image without hypothesis: deterministic sweep
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import CCSpec, Sweep, cc
 from repro.core.fluid import init_state, make_step_fn

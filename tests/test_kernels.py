@@ -5,10 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # image without hypothesis: deterministic sweep
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ref
 from repro.kernels.cc_step import erp_step, gen_np_step, rp_step

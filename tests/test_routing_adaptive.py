@@ -5,10 +5,7 @@ one Sweep launch."""
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # image without hypothesis: deterministic sweep
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import CCScheme, PAPER_CONFIG, ScenarioSpec, Sweep, run
 from repro.core.workloads import group_shift

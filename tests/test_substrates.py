@@ -9,10 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # image without hypothesis: deterministic sweep
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.ckpt import (CheckpointManager, latest_step, load_checkpoint,
                         save_checkpoint)
