@@ -13,6 +13,10 @@ import os
 #: the checkout root (this file lives in ``<root>/benchmarks/``)
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+#: files the persistent cache held when :func:`use_compile_cache` ran
+#: (None before it ran): 0 means this process compiled from scratch
+_CACHE_ENTRIES_AT_START: int | None = None
+
 
 def use_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache; returns its directory.
@@ -22,15 +26,19 @@ def use_compile_cache() -> str:
     at the checkout root: a fixed path, so a later process in the same
     checkout finds what an earlier one compiled.  Entry points call
     this at start-up, before their first compile; importing the library
-    never does.
+    never does.  How many entries the cache already held is kept for
+    :func:`bench_env`: a compile time read from a warm cache is a
+    cache load, not a compile.
     """
     import jax
 
-    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if env:
-        return env
-    path = os.path.join(ROOT, ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", path)
+    global _CACHE_ENTRIES_AT_START
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    _CACHE_ENTRIES_AT_START = (len(os.listdir(path))
+                               if os.path.isdir(path) else 0)
     return path
 
 
@@ -45,7 +53,13 @@ def pallas_interpret() -> bool:
 
 def bench_env(interpret: bool = False) -> dict:
     """Backend/platform facts for a bench record (cheap, no device
-    work beyond enumerating what jax already initialised)."""
+    work beyond enumerating what jax already initialised).
+
+    ``compile_cache`` is the persistent cache directory in force (None
+    when off) and ``compile_cache_entries_at_start`` what it held when
+    the entry point turned it on (None: not turned on through
+    :func:`use_compile_cache`); compile seconds in a record with a warm
+    cache measure cache loads."""
     import jax
 
     devs = jax.devices()
@@ -56,4 +70,8 @@ def bench_env(interpret: bool = False) -> dict:
         "n_devices": len(devs),
         "jax_version": jax.__version__,
         "interpret": bool(interpret),
+        "compile_cache": (jax.config.jax_compilation_cache_dir
+                          if jax.config.jax_enable_compilation_cache
+                          else None) or None,
+        "compile_cache_entries_at_start": _CACHE_ENTRIES_AT_START,
     }

@@ -22,8 +22,11 @@ One-chip phases, in order:
               launch: the §II orderings, the ``reduce="scat"`` engine
               against the default, and the frozen CPU golden grids
   real_size   a 1000-host fat tree under a 64-host all-to-all (4032
-              flows) x the three schemes in one launch: host build,
-              compile and run seconds, peak device memory, sanity
+              flows) mixed with a 256-to-8 incast storm (256 flows)
+              x the three schemes in one launch: host build, compile,
+              and a warm launch split into host staging, execution and
+              fetch; peak device memory, sanity, and the CC counters
+              of each scheme (the storm must engage the loop)
   kernels     ``reduce="pallas"`` and the flow-kernel tier on that grid
               against the default engine
   whatif      8 queries over 2 shape signatures through
@@ -54,7 +57,9 @@ import numpy as np  # noqa: E402
 from benchmarks._env import pallas_interpret, use_compile_cache  # noqa: E402
 from repro.core import (SWEEP_EXEC_CACHE, CCSpec, ScenarioSpec,  # noqa: E402
                         Sweep, config_grid)
-from repro.core.workloads import all_to_all, hol_victim_incast  # noqa: E402
+from repro.core.experiments import _sweep_executable  # noqa: E402
+from repro.core.workloads import (all_to_all, concat,  # noqa: E402
+                                  hol_victim_incast, incast_storm)
 from repro.dist import sweep_mesh  # noqa: E402
 from repro.fleet import FleetConfig, run_fleet  # noqa: E402
 from repro.net import FabricSpec  # noqa: E402
@@ -73,16 +78,21 @@ RTOL = 2e-3
 
 #: the real-size grid: a 1000-host 3-level fat tree (10-ary), with an
 #: all-to-all among 64 hosts spread over it (4 phases, 1 MB per pair)
+#: and a 32:1 incast storm from 0.5 to 2.5 ms.  The all-to-all alone
+#: shares every NIC fairly and never builds a queue; the storm is what
+#: makes the three schemes mark, notify and pause differently.
 REAL_FABRIC = FabricSpec.fat_tree(10)
 REAL_HOSTS = 1000
 REAL_GROUP = 64
+REAL_STORM = (256, 8)
 REAL_STEPS = 3000
 
 #: DCQCN marking thresholds of the four-chip grid (kmin <= kmax = 15 KiB)
 KMINS = (3840.0, 7680.0, 11520.0, 15360.0)
 #: the four-chip grid runs fewer steps: its one-device reference holds
-#: all 12 runs on one chip
-FOUR_CHIP_STEPS = 1000
+#: all 12 runs on one chip.  The storm opens at step 500, so the last
+#: 200 steps are where the runs part ways.
+FOUR_CHIP_STEPS = 700
 
 WHATIF_STEPS = 2000
 
@@ -252,7 +262,9 @@ def real_size_sweep(configs: dict) -> tuple[Sweep, float]:
     step, so the routed tensors are the same for all."""
     t0 = time.perf_counter()
     nodes = [i * REAL_HOSTS // REAL_GROUP for i in range(REAL_GROUP)]
-    wl = all_to_all(REAL_HOSTS, 1e6, phases=4, nodes=nodes)
+    wl = concat(all_to_all(REAL_HOSTS, 1e6, phases=4, nodes=nodes),
+                incast_storm(*REAL_STORM, REAL_HOSTS, t_start=0.5e-3,
+                             t_stop=2.5e-3))
     scn = wl.spec(fabric=REAL_FABRIC).build(next(iter(configs.values())))
     sweep = Sweep.grid(configs, {"a2a": scn})
     return sweep, time.perf_counter() - t0
@@ -267,6 +279,23 @@ def _timed_run(sweep: Sweep, **kw):
     return res, (SWEEP_EXEC_CACHE.stats() - before).build_s, wall
 
 
+def _split_launch(sweep: Sweep, n_steps: int):
+    """One warm launch of ``sweep`` in the three parts ``Sweep.run``
+    chains: host staging (stack, pad, initial state), execution until
+    the device is done, and the fetch of traces and final state.
+    Returns the final state and the traces ([T, R, ...]) as host
+    arrays, and the seconds of each part."""
+    t0 = time.perf_counter()
+    static, args, _, _ = sweep._prepare(n_steps)
+    jax.block_until_ready(args)
+    t1 = time.perf_counter()
+    out = jax.block_until_ready(_sweep_executable(static, args)(*args))
+    t2 = time.perf_counter()
+    final, traces = jax.device_get(out)
+    t3 = time.perf_counter()
+    return final, traces, (t1 - t0, t2 - t1, t3 - t2)
+
+
 def phase_real_size():
     p = "real_size"
     sweep, build_s = real_size_sweep(SCHEMES)
@@ -275,26 +304,51 @@ def phase_real_size():
         f"flows={scn.routes.shape[0]} links={scn.capacity.shape[0]} "
         f"runs={len(sweep.points)} steps={REAL_STEPS}")
     res, compile_s, cold_s = _timed_run(sweep, n_steps=REAL_STEPS)
-    warm, _, run_s = _timed_run(sweep, n_steps=REAL_STEPS)
+    final, traces, (prep_s, exec_s, fetch_s) = _split_launch(
+        sweep, REAL_STEPS)
     peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
     say(p, f"smoke timing, not a benchmark: host_build_s={build_s!r} "
-        f"compile_s={compile_s!r} run_s={run_s!r} (first launch "
-        f"{cold_s!r} s incl. compile) peak_bytes_in_use={peak}")
-    check(bitwise(res, warm), "a repeated launch is bitwise identical")
+        f"compile_s={compile_s!r} first_launch_s={cold_s!r} (incl. "
+        f"compile); warm launch: host_prepare_s={prep_s!r} "
+        f"execute_s={exec_s!r} fetch_s={fetch_s!r} "
+        f"peak_bytes_in_use={peak}")
+    again = [np.asarray(x) for x in jax.tree.leaves(final)] + [
+        np.moveaxis(np.asarray(x), 0, 1) for x in jax.tree.leaves(traces)]
+    first = [np.asarray(x) for x in
+             (*jax.tree.leaves(res.final), *jax.tree.leaves(res.traces))]
+    check(len(again) == len(first) and all(
+        a.tobytes() == b.tobytes() for a, b in zip(again, first)),
+        "a repeated launch is bitwise identical")
     arrays = _arrays(res)
     check(all(np.isfinite(a).all() for a in arrays
               if np.issubdtype(a.dtype, np.floating)),
           "every trace and final-state value is finite")
     fin = res.final
-    check(bool((np.asarray(fin.delivered) <= np.asarray(fin.offered)).all()),
-          "delivered <= offered for every flow")
+    # both are f32 running sums of per-step increments, so a finished
+    # flow's two totals may differ by their rounding: at most n_steps
+    # half-ulps of the total for a sequential sum
+    delivered = np.asarray(fin.delivered, np.float64)
+    offered = np.asarray(fin.offered, np.float64)
+    excess = float(np.max((delivered - offered) / np.maximum(offered, 1.0)))
+    bound = REAL_STEPS * float(np.finfo(np.float32).eps) / 2
+    say(p, f"largest (delivered - offered) / offered over all flows: "
+        f"{excess!r} (f32 rounding bound {bound!r})")
+    check(excess <= bound, "delivered <= offered for every flow, up to "
+          "the rounding of their f32 sums")
     check(min(float(np.asarray(fin.qh).min()),
               float(np.asarray(fin.nicq).min()),
               float(np.asarray(res.traces.max_q).min())) >= 0.0,
           "no queue ever negative")
-    s = res.summary()
-    say(p, "delivered_mb " + " ".join(
-        f"{k}={v['delivered_mb']!r}" for k, v in s.items()))
+    s = {k.split("/")[0]: v for k, v in res.summary().items()}
+    for k, v in s.items():
+        say(p, f"{k}: " + " ".join(
+            f"{f}={v[f]!r}" for f in ("delivered_mb", "marks", "cnps",
+                                      "pause_s", "peak_queue_kb")))
+    check(all(v["marks"] > 0 and v["cnps"] > 0 for v in s.values()),
+          "the storm is marked and notified under every scheme")
+    check(s["PFC_ONLY"]["pause_s"] > s["DCQCN"]["pause_s"]
+          > s["DCQCN_REV"]["pause_s"],
+          "pause time PFC_ONLY > DCQCN > DCQCN_REV")
     return sweep, res
 
 
@@ -379,9 +433,14 @@ def phase_four_chips() -> None:
     t_single = time.perf_counter() - t0
     say(p, f"smoke timing, not a benchmark: sharded_s={t_sharded!r} "
         f"single_device_s={t_single!r} (both incl. compile)")
+    # runs that all came out alike would hide a shard landing in the
+    # wrong place
+    distinct = len({np.asarray(r).tobytes() for r in single.final.delivered})
+    say(p, f"{distinct} distinct per-flow delivered vectors over "
+        f"{len(sweep.points)} runs")
+    check(distinct > 1, "the runs differ from each other")
     # the device arrays Sweep.run pulls to the host: run the executable
     # it resolved once more and look at where the output lives
-    from repro.core.experiments import _sweep_executable
     static, args, _, _ = sweep._prepare(FOUR_CHIP_STEPS, mesh=mesh)
     _, traces = _sweep_executable(static, args)(*args)
     out = traces.delivered

@@ -9,8 +9,8 @@ runs on 1 CPU device and on the 512-way production mesh.
 
   * ``pspec(dims, shape, rules, mesh)``  -> PartitionSpec
   * ``logical_sharding(dims, shape, mesh)`` -> NamedSharding
-  * ``shard(x, *dims)``  -> with_sharding_constraint under the ambient
-    mesh (no-op outside any mesh, e.g. single-device tests)
+  * ``shard(x, *dims)``  -> ``reshard`` under the ambient mesh (no-op
+    outside any mesh, e.g. single-device tests)
 """
 
 from __future__ import annotations
@@ -104,16 +104,12 @@ def sweep_mesh(n_devices: int | None = None, axis: str = "run"):
 def shard(x, *dims):
     """Constrain ``x``'s sharding by logical dims under the ambient mesh.
 
-    Inside a ``with jax.set_mesh(mesh):`` scope this lowers to
-    with_sharding_constraint, or to ``reshard`` on a mesh whose axes
-    are all Explicit (where a constraint may only assert); with no mesh
+    Inside a ``with jax.set_mesh(mesh):`` scope (``jax.make_mesh``
+    meshes, whose axes are Explicit) this is ``reshard``; with no mesh
     (unit tests, single device) it is the identity, so model code can
     call it unconditionally.
     """
     mesh = _ambient_mesh()
     if mesh is None:
         return x
-    spec = pspec(dims, x.shape, DEFAULT_RULES, mesh)
-    if all(t == jax.sharding.AxisType.Explicit for t in mesh.axis_types):
-        return jax.sharding.reshard(x, spec)
-    return jax.lax.with_sharding_constraint(x, spec)
+    return jax.sharding.reshard(x, pspec(dims, x.shape, DEFAULT_RULES, mesh))

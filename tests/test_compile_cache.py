@@ -16,11 +16,14 @@ _CHILD = """
 import sys
 sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp
-from benchmarks._env import use_compile_cache
+from benchmarks._env import bench_env, use_compile_cache
 print("RETURNED", use_compile_cache())
 if len(sys.argv) > 2:
     jax.jit(lambda x: jnp.sin(x) * 2 + 1)(jnp.ones(8)).block_until_ready()
 print("CONFIG", jax.config.jax_compilation_cache_dir)
+env = bench_env()
+print("STAMPED", env["compile_cache"])
+print("WARM", env["compile_cache_entries_at_start"] > 0)
 """
 
 
@@ -35,14 +38,17 @@ def _run(env: dict, *extra: str) -> dict:
 
 def test_cache_dir_from_environment_only(tmp_path):
     """With the variable set, the helper sets nothing and the compiled
-    program lands in that directory."""
+    program lands in that directory; bench records stamp the directory
+    and whether it was warm when the process started."""
     cache = str(tmp_path / "cache")
     env = {**os.environ, "JAX_COMPILATION_CACHE_DIR": cache,
            "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
            "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "0"}
     got = _run(env, "compile")
-    assert got == {"RETURNED": cache, "CONFIG": cache}
+    assert got == {"RETURNED": cache, "CONFIG": cache, "STAMPED": cache,
+                   "WARM": "False"}
     assert os.listdir(cache), "no cache entry was written"
+    assert _run(env)["WARM"] == "True"
 
 
 def test_cache_dir_defaults_to_checkout():
@@ -50,4 +56,5 @@ def test_cache_dir_defaults_to_checkout():
     env = {k: v for k, v in os.environ.items()
            if k != "JAX_COMPILATION_CACHE_DIR"}
     want = os.path.join(ROOT, ".jax_cache")
-    assert _run(env) == {"RETURNED": want, "CONFIG": want}
+    got = _run(env)
+    assert (got["RETURNED"], got["CONFIG"], got["STAMPED"]) == (want,) * 3

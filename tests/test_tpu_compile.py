@@ -30,16 +30,15 @@ TRACE_EVERY = 10
 def one_chip():
     """One chip of a described v5e:2x2, with the persistent compile
     cache off: entries written for a described chip cannot be read
-    back without one."""
+    back without one.  Skips only where the TPU compiler (libtpu) is
+    not installed; any other failure to describe the chip fails."""
+    pytest.importorskip("libtpu")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")
-        try:
-            topo = topologies.get_topology_desc(platform="tpu",
-                                                topology_name="v5e:2x2")
-        except Exception as e:  # noqa: BLE001 — any failure means skip
-            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
