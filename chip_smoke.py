@@ -56,7 +56,7 @@ import numpy as np  # noqa: E402
 
 from benchmarks._env import pallas_interpret, use_compile_cache  # noqa: E402
 from repro.core import (SWEEP_EXEC_CACHE, CCSpec, ScenarioSpec,  # noqa: E402
-                        Sweep, config_grid)
+                        Sweep, config_grid, obs)
 from repro.core.experiments import _sweep_executable  # noqa: E402
 from repro.core.workloads import (all_to_all, concat,  # noqa: E402
                                   hol_victim_incast, incast_storm)
@@ -280,20 +280,16 @@ def _timed_run(sweep: Sweep, **kw):
 
 
 def _split_launch(sweep: Sweep, n_steps: int):
-    """One warm launch of ``sweep`` in the three parts ``Sweep.run``
-    chains: host staging (stack, pad, initial state), execution until
-    the device is done, and the fetch of traces and final state.
-    Returns the final state and the traces ([T, R, ...]) as host
-    arrays, and the seconds of each part."""
-    t0 = time.perf_counter()
-    static, args, _, _ = sweep._prepare(n_steps)
-    jax.block_until_ready(args)
-    t1 = time.perf_counter()
-    out = jax.block_until_ready(_sweep_executable(static, args)(*args))
-    t2 = time.perf_counter()
-    final, traces = jax.device_get(out)
-    t3 = time.perf_counter()
-    return final, traces, (t1 - t0, t2 - t1, t3 - t2)
+    """One warm launch of ``sweep`` and the seconds of the three parts
+    ``Sweep.run`` chains, read from its spans: host staging (stack, pad,
+    initial state; device work it queued finishes in the next part),
+    execution until the device is done, and the fetch of traces and
+    final state."""
+    before = obs.stats()
+    res = sweep.run(n_steps=n_steps)
+    spans = obs.stats() - before
+    return res, tuple(spans.span(f"repro.sweep.{part}").s
+                      for part in ("stage", "execute", "fetch"))
 
 
 def phase_real_size():
@@ -304,21 +300,14 @@ def phase_real_size():
         f"flows={scn.routes.shape[0]} links={scn.capacity.shape[0]} "
         f"runs={len(sweep.points)} steps={REAL_STEPS}")
     res, compile_s, cold_s = _timed_run(sweep, n_steps=REAL_STEPS)
-    final, traces, (prep_s, exec_s, fetch_s) = _split_launch(
-        sweep, REAL_STEPS)
+    again, (prep_s, exec_s, fetch_s) = _split_launch(sweep, REAL_STEPS)
     peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
     say(p, f"smoke timing, not a benchmark: host_build_s={build_s!r} "
         f"compile_s={compile_s!r} first_launch_s={cold_s!r} (incl. "
         f"compile); warm launch: host_prepare_s={prep_s!r} "
         f"execute_s={exec_s!r} fetch_s={fetch_s!r} "
         f"peak_bytes_in_use={peak}")
-    again = [np.asarray(x) for x in jax.tree.leaves(final)] + [
-        np.moveaxis(np.asarray(x), 0, 1) for x in jax.tree.leaves(traces)]
-    first = [np.asarray(x) for x in
-             (*jax.tree.leaves(res.final), *jax.tree.leaves(res.traces))]
-    check(len(again) == len(first) and all(
-        a.tobytes() == b.tobytes() for a, b in zip(again, first)),
-        "a repeated launch is bitwise identical")
+    check(bitwise(again, res), "a repeated launch is bitwise identical")
     arrays = _arrays(res)
     check(all(np.isfinite(a).all() for a in arrays
               if np.issubdtype(a.dtype, np.floating)),

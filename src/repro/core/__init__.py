@@ -16,6 +16,8 @@ Public surface:
   * workloads:   collective-workload generator (all-to-all, ring /
                  recursive-doubling allreduce, incast storms, hotspots,
                  bursts) — combine with ``repro.net`` fabrics
+  * obs:         host spans, counters and the step's device scopes
+                 (``obs.stats()``, ``obs.sweep_op_scopes()``)
 """
 
 from .params import (CCConfig, CCScheme, CCSpec, DCQCNParams, FNCCParams,
@@ -38,7 +40,7 @@ from .scenarios import (PAPER_FLOW_NAMES, collective_flows, incast,
                         paper_incast, paper_incast_volume,
                         random_permutation)
 from .workloads import Workload
-from . import workloads
+from . import obs, workloads
 
 __all__ = [
     "CCConfig", "CCScheme", "CCSpec", "DCQCNParams", "FNCCParams",
@@ -55,5 +57,5 @@ __all__ = [
     "ScenarioSpec", "Sweep", "SweepResult", "config_grid",
     "pad_scenario", "stack_scenarios", "trim_final", "PAPER_FLOW_NAMES",
     "collective_flows", "incast", "paper_incast", "paper_incast_volume",
-    "random_permutation", "Workload", "workloads",
+    "random_permutation", "Workload", "workloads", "obs",
 ]

@@ -20,7 +20,9 @@ first-class metrics.  ``ExecutableCache`` is that cache made explicit:
 
 The module is dependency-free on purpose: the cache stores whatever the
 builder returns (AOT-compiled ``jax.stages.Compiled`` executables for
-the sweep engine, plain jitted callables for the mesh-sharded path).
+the sweep engine, plain jitted callables for the mesh-sharded path).  A
+miss's build runs under the ``repro.exec_cache.build`` span of
+``obs``, which loads JAX only when a span opens.
 """
 
 from __future__ import annotations
@@ -30,6 +32,8 @@ import dataclasses
 import threading
 import time
 from typing import Any, Callable, Hashable
+
+from . import obs
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,7 +128,8 @@ class ExecutableCache:
             # not compile twice (compilation is the expensive part)
             self._misses += 1
             t0 = time.perf_counter()
-            value = builder()
+            with obs.span("repro.exec_cache.build"):
+                value = builder()
             self._build_s += time.perf_counter() - t0
             self._entries[key] = value
             while len(self._entries) > self._capacity:
@@ -176,6 +181,10 @@ class ExecutableCache:
     def keys(self):
         with self._lock:
             return list(self._entries.keys())
+
+    def values(self):
+        with self._lock:
+            return list(self._entries.values())
 
     def __repr__(self) -> str:
         s = self.stats()

@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import cc
+from . import cc, obs
 from .exec_cache import ExecutableCache, structural_signature
 from .fluid import (FluidState, Scenario, check_routing_paths,
                     clamp_dense_rows, delay_depth, dense_reduce_rows,
@@ -252,6 +252,7 @@ class ScenarioSpec:
             return np.asarray(field, dtype)
         return np.full((F,), scalar, dtype)
 
+    @obs.span("repro.scenario.build")
     def build(self, cfg: CCConfig) -> Scenario:
         fab = self._fabric()
         topo = fab.build(line_rate=cfg.link.line_rate)
@@ -585,6 +586,7 @@ def _sweep_scan_fn(n_samples: int, trace_every: int, dt: float,
         check_vma=False)
 
 
+@obs.span("repro.sweep.resolve")
 def _sweep_executable(static: tuple, args: tuple):
     """Resolve one sweep launch to a cached compiled executable.
 
@@ -684,6 +686,7 @@ class Sweep:
             pts.append((p.name, p.cfg, p.scenario))
         return Sweep(pts)
 
+    @obs.span("repro.sweep.stage")
     def _prepare(self, n_steps: int | None = None,
                  trace_every: int | None = None, *, mesh=None,
                  reduce: str = "fused", use_kernels: bool = False,
@@ -800,15 +803,18 @@ class Sweep:
         st_b, sd_b, par_b = args
         R = len(self.points)
         exec_fn = _sweep_executable(static, args)
-        final, tr = exec_fn(st_b, sd_b, par_b)
+        with obs.span("repro.sweep.execute"):
+            final, tr = jax.block_until_ready(exec_fn(st_b, sd_b, par_b))
+        with obs.span("repro.sweep.fetch"):
+            tr, final = jax.device_get((tr, final))
+            obs.count("sweep.fetch_bytes", sum(
+                x.nbytes for x in jax.tree.leaves((tr, final))))
         times = (np.arange(n_samples) + 1) * k * self.points[0].cfg.sim.dt
         # scan stacks samples on axis 0 -> [T, R, ...]; runs lead on host
         return SweepResult(
             points=self.points, times=times,
-            traces=jax.tree.map(
-                lambda x: np.moveaxis(np.asarray(x), 0, 1)[:R], tr),
-            final=jax.tree.map(lambda x: np.asarray(x)[:R],
-                               jax.device_get(final)),
+            traces=jax.tree.map(lambda x: np.moveaxis(x, 0, 1)[:R], tr),
+            final=jax.tree.map(lambda x: x[:R], final),
             trace_every=k)
 
 

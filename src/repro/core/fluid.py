@@ -51,6 +51,12 @@ Per step (Jacobi, from pre-step state):
      hold, desynchronised additive recovery), swift (delay-target).
 
 All arrays are float32; the update is pure jnp and runs inside lax.scan.
+Each numbered phase runs under its own ``obs.scope`` (``fluid.select``,
+``fluid.generate``, ``fluid.transfer``, ``fluid.pfc``, ``fluid.mark``,
+``fluid.notify``, ``fluid.react``), the per-link reductions under
+``fluid.reduce`` inside them and the step's trace under
+``fluid.decimate``: names in the compiled program's op metadata, so a
+profiler trace's device time splits by phase.  They add no op.
 
 Layering (the Sweep engine in ``experiments.py`` builds on this):
   * ``Scenario``        — host-side numpy tensors describing one workload.
@@ -80,7 +86,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import cc
+from . import cc, obs
 from .params import CCConfig, CCSpec, ROUTING_MODES
 from .routing import PAD, link_incidence
 from repro.tune import soft
@@ -751,15 +757,16 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
         h = jnp.take_along_axis(sd.alt_hops, k_idx[:, None], axis=1)[:, 0]
         return r, h
 
-    if fused and dense_rows:
-        # dense-CSR row table, shared by every reduction pass this
-        # step: position p of queue q reads sorted row off[q] + p (the
-        # sentinel F*K*H reads an all-zero row).
-        _lens = sd.red_off[1:S + 1] - sd.red_off[:S]        # [S]
-        _pos = jnp.arange(dense_rows, dtype=jnp.int32)[None, :]
-        dense_idx = jnp.where(_pos < _lens[:, None],
-                              sd.red_off[:S, None] + _pos,
-                              F * K * H).reshape(-1)
+    with obs.scope("fluid.reduce"):
+        if fused and dense_rows:
+            # dense-CSR row table, shared by every reduction pass this
+            # step: position p of queue q reads sorted row off[q] + p (the
+            # sentinel F*K*H reads an all-zero row).
+            _lens = sd.red_off[1:S + 1] - sd.red_off[:S]        # [S]
+            _pos = jnp.arange(dense_rows, dtype=jnp.int32)[None, :]
+            dense_idx = jnp.where(_pos < _lens[:, None],
+                                  sd.red_off[:S, None] + _pos,
+                                  F * K * H).reshape(-1)
 
     def link_sums(channels, k_sel):
         """All per-queue sums of the [F, H] ``channels`` in ONE sweep.
@@ -773,405 +780,418 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
         sum — all three accumulate each queue's contributors in the
         same order, so the result is bit-identical across engines.
         """
-        data = jnp.stack(channels, axis=-1)                 # [F, H, C]
-        C = data.shape[-1]
-        if K > 1:
-            onehot = (jnp.arange(K, dtype=jnp.int32)[None, :]
-                      == k_sel[:, None])                    # [F, K]
-            data = data[:, None] * \
-                onehot[:, :, None, None].astype(jnp.float32)
-        data = jnp.take(data.reshape(F * K * H, C), sd.red_perm, axis=0)
-        if reduce == "pallas":
-            from repro.kernels.fluid_reduce import segment_reduce
-            sums = segment_reduce(data, sd.red_seg, S + 1,
-                                  interpret=interpret)
-        elif dense_rows:
-            data_ext = jnp.concatenate(
-                [data, jnp.zeros((1, C), jnp.float32)])
-            if dense_tiled:
-                from repro.kernels.fluid_step import dense_reduce_tiled
-                sums = dense_reduce_tiled(data_ext, dense_idx, S,
-                                          dense_rows)
+        with obs.scope("fluid.reduce"):
+            data = jnp.stack(channels, axis=-1)                 # [F, H, C]
+            C = data.shape[-1]
+            if K > 1:
+                onehot = (jnp.arange(K, dtype=jnp.int32)[None, :]
+                          == k_sel[:, None])                    # [F, K]
+                data = data[:, None] * \
+                    onehot[:, :, None, None].astype(jnp.float32)
+            data = jnp.take(data.reshape(F * K * H, C), sd.red_perm, axis=0)
+            if reduce == "pallas":
+                from repro.kernels.fluid_reduce import segment_reduce
+                sums = segment_reduce(data, sd.red_seg, S + 1,
+                                      interpret=interpret)
+            elif dense_rows:
+                data_ext = jnp.concatenate(
+                    [data, jnp.zeros((1, C), jnp.float32)])
+                if dense_tiled:
+                    from repro.kernels.fluid_step import dense_reduce_tiled
+                    sums = dense_reduce_tiled(data_ext, dense_idx, S,
+                                              dense_rows)
+                else:
+                    dense = jnp.take(data_ext, dense_idx,
+                                     axis=0).reshape(S, dense_rows, C)
+
+                    def body(p, acc):
+                        return acc + jax.lax.dynamic_slice_in_dim(
+                            dense, p, 1, 1)[:, 0]
+
+                    acc = jax.lax.fori_loop(0, dense_rows, body,
+                                            jnp.zeros((S, C), jnp.float32))
+                    sums = jnp.concatenate(
+                        [acc, jnp.zeros((1, C), jnp.float32)])
             else:
-                dense = jnp.take(data_ext, dense_idx,
-                                 axis=0).reshape(S, dense_rows, C)
-
-                def body(p, acc):
-                    return acc + jax.lax.dynamic_slice_in_dim(
-                        dense, p, 1, 1)[:, 0]
-
-                acc = jax.lax.fori_loop(0, dense_rows, body,
-                                        jnp.zeros((S, C), jnp.float32))
-                sums = jnp.concatenate(
-                    [acc, jnp.zeros((1, C), jnp.float32)])
-        else:
-            sums = jax.ops.segment_sum(data, sd.red_seg,
-                                       num_segments=S + 1,
-                                       indices_are_sorted=True)
-        return [sums[:, c] for c in range(C)]
+                sums = jax.ops.segment_sum(data, sd.red_seg,
+                                           num_segments=S + 1,
+                                           indices_are_sorted=True)
+            return [sums[:, c] for c in range(C)]
 
     # ---- 0. path selection (min / valiant / ugal) -------------------------
-    if K == 1:
-        # single-path scenario: selection is statically a no-op, and the
-        # update below is the exact single-table computation.
-        path_idx = st.path_idx
-        routes, hops = sd.alt_routes[:, 0, :], sd.alt_hops[:, 0]
-    else:
-        # Per-link backlog of the *pre-step* queues, laid out along each
-        # flow's currently selected path (its queued bytes live there).
-        routes_old, hops_old = pick_paths(st.path_idx)
-        v_old = routes_old != PAD
-        hq_old = v_old & (arange_h < (hops_old[:, None] - 1))
-        if fused:
-            (B_prev,) = link_sums([jnp.where(hq_old, st.qh, 0.0)],
-                                  st.path_idx)
-            B_prev = to_wire(B_prev)
-        elif V == 1:
-            B_prev = jnp.zeros((L + 1,), jnp.float32).at[
-                jnp.where(v_old, routes_old, L)].add(
-                    jnp.where(hq_old, st.qh, 0.0))
+    with obs.scope("fluid.select"):
+        if K == 1:
+            # single-path scenario: selection is statically a no-op, and the
+            # update below is the exact single-table computation.
+            path_idx = st.path_idx
+            routes, hops = sd.alt_routes[:, 0, :], sd.alt_hops[:, 0]
         else:
-            vc_old = jnp.take_along_axis(
-                sd.vc, st.path_idx[:, None, None], axis=1)[:, 0]
-            B_prev = to_wire(jnp.zeros((S + 1,), jnp.float32).at[
-                jnp.where(v_old, routes_old * V + vc_old, S)].add(
-                    jnp.where(hq_old, st.qh, 0.0)))
+            # Per-link backlog of the *pre-step* queues, laid out along each
+            # flow's currently selected path (its queued bytes live there).
+            routes_old, hops_old = pick_paths(st.path_idx)
+            v_old = routes_old != PAD
+            hq_old = v_old & (arange_h < (hops_old[:, None] - 1))
+            if fused:
+                (B_prev,) = link_sums([jnp.where(hq_old, st.qh, 0.0)],
+                                      st.path_idx)
+                B_prev = to_wire(B_prev)
+            elif V == 1:
+                B_prev = jnp.zeros((L + 1,), jnp.float32).at[
+                    jnp.where(v_old, routes_old, L)].add(
+                        jnp.where(hq_old, st.qh, 0.0))
+            else:
+                vc_old = jnp.take_along_axis(
+                    sd.vc, st.path_idx[:, None, None], axis=1)[:, 0]
+                B_prev = to_wire(jnp.zeros((S + 1,), jnp.float32).at[
+                    jnp.where(v_old, routes_old * V + vc_old, S)].add(
+                        jnp.where(hq_old, st.qh, 0.0)))
 
-        def path_cost(k_idx):
-            """UGAL cost: hop count x backlog along the candidate."""
-            r, h = pick_paths(k_idx)
-            v = r != PAD
-            q = jnp.sum(jnp.where(v, B_prev[jnp.where(v, r, L)], 0.0),
-                        axis=1)
-            return h.astype(jnp.float32) * q
+            def path_cost(k_idx):
+                """UGAL cost: hop count x backlog along the candidate."""
+                r, h = pick_paths(k_idx)
+                v = r != PAD
+                q = jnp.sum(jnp.where(v, B_prev[jnp.where(v, r, L)], 0.0),
+                            axis=1)
+                return h.astype(jnp.float32) * q
 
-        # one sampled detour per flow, rotating over its valid slots
-        # (slots 1..n_alt; flows without candidates stay minimal)
-        n_alt = jnp.sum((sd.alt_hops[:, 1:] > 0).astype(jnp.int32), axis=1)
-        samp = jnp.where(n_alt > 0,
-                         1 + (fidx + st.t) % jnp.maximum(n_alt, 1), 0)
-        # UGAL-L: switch only if the detour's queue-weighted hops beat
-        # the minimal path's STRICTLY — ties (e.g. zero backlog
-        # everywhere) keep the minimal route.
-        ugal_pick = jnp.where(path_cost(samp) < path_cost(
-            jnp.zeros((F,), jnp.int32)), samp, 0)
-        # selection epochs: flow start (both modes) + CNP arrival (ugal
-        # re-evaluates under congestion feedback).  Reading the delay
-        # line here matches phase 5's cnp exactly: this step's emissions
-        # land at (t + rtt) % D != t % D since 0 < rtt < D.
-        starting = (t_sec >= sd.t_start) & (t_sec - dt < sd.t_start)
-        cnp_now = st.trig_buf[st.t % D] > 0
-        epoch = starting | ((par.route_code == 2) & cnp_now)
-        pick = jnp.where(par.route_code == 1, samp, ugal_pick)
-        path_idx = jnp.where(par.route_code == 0, 0,
-                             jnp.where(epoch, pick, st.path_idx))
-        routes, hops = pick_paths(path_idx)
+            # one sampled detour per flow, rotating over its valid slots
+            # (slots 1..n_alt; flows without candidates stay minimal)
+            n_alt = jnp.sum((sd.alt_hops[:, 1:] > 0).astype(jnp.int32), axis=1)
+            samp = jnp.where(n_alt > 0,
+                             1 + (fidx + st.t) % jnp.maximum(n_alt, 1), 0)
+            # UGAL-L: switch only if the detour's queue-weighted hops beat
+            # the minimal path's STRICTLY — ties (e.g. zero backlog
+            # everywhere) keep the minimal route.
+            ugal_pick = jnp.where(path_cost(samp) < path_cost(
+                jnp.zeros((F,), jnp.int32)), samp, 0)
+            # selection epochs: flow start (both modes) + CNP arrival (ugal
+            # re-evaluates under congestion feedback).  Reading the delay
+            # line here matches phase 5's cnp exactly: this step's emissions
+            # land at (t + rtt) % D != t % D since 0 < rtt < D.
+            starting = (t_sec >= sd.t_start) & (t_sec - dt < sd.t_start)
+            cnp_now = st.trig_buf[st.t % D] > 0
+            epoch = starting | ((par.route_code == 2) & cnp_now)
+            pick = jnp.where(par.route_code == 1, samp, ugal_pick)
+            path_idx = jnp.where(par.route_code == 0, 0,
+                                 jnp.where(epoch, pick, st.path_idx))
+            routes, hops = pick_paths(path_idx)
 
-    valid = routes != PAD
-    widx = jnp.where(valid, routes, L)         # PAD -> scratch slot L
-    if V == 1:
-        qidx = widx                            # queue == wire, verbatim
-    else:
-        # VC of the selected candidate per hop; PAD hops carry VC 0
-        # (enforced host-side), so qidx == S exactly at the scratch.
-        vc_sel = sd.vc[:, 0, :] if K == 1 else jnp.take_along_axis(
-            sd.vc, path_idx[:, None, None], axis=1)[:, 0]
-        qidx = jnp.where(valid, widx * V + vc_sel, S)
-    is_last = valid & (arange_h == (hops[:, None] - 1))
-    holds_queue = valid & (arange_h < (hops[:, None] - 1))
-    eps_rate = jnp.float32(1e6)                # B/s: "active" demand
+        valid = routes != PAD
+        widx = jnp.where(valid, routes, L)         # PAD -> scratch slot L
+        if V == 1:
+            qidx = widx                            # queue == wire, verbatim
+        else:
+            # VC of the selected candidate per hop; PAD hops carry VC 0
+            # (enforced host-side), so qidx == S exactly at the scratch.
+            vc_sel = sd.vc[:, 0, :] if K == 1 else jnp.take_along_axis(
+                sd.vc, path_idx[:, None, None], axis=1)[:, 0]
+            qidx = jnp.where(valid, widx * V + vc_sel, S)
+        is_last = valid & (arange_h == (hops[:, None] - 1))
+        holds_queue = valid & (arange_h < (hops[:, None] - 1))
+        eps_rate = jnp.float32(1e6)                # B/s: "active" demand
 
     def scat(values_fh, init=0.0):
         """Scatter-add a [F,H] quantity onto per-queue slots [S+1]."""
-        out = jnp.full((S + 1,), init, jnp.float32)
-        return out.at[qidx].add(values_fh)
+        with obs.scope("fluid.reduce"):
+            out = jnp.full((S + 1,), init, jnp.float32)
+            return out.at[qidx].add(values_fh)
 
     # ---- 1. generation ----------------------------------------------------
-    if use_kernels:
-        from repro.kernels.cc_step import gen_np_step
-        nicq, offered, dropped, np_tmr_t = gen_np_step(
-            st.nicq, st.offered, st.dropped, st.np_tmr,
-            sd.gen_rate, sd.t_start, sd.t_stop, sd.volume, sd.nic_buffer,
-            t_sec=t_sec, dt=dt, interpret=interpret)
-    else:
-        active = (t_sec >= sd.t_start) & (t_sec < sd.t_stop)
-        gen = jnp.where(active, sd.gen_rate, 0.0) * dt
-        gen = jnp.minimum(gen, jnp.maximum(sd.volume - st.offered, 0.0))
-        nicq = st.nicq + gen
-        over = jnp.maximum(nicq - sd.nic_buffer, 0.0)
-        nicq = nicq - over
-        offered = st.offered + gen - over
-        dropped = st.dropped + over
-        np_tmr_t = st.np_tmr + dt              # notification-window tick
+    with obs.scope("fluid.generate"):
+        if use_kernels:
+            from repro.kernels.cc_step import gen_np_step
+            nicq, offered, dropped, np_tmr_t = gen_np_step(
+                st.nicq, st.offered, st.dropped, st.np_tmr,
+                sd.gen_rate, sd.t_start, sd.t_stop, sd.volume, sd.nic_buffer,
+                t_sec=t_sec, dt=dt, interpret=interpret)
+        else:
+            active = (t_sec >= sd.t_start) & (t_sec < sd.t_stop)
+            gen = jnp.where(active, sd.gen_rate, 0.0) * dt
+            gen = jnp.minimum(gen, jnp.maximum(sd.volume - st.offered, 0.0))
+            nicq = st.nicq + gen
+            over = jnp.maximum(nicq - sd.nic_buffer, 0.0)
+            nicq = nicq - over
+            offered = st.offered + gen - over
+            dropped = st.dropped + over
+            np_tmr_t = st.np_tmr + dt              # notification-window tick
 
     # ---- 2. transfers -----------------------------------------------------
-    src_inj = jnp.minimum(nicq, jnp.minimum(st.rate, par.line_rate) * dt)
-    src_q = jnp.concatenate([src_inj[:, None], st.qh[:, :-1]], axis=1)
-    src_q = jnp.where(valid, src_q, 0.0)
+    with obs.scope("fluid.transfer"):
+        src_inj = jnp.minimum(nicq, jnp.minimum(st.rate, par.line_rate) * dt)
+        src_q = jnp.concatenate([src_inj[:, None], st.qh[:, :-1]], axis=1)
+        src_q = jnp.where(valid, src_q, 0.0)
 
-    pause_q = jnp.concatenate([st.paused, jnp.zeros((1,), jnp.float32)])
-    wire_open = 1.0 - pause_q[qidx]                    # [F,H] 1 = drainable
+        pause_q = jnp.concatenate([st.paused, jnp.zeros((1,), jnp.float32)])
+        wire_open = 1.0 - pause_q[qidx]                    # [F,H] 1 = drainable
 
-    # strict-FIFO HoL factor per link queue: share of the queue whose
-    # *next* wire is currently drainable.  ``wire_open`` is an exact
-    # 0/1 float in hard mode; a fractional pause level scales service
-    # proportionally (the fluid relaxation of the on/off gate).
-    next_open = jnp.concatenate(
-        [wire_open[:, 1:], jnp.ones((F, 1), jnp.float32)], axis=1)
-    q_here = jnp.where(holds_queue, st.qh, 0.0)        # queue at sink(h)
-    weight = src_q * wire_open
-    caps_w = sd.cap_ext[widx]                          # [F,H]
-    if fused:
-        num, den, sum_w = link_sums(
-            [q_here * next_open, q_here, weight], path_idx)
-    else:
-        num = scat(q_here * next_open)
-        den = scat(q_here)
-        sum_w = scat(weight)
-    # FIFO factor is per (wire, VC) queue — a paused-head VC no longer
-    # stalls its siblings, only its own lane (the HoL fix VCs buy).
-    fifo_ok = jnp.where(den > 0, num / jnp.maximum(den, 1e-9), 1.0)
-    # ... but the byte budget is per *wire*: capacity is shared across
-    # VCs in proportion to drainable backlog.  fifo_ok <= 1, so the
-    # summed per-VC grants never exceed the wire's C*dt.
-    sum_w_w = to_wire(sum_w)
+        # strict-FIFO HoL factor per link queue: share of the queue whose
+        # *next* wire is currently drainable.  ``wire_open`` is an exact
+        # 0/1 float in hard mode; a fractional pause level scales service
+        # proportionally (the fluid relaxation of the on/off gate).
+        next_open = jnp.concatenate(
+            [wire_open[:, 1:], jnp.ones((F, 1), jnp.float32)], axis=1)
+        q_here = jnp.where(holds_queue, st.qh, 0.0)        # queue at sink(h)
+        weight = src_q * wire_open
+        caps_w = sd.cap_ext[widx]                          # [F,H]
+        if fused:
+            num, den, sum_w = link_sums(
+                [q_here * next_open, q_here, weight], path_idx)
+        else:
+            num = scat(q_here * next_open)
+            den = scat(q_here)
+            sum_w = scat(weight)
+        # FIFO factor is per (wire, VC) queue — a paused-head VC no longer
+        # stalls its siblings, only its own lane (the HoL fix VCs buy).
+        fifo_ok = jnp.where(den > 0, num / jnp.maximum(den, 1e-9), 1.0)
+        # ... but the byte budget is per *wire*: capacity is shared across
+        # VCs in proportion to drainable backlog.  fifo_ok <= 1, so the
+        # summed per-VC grants never exceed the wire's C*dt.
+        sum_w_w = to_wire(sum_w)
 
-    budget = caps_w * dt * fifo_ok[qidx]
-    share = jnp.where(sum_w_w[widx] > 0,
-                      budget * weight / jnp.maximum(sum_w_w[widx], 1e-9),
-                      0.0)
-    T = jnp.minimum(weight, share)                     # bytes crossing h
+        budget = caps_w * dt * fifo_ok[qidx]
+        share = jnp.where(sum_w_w[widx] > 0,
+                          budget * weight / jnp.maximum(sum_w_w[widx], 1e-9),
+                          0.0)
+        T = jnp.minimum(weight, share)                     # bytes crossing h
 
-    nicq = nicq - T[:, 0]
-    qh = st.qh - jnp.pad(T[:, 1:], ((0, 0), (0, 1)))   # drain from h-1
-    qh = qh + jnp.where(holds_queue, T, 0.0)           # land at sink(h)
-    qh = jnp.maximum(qh, 0.0)
-    deliv_step = jnp.sum(jnp.where(is_last, T, 0.0), axis=1)
-    delivered = st.delivered + deliv_step
+        nicq = nicq - T[:, 0]
+        qh = st.qh - jnp.pad(T[:, 1:], ((0, 0), (0, 1)))   # drain from h-1
+        qh = qh + jnp.where(holds_queue, T, 0.0)           # land at sink(h)
+        qh = jnp.maximum(qh, 0.0)
+        deliv_step = jnp.sum(jnp.where(is_last, T, 0.0), axis=1)
+        delivered = st.delivered + deliv_step
 
-    # crossing-rate EWMA (doubles as arrival-into-queue estimate)
-    est = (1 - par.ecp_beta) * st.est + par.ecp_beta * (T / dt)
+        # crossing-rate EWMA (doubles as arrival-into-queue estimate)
+        est = (1 - par.ecp_beta) * st.est + par.ecp_beta * (T / dt)
 
-    # Demand to cross wire h = arrival rate into the queue feeding it
-    # (pre-stall, so FIFO-blocked victims keep their true demand).
-    # Computed here so the post-transfer reduction pass covers the PFC
-    # sink queues AND the marking activity sums in one sweep.
-    dem = jnp.concatenate([est[:, :1], est[:, :-1]], axis=1)
-    dem = jnp.where(valid, dem, 0.0)
-    act = (dem > eps_rate) & valid
+        # Demand to cross wire h = arrival rate into the queue feeding it
+        # (pre-stall, so FIFO-blocked victims keep their true demand).
+        # Computed here so the post-transfer reduction pass covers the PFC
+        # sink queues AND the marking activity sums in one sweep.
+        dem = jnp.concatenate([est[:, :1], est[:, :-1]], axis=1)
+        dem = jnp.where(valid, dem, 0.0)
+        act = (dem > eps_rate) & valid
 
     # ---- 3. PFC -----------------------------------------------------------
-    if fused:
-        B_ext, n_act, sum_dem = link_sums(
-            [jnp.where(holds_queue, qh, 0.0),
-             act.astype(jnp.float32),
-             jnp.where(act, dem, 0.0)], path_idx)
-        B = B_ext[:S]                           # [S] per-(wire, VC) queues
-    else:
-        B = scat(jnp.where(holds_queue, qh, 0.0))[:S]
-        n_act = scat(act.astype(jnp.float32), init=0.0)
-        sum_dem = scat(jnp.where(act, dem, 0.0))
-    # fair grants / oversubscription below are per-wire notions
-    n_act_w = to_wire(n_act)
-    sum_dem_w = to_wire(sum_dem)
-    # xoff/xon hysteresis per queue: hard = set above xoff, clear below
-    # xon, hold in between; soft = the pause level relaxes toward 1 (0)
-    # through a sigmoid band O(tau * port_buffer) wide around each
-    # threshold.  With V > 1 the port thresholds split evenly across
-    # the VC queues (static branch — V == 1 keeps the exact scalars).
-    if V == 1:
-        xoff_q, xon_q = par.xoff, par.xon
-    else:
-        xoff_q, xon_q = par.xoff / V, par.xon / V
-    paused_h = jnp.where(B > xoff_q, 1.0,
-                         jnp.where(B < xon_q, 0.0, st.paused))
-    g_on = soft.unit_gate(B - xoff_q, tau, par.port_buffer)
-    g_off = soft.unit_gate(xon_q - B, tau, par.port_buffer)
-    paused_s = st.paused + (1.0 - st.paused) * g_on - st.paused * g_off
-    paused = soft.select(tau, paused_s, paused_h)
-    sink_l = sd.sink_ext[:L]
-    # shared pool counts the wire's whole input buffer across its VCs
-    B_wire = B if V == 1 else B.reshape(L, V).sum(axis=1)
-    if fused:
-        pool = jax.ops.segment_sum(
-            jnp.take(jnp.where(sink_l >= 0, B_wire, 0.0), sd.pool_perm),
-            sd.pool_seg, num_segments=n_switches + 1,
-            indices_are_sorted=True)[:n_switches]
-    else:
-        pool = jnp.zeros((n_switches,), jnp.float32).at[
-            jnp.maximum(sink_l, 0)].add(
-                jnp.where(sink_l >= 0, B_wire, 0.0))
-    pool_hot = soft.select(
-        tau,
-        soft.unit_gate(pool - par.pool_xoff, tau, par.port_buffer),
-        (pool > par.pool_xoff).astype(jnp.float32))
-    # max of pause levels == boolean OR on the exact 0/1 hard values;
-    # a hot pool pauses every VC of the wire (pause is per-queue state)
-    pool_pause = jnp.where(sink_l >= 0,
-                           pool_hot[jnp.maximum(sink_l, 0)], 0.0)
-    if V > 1:
-        pool_pause = jnp.repeat(pool_pause, V)
-    paused = jnp.maximum(paused, pool_pause)
+    with obs.scope("fluid.pfc"):
+        if fused:
+            B_ext, n_act, sum_dem = link_sums(
+                [jnp.where(holds_queue, qh, 0.0),
+                 act.astype(jnp.float32),
+                 jnp.where(act, dem, 0.0)], path_idx)
+            B = B_ext[:S]                           # [S] per-(wire, VC) queues
+        else:
+            B = scat(jnp.where(holds_queue, qh, 0.0))[:S]
+            n_act = scat(act.astype(jnp.float32), init=0.0)
+            sum_dem = scat(jnp.where(act, dem, 0.0))
+        # fair grants / oversubscription below are per-wire notions
+        n_act_w = to_wire(n_act)
+        sum_dem_w = to_wire(sum_dem)
+        # xoff/xon hysteresis per queue: hard = set above xoff, clear below
+        # xon, hold in between; soft = the pause level relaxes toward 1 (0)
+        # through a sigmoid band O(tau * port_buffer) wide around each
+        # threshold.  With V > 1 the port thresholds split evenly across
+        # the VC queues (static branch — V == 1 keeps the exact scalars).
+        if V == 1:
+            xoff_q, xon_q = par.xoff, par.xon
+        else:
+            xoff_q, xon_q = par.xoff / V, par.xon / V
+        paused_h = jnp.where(B > xoff_q, 1.0,
+                             jnp.where(B < xon_q, 0.0, st.paused))
+        g_on = soft.unit_gate(B - xoff_q, tau, par.port_buffer)
+        g_off = soft.unit_gate(xon_q - B, tau, par.port_buffer)
+        paused_s = st.paused + (1.0 - st.paused) * g_on - st.paused * g_off
+        paused = soft.select(tau, paused_s, paused_h)
+        sink_l = sd.sink_ext[:L]
+        # shared pool counts the wire's whole input buffer across its VCs
+        B_wire = B if V == 1 else B.reshape(L, V).sum(axis=1)
+        with obs.scope("fluid.reduce"):
+            if fused:
+                pool = jax.ops.segment_sum(
+                    jnp.take(jnp.where(sink_l >= 0, B_wire, 0.0), sd.pool_perm),
+                    sd.pool_seg, num_segments=n_switches + 1,
+                    indices_are_sorted=True)[:n_switches]
+            else:
+                pool = jnp.zeros((n_switches,), jnp.float32).at[
+                    jnp.maximum(sink_l, 0)].add(
+                        jnp.where(sink_l >= 0, B_wire, 0.0))
+        pool_hot = soft.select(
+            tau,
+            soft.unit_gate(pool - par.pool_xoff, tau, par.port_buffer),
+            (pool > par.pool_xoff).astype(jnp.float32))
+        # max of pause levels == boolean OR on the exact 0/1 hard values;
+        # a hot pool pauses every VC of the wire (pause is per-queue state)
+        pool_pause = jnp.where(sink_l >= 0,
+                               pool_hot[jnp.maximum(sink_l, 0)], 0.0)
+        if V > 1:
+            pool_pause = jnp.repeat(pool_pause, V)
+        paused = jnp.maximum(paused, pool_pause)
 
     # ---- 4. marking (cc.MARKING dispatch) ---------------------------------
-    # B1_w: occupancy of the flow's own (wire, VC) queue — marking sees
-    # the lane the flow actually sits in, not its siblings' backlog
-    B1 = jnp.concatenate([B, jnp.zeros((1,), jnp.float32)])
-    B1_w = B1[qidx]
-    present = (qh > 0) | (T > 0)
+    with obs.scope("fluid.mark"):
+        # B1_w: occupancy of the flow's own (wire, VC) queue — marking sees
+        # the lane the flow actually sits in, not its siblings' backlog
+        B1 = jnp.concatenate([B, jnp.zeros((1,), jnp.float32)])
+        B1_w = B1[qidx]
+        present = (qh > 0) | (T > 0)
 
-    share0 = caps_w / jnp.maximum(n_act_w[widx], 1.0)
-    under = dem < share0
-    if fused:
-        surplus, n_heavy = link_sums(
-            [jnp.where(act & under, share0 - dem, 0.0),
-             (act & ~under).astype(jnp.float32)], path_idx)
-    else:
-        surplus = scat(jnp.where(act & under, share0 - dem, 0.0))
-        n_heavy = scat((act & ~under).astype(jnp.float32))
-    surplus_w = to_wire(surplus)
-    n_heavy_w = to_wire(n_heavy)
-    grant = jnp.where(
-        under, dem,
-        share0 + surplus_w[widx] / jnp.maximum(n_heavy_w[widx], 1.0))
-    grant = jnp.where(act, grant, caps_w)
-    # wire h oversubscribed?  (soft: sigmoid in the demand excess; the
-    # PAD slot's cap is inf, so the soft gate is exactly 0 there too)
-    oversub = soft.select(
-        tau,
-        soft.unit_gate(sum_dem_w[widx] - caps_w, tau, par.line_rate),
-        (sum_dem_w[widx] > caps_w).astype(jnp.float32))
-    # ... all shifted to the *next* wire (the flow's requested output)
-    inf_col = jnp.full((F, 1), jnp.inf, jnp.float32)
-    grant_next = jnp.concatenate([grant[:, 1:], inf_col], axis=1)
-    grant_next = jnp.where(holds_queue, grant_next, jnp.inf)
-    dem_next = jnp.concatenate(
-        [dem[:, 1:], jnp.zeros((F, 1), jnp.float32)], axis=1)
-    over_next = jnp.concatenate(
-        [oversub[:, 1:], jnp.zeros((F, 1), jnp.float32)], axis=1)
+        share0 = caps_w / jnp.maximum(n_act_w[widx], 1.0)
+        under = dem < share0
+        if fused:
+            surplus, n_heavy = link_sums(
+                [jnp.where(act & under, share0 - dem, 0.0),
+                 (act & ~under).astype(jnp.float32)], path_idx)
+        else:
+            surplus = scat(jnp.where(act & under, share0 - dem, 0.0))
+            n_heavy = scat((act & ~under).astype(jnp.float32))
+        surplus_w = to_wire(surplus)
+        n_heavy_w = to_wire(n_heavy)
+        grant = jnp.where(
+            under, dem,
+            share0 + surplus_w[widx] / jnp.maximum(n_heavy_w[widx], 1.0))
+        grant = jnp.where(act, grant, caps_w)
+        # wire h oversubscribed?  (soft: sigmoid in the demand excess; the
+        # PAD slot's cap is inf, so the soft gate is exactly 0 there too)
+        oversub = soft.select(
+            tau,
+            soft.unit_gate(sum_dem_w[widx] - caps_w, tau, par.line_rate),
+            (sum_dem_w[widx] > caps_w).astype(jnp.float32))
+        # ... all shifted to the *next* wire (the flow's requested output)
+        inf_col = jnp.full((F, 1), jnp.inf, jnp.float32)
+        grant_next = jnp.concatenate([grant[:, 1:], inf_col], axis=1)
+        grant_next = jnp.where(holds_queue, grant_next, jnp.inf)
+        dem_next = jnp.concatenate(
+            [dem[:, 1:], jnp.zeros((F, 1), jnp.float32)], axis=1)
+        over_next = jnp.concatenate(
+            [oversub[:, 1:], jnp.zeros((F, 1), jnp.float32)], axis=1)
 
-    # Every registered marking stage (CP occupancy / ECP fair-grant /
-    # slope ramp / ...) computes its mark set + severity from this
-    # shared context; the traced ``mark_code`` selects one — so marking
-    # joins scheme constants and routing as a one-launch sweep axis.
-    (mark_fh, sev), cc_mark = cc.dispatch(
-        cc.MARKING, par.mark_code, par.mark,
-        cc.MarkCtx(B1_w=B1_w, present=present, holds_queue=holds_queue,
-                   dem_next=dem_next, grant_next=grant_next,
-                   over_next=over_next, port_buffer=par.port_buffer,
-                   line_rate=par.line_rate, tau=tau),
-        st.cc, in_kernel=in_kernel)
-    # mark_fh is a [F, H] float mark intensity: exact 0/1 in hard mode,
-    # sigmoid-graded under the soft model.
-    mark_pos = mark_fh > 0.0
-    marked = jnp.any(mark_pos, axis=1)
-    # severity payload: fair grant at the marking queue, scaled down by
-    # the queue's excess over V so standing backlog drains (ENP carries
-    # "timely congestion severity", ERP converges to fair as B -> V).
-    # Hard: min over marking hops.  Soft: intensity-weighted mean —
-    # inf sentinels (non-queue hops) carry zero intensity and are
-    # where-masked out, never multiplied (0 * inf = nan).
-    tgt_h = jnp.min(jnp.where(mark_pos, sev, jnp.inf), axis=1)
-    tgt_h = jnp.where(jnp.isfinite(tgt_h), tgt_h, par.line_rate)
-    # inf severities (a marking hop whose next wire has no finite
-    # grant) take the same line-rate fallback as the hard min above —
-    # inside the mask, so the weighted mean never touches inf
-    sev_fin = jnp.where(jnp.isfinite(sev), sev, par.line_rate)
-    m_sev = jnp.sum(jnp.where(mark_pos, mark_fh * sev_fin, 0.0), axis=1)
-    m_sum = jnp.sum(mark_fh, axis=1)
-    tgt = soft.select(
-        tau, (m_sev + 1e-6 * par.line_rate) / (m_sum + 1e-6), tgt_h)
-    # notification sees a [F] mark level: any-hop in hard mode, the
-    # peak intensity (capped at one message) under the soft model
-    mark_lvl = jnp.minimum(jnp.max(mark_fh, axis=1), 1.0)
+        # Every registered marking stage (CP occupancy / ECP fair-grant /
+        # slope ramp / ...) computes its mark set + severity from this
+        # shared context; the traced ``mark_code`` selects one — so marking
+        # joins scheme constants and routing as a one-launch sweep axis.
+        (mark_fh, sev), cc_mark = cc.dispatch(
+            cc.MARKING, par.mark_code, par.mark,
+            cc.MarkCtx(B1_w=B1_w, present=present, holds_queue=holds_queue,
+                       dem_next=dem_next, grant_next=grant_next,
+                       over_next=over_next, port_buffer=par.port_buffer,
+                       line_rate=par.line_rate, tau=tau),
+            st.cc, in_kernel=in_kernel)
+        # mark_fh is a [F, H] float mark intensity: exact 0/1 in hard mode,
+        # sigmoid-graded under the soft model.
+        mark_pos = mark_fh > 0.0
+        marked = jnp.any(mark_pos, axis=1)
+        # severity payload: fair grant at the marking queue, scaled down by
+        # the queue's excess over V so standing backlog drains (ENP carries
+        # "timely congestion severity", ERP converges to fair as B -> V).
+        # Hard: min over marking hops.  Soft: intensity-weighted mean —
+        # inf sentinels (non-queue hops) carry zero intensity and are
+        # where-masked out, never multiplied (0 * inf = nan).
+        tgt_h = jnp.min(jnp.where(mark_pos, sev, jnp.inf), axis=1)
+        tgt_h = jnp.where(jnp.isfinite(tgt_h), tgt_h, par.line_rate)
+        # inf severities (a marking hop whose next wire has no finite
+        # grant) take the same line-rate fallback as the hard min above —
+        # inside the mask, so the weighted mean never touches inf
+        sev_fin = jnp.where(jnp.isfinite(sev), sev, par.line_rate)
+        m_sev = jnp.sum(jnp.where(mark_pos, mark_fh * sev_fin, 0.0), axis=1)
+        m_sum = jnp.sum(mark_fh, axis=1)
+        tgt = soft.select(
+            tau, (m_sev + 1e-6 * par.line_rate) / (m_sum + 1e-6), tgt_h)
+        # notification sees a [F] mark level: any-hop in hard mode, the
+        # peak intensity (capped at one message) under the soft model
+        mark_lvl = jnp.minimum(jnp.max(mark_fh, axis=1), 1.0)
 
     # ---- 5. notification (cc.NOTIFICATION dispatch) -----------------------
-    # Each stage decides who emits (suppression/coalescing window) and
-    # *when* the payload lands: NP/ENP after the end-to-end RTT, FNCC
-    # from the marking hop's position on the return path.  The delay
-    # line is sized >= max(rtt)+1 (see delay_depth), so the modulo is a
-    # ring-buffer index, never an aliased (shortened) feedback delay.
-    # ``emit`` is a [F] float emission intensity (exact 0/1 hard,
-    # fractional soft) — it is also the per-step control-traffic
-    # counter surfaced in the trace below.
-    (emit, np_tmr, wslot), cc_notif = cc.dispatch(
-        cc.NOTIFICATION, par.notif_code, par.notif,
-        cc.NotifCtx(marked=mark_lvl, mark_fh=mark_fh, np_tmr_t=np_tmr_t,
-                    hops=hops, rtt=sd.rtt, t=st.t, D=D, tau=tau),
-        st.cc, in_kernel=in_kernel)
-    rslot = st.t % D
-    if fused:
-        # branch-free ring ops: one-hot compare instead of scatters.
-        # Exact: each (wslot[f], f) cell gets the same single add/set,
-        # every other cell an exact +0.0 / keep; the read row rslot is
-        # disjoint from all write slots (0 < rtt < D).
-        d_iota = jnp.arange(D, dtype=jnp.int32)[:, None]       # [D, 1]
-        w_hot = d_iota == wslot[None, :]                       # [D, F]
-        trig_buf = st.trig_buf + jnp.where(w_hot, emit[None, :], 0.0)
-        tgt_buf = soft.select(
-            tau,
-            jnp.where(w_hot,
-                      emit[None, :] * tgt[None, :]
-                      + (1.0 - emit[None, :]) * st.tgt_buf,
-                      st.tgt_buf),
-            jnp.where(w_hot & (emit[None, :] > 0), tgt[None, :],
-                      st.tgt_buf))
-        cnp = soft.select(tau, jnp.minimum(trig_buf[rslot], 1.0),
-                          (trig_buf[rslot] > 0).astype(jnp.float32))
-        tgt_rx = tgt_buf[rslot]
-        trig_buf = jnp.where(d_iota == rslot, 0.0, trig_buf)
-    else:
-        trig_buf = st.trig_buf.at[wslot, fidx].add(emit)
-        prev_tgt = st.tgt_buf[wslot, fidx]
-        tgt_buf = st.tgt_buf.at[wslot, fidx].set(
-            soft.select(tau,
-                        emit * tgt + (1.0 - emit) * prev_tgt,
-                        jnp.where(emit > 0, tgt, prev_tgt)))
-        cnp = soft.select(tau, jnp.minimum(trig_buf[rslot], 1.0),
-                          (trig_buf[rslot] > 0).astype(jnp.float32))
-        tgt_rx = tgt_buf[rslot]
-        trig_buf = trig_buf.at[rslot].set(0.0)
+    with obs.scope("fluid.notify"):
+        # Each stage decides who emits (suppression/coalescing window) and
+        # *when* the payload lands: NP/ENP after the end-to-end RTT, FNCC
+        # from the marking hop's position on the return path.  The delay
+        # line is sized >= max(rtt)+1 (see delay_depth), so the modulo is a
+        # ring-buffer index, never an aliased (shortened) feedback delay.
+        # ``emit`` is a [F] float emission intensity (exact 0/1 hard,
+        # fractional soft) — it is also the per-step control-traffic
+        # counter surfaced in the trace below.
+        (emit, np_tmr, wslot), cc_notif = cc.dispatch(
+            cc.NOTIFICATION, par.notif_code, par.notif,
+            cc.NotifCtx(marked=mark_lvl, mark_fh=mark_fh, np_tmr_t=np_tmr_t,
+                        hops=hops, rtt=sd.rtt, t=st.t, D=D, tau=tau),
+            st.cc, in_kernel=in_kernel)
+        rslot = st.t % D
+        if fused:
+            # branch-free ring ops: one-hot compare instead of scatters.
+            # Exact: each (wslot[f], f) cell gets the same single add/set,
+            # every other cell an exact +0.0 / keep; the read row rslot is
+            # disjoint from all write slots (0 < rtt < D).
+            d_iota = jnp.arange(D, dtype=jnp.int32)[:, None]       # [D, 1]
+            w_hot = d_iota == wslot[None, :]                       # [D, F]
+            trig_buf = st.trig_buf + jnp.where(w_hot, emit[None, :], 0.0)
+            tgt_buf = soft.select(
+                tau,
+                jnp.where(w_hot,
+                          emit[None, :] * tgt[None, :]
+                          + (1.0 - emit[None, :]) * st.tgt_buf,
+                          st.tgt_buf),
+                jnp.where(w_hot & (emit[None, :] > 0), tgt[None, :],
+                          st.tgt_buf))
+            cnp = soft.select(tau, jnp.minimum(trig_buf[rslot], 1.0),
+                              (trig_buf[rslot] > 0).astype(jnp.float32))
+            tgt_rx = tgt_buf[rslot]
+            trig_buf = jnp.where(d_iota == rslot, 0.0, trig_buf)
+        else:
+            trig_buf = st.trig_buf.at[wslot, fidx].add(emit)
+            prev_tgt = st.tgt_buf[wslot, fidx]
+            tgt_buf = st.tgt_buf.at[wslot, fidx].set(
+                soft.select(tau,
+                            emit * tgt + (1.0 - emit) * prev_tgt,
+                            jnp.where(emit > 0, tgt, prev_tgt)))
+            cnp = soft.select(tau, jnp.minimum(trig_buf[rslot], 1.0),
+                              (trig_buf[rslot] > 0).astype(jnp.float32))
+            tgt_rx = tgt_buf[rslot]
+            trig_buf = trig_buf.at[rslot].set(0.0)
 
     # ---- 6. reaction (cc.REACTION dispatch), branchless -------------------
-    # Every registered reaction (fixed-rate PFC source / DCQCN RP / the
-    # paper's ERP / delay-target swift / ...) advances from the same
-    # context; the traced ``react_code`` selects one, and stages with a
-    # Pallas form route through it behind ``use_kernels``.  The queuing-
-    # delay estimate (bytes queued along the path / line rate) feeds the
-    # mark-free delay-based stages.
-    qdelay = jnp.sum(jnp.where(holds_queue, qh, 0.0),
-                     axis=1) / par.line_rate
-    react_out, cc_react = cc.dispatch(
-        cc.REACTION, par.react_code, par.react,
-        cc.ReactCtx(rate=st.rate, rp_target=st.rp_target, alpha=st.alpha,
-                    byte_cnt=st.byte_cnt, tmr=st.tmr,
-                    alpha_tmr=st.alpha_tmr, bc_stage=st.bc_stage,
-                    t_stage=st.t_stage, hold=st.hold, cnp=cnp,
-                    tgt_rx=tgt_rx, qdelay=qdelay, jitter=sd.jitter,
-                    gen_rate=sd.gen_rate, line_rate=par.line_rate, dt=dt,
-                    tau=tau),
-        st.cc, use_kernels=use_kernels, interpret=interpret,
-        in_kernel=in_kernel, packed=packed_react)
+    with obs.scope("fluid.react"):
+        # Every registered reaction (fixed-rate PFC source / DCQCN RP / the
+        # paper's ERP / delay-target swift / ...) advances from the same
+        # context; the traced ``react_code`` selects one, and stages with a
+        # Pallas form route through it behind ``use_kernels``.  The queuing-
+        # delay estimate (bytes queued along the path / line rate) feeds the
+        # mark-free delay-based stages.
+        qdelay = jnp.sum(jnp.where(holds_queue, qh, 0.0),
+                         axis=1) / par.line_rate
+        react_out, cc_react = cc.dispatch(
+            cc.REACTION, par.react_code, par.react,
+            cc.ReactCtx(rate=st.rate, rp_target=st.rp_target, alpha=st.alpha,
+                        byte_cnt=st.byte_cnt, tmr=st.tmr,
+                        alpha_tmr=st.alpha_tmr, bc_stage=st.bc_stage,
+                        t_stage=st.t_stage, hold=st.hold, cnp=cnp,
+                        tgt_rx=tgt_rx, qdelay=qdelay, jitter=sd.jitter,
+                        gen_rate=sd.gen_rate, line_rate=par.line_rate, dt=dt,
+                        tau=tau),
+            st.cc, use_kernels=use_kernels, interpret=interpret,
+            in_kernel=in_kernel, packed=packed_react)
 
-    new = FluidState(
-        qh=qh, nicq=nicq, delivered=delivered, offered=offered,
-        dropped=dropped, est=est, paused=paused, rate=react_out.rate,
-        rp_target=react_out.rp_target, alpha=react_out.alpha,
-        byte_cnt=react_out.byte_cnt, tmr=react_out.tmr,
-        alpha_tmr=react_out.alpha_tmr, bc_stage=react_out.bc_stage,
-        t_stage=react_out.t_stage, hold=react_out.hold, np_tmr=np_tmr,
-        trig_buf=trig_buf, tgt_buf=tgt_buf, path_idx=path_idx,
-        cc={**st.cc, **cc_mark, **cc_notif, **cc_react}, t=st.t + 1)
-    rate = react_out.rate
-    trace = StepTrace(
-        delivered=delivered, rate=rate, inst_thr=deliv_step / dt,
-        max_q=jnp.max(B),
-        n_paused=jnp.sum((paused > 0.5).astype(jnp.int32)),
-        marked=marked, cnp=cnp > 0,
-        n_nonmin=jnp.sum((path_idx > 0).astype(jnp.int32)),
-        ctrl=emit,
-        pause_time=jnp.sum(paused) * dt,
-        vc_stall=paused.reshape(L, V).sum(axis=0) * dt)
+        new = FluidState(
+            qh=qh, nicq=nicq, delivered=delivered, offered=offered,
+            dropped=dropped, est=est, paused=paused, rate=react_out.rate,
+            rp_target=react_out.rp_target, alpha=react_out.alpha,
+            byte_cnt=react_out.byte_cnt, tmr=react_out.tmr,
+            alpha_tmr=react_out.alpha_tmr, bc_stage=react_out.bc_stage,
+            t_stage=react_out.t_stage, hold=react_out.hold, np_tmr=np_tmr,
+            trig_buf=trig_buf, tgt_buf=tgt_buf, path_idx=path_idx,
+            cc={**st.cc, **cc_mark, **cc_notif, **cc_react}, t=st.t + 1)
+
+    # ---- trace (the scan folds it into the decimated sample) --------------
+    with obs.scope("fluid.decimate"):
+        rate = react_out.rate
+        trace = StepTrace(
+            delivered=delivered, rate=rate, inst_thr=deliv_step / dt,
+            max_q=jnp.max(B),
+            n_paused=jnp.sum((paused > 0.5).astype(jnp.int32)),
+            marked=marked, cnp=cnp > 0,
+            n_nonmin=jnp.sum((path_idx > 0).astype(jnp.int32)),
+            ctrl=emit,
+            pause_time=jnp.sum(paused) * dt,
+            vc_stall=paused.reshape(L, V).sum(axis=0) * dt)
     return new, trace
 
 

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import obs
 from .fluid import (FluidState, Scenario, StepTrace, init_state,
                     make_step_fn)
 from .params import CCConfig, CCScheme
@@ -60,25 +61,27 @@ def _acc_update(acc, tr: StepTrace):
     the single definition is what keeps the two trace paths bitwise
     identical."""
     mq, npz, mk, cn, nm, ct, pt, vs = acc
-    return (jnp.maximum(mq, tr.max_q),
-            jnp.maximum(npz, tr.n_paused),
-            mk + tr.marked.astype(jnp.int32),
-            cn + tr.cnp.astype(jnp.int32),
-            jnp.maximum(nm, tr.n_nonmin),
-            ct + tr.ctrl,
-            pt + tr.pause_time,
-            vs + tr.vc_stall)
+    with obs.scope("fluid.decimate"):
+        return (jnp.maximum(mq, tr.max_q),
+                jnp.maximum(npz, tr.n_paused),
+                mk + tr.marked.astype(jnp.int32),
+                cn + tr.cnp.astype(jnp.int32),
+                jnp.maximum(nm, tr.n_nonmin),
+                ct + tr.ctrl,
+                pt + tr.pause_time,
+                vs + tr.vc_stall)
 
 
 def _window_sample(st: FluidState, d0, acc, trace_every: int,
                    dt: float) -> TraceSample:
     """One TraceSample from the window-end state + accumulators."""
     mq, npz, mk, cn, nm, ct, pt, vs = acc
-    return TraceSample(
-        delivered=st.delivered, rate=st.rate,
-        inst_thr=(st.delivered - d0) / jnp.float32(trace_every * dt),
-        max_q=mq, n_paused=npz, marked=mk, cnp=cn, n_nonmin=nm,
-        ctrl=ct, pause_time=pt, vc_stall=vs)
+    with obs.scope("fluid.decimate"):
+        return TraceSample(
+            delivered=st.delivered, rate=st.rate,
+            inst_thr=(st.delivered - d0) / jnp.float32(trace_every * dt),
+            max_q=mq, n_paused=npz, marked=mk, cnp=cn, n_nonmin=nm,
+            ctrl=ct, pause_time=pt, vc_stall=vs)
 
 
 def decimating_scan(step, st: FluidState, n_samples: int,

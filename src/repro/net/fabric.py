@@ -24,6 +24,7 @@ import functools
 
 import numpy as np
 
+from repro.core import obs
 from repro.core.topology import Topology, make_clos3
 
 from .routing import (RouteSet, RouteTable, clos_route_set,
@@ -238,6 +239,7 @@ def _build_topo(spec: FabricSpec, line_rate: float) -> Topology:
 
 
 @functools.lru_cache(maxsize=64)
+@obs.span("repro.routes.build")
 def _build_table(spec: FabricSpec) -> RouteTable:
     """Build + validate one fabric's route table; cached per spec."""
     if spec.kind == "clos3":
@@ -275,6 +277,7 @@ def _flow_route_set(spec: FabricSpec, pairs: tuple, k: int, seed: int):
 
 
 @functools.lru_cache(maxsize=64)
+@obs.span("repro.routes.build")
 def _build_route_set(spec: FabricSpec, k: int, seed: int) -> RouteSet:
     """Build + validate one fabric's multi-path RouteSet; cached."""
     if spec.kind == "clos3":
