@@ -43,9 +43,10 @@ import numpy as np
 from . import cc, obs
 from .exec_cache import ExecutableCache, structural_signature
 from .fluid import (FluidState, Scenario, check_routing_paths,
-                    clamp_dense_rows, delay_depth, dense_reduce_rows,
-                    fluid_step, init_state, kernel_tier, scenario_device,
-                    step_body_fn, step_params)
+                    clamp_dense_rows, delay_depth, dense_engine,
+                    dense_reduce_rows, fluid_step, init_state,
+                    kernel_tier, scenario_device, step_body_fn,
+                    step_params)
 from .params import CCConfig, CCSpec
 from .routing import PAD, route_hops
 from .simulator import (SimResult, _acc_update, _resolve_steps,
@@ -436,16 +437,17 @@ def stack_scenarios(scns: Sequence[Scenario], n_vcs: int = 1):
 def batch_dense_rows(padded: Sequence[Scenario], n_vcs: int,
                      reduce: str = "fused",
                      dense_rows: int | None = None) -> int:
-    """The dense-CSR row count one batch of padded scenarios runs with.
+    """The longest contributor list (or pinned row count) one batch of
+    padded scenarios runs its dense reduction with (``dense_engine``).
 
-    The static row count must cover every run in the batch; any
+    The row count must cover every run in the batch; any
     over-skew scenario disables the dense engine for the batch (0 = the
     segment-sum path, bit-identical), and the batch-wide max is
     re-clamped so one skewed run can't force the rest onto an oversized
     table.  An explicit ``dense_rows`` that cannot cover the batch also
     falls back to 0.  Shared by ``Sweep.run`` and the fleet planner so
-    a shard pinned to the plan's value runs the exact program the full
-    batch would.
+    every shard pinned to the plan's value runs one program, bitwise
+    the full batch's results.
     """
     if reduce != "fused":
         return 0
@@ -516,7 +518,7 @@ SWEEP_EXEC_CACHE = ExecutableCache(capacity=32, name="sweep")
 
 
 def _sweep_scan_fn(n_samples: int, trace_every: int, dt: float,
-                   n_switches: int, reduce: str, dense_rows: int,
+                   n_switches: int, reduce: str, dense_blocks: tuple,
                    use_kernels: "bool | str", interpret: bool,
                    n_vcs: int, substep_block: int, mesh):
     """Build the (unjitted) sweep scan for one static configuration.
@@ -536,7 +538,7 @@ def _sweep_scan_fn(n_samples: int, trace_every: int, dt: float,
     tier = kernel_tier(use_kernels)
     if tier == "mega":
         body = step_body_fn(dt=dt, n_switches=n_switches, reduce=reduce,
-                            dense_rows=dense_rows, n_vcs=n_vcs)
+                            dense_blocks=dense_blocks, n_vcs=n_vcs)
         from repro.kernels.fluid_step import megastep_block
 
         def scan_fn(st_b, sd_b, par_b):
@@ -566,7 +568,7 @@ def _sweep_scan_fn(n_samples: int, trace_every: int, dt: float,
                 return jax.vmap(
                     lambda s, sd, par, pk: fluid_step(
                         s, sd, par, dt=dt, n_switches=n_switches,
-                        reduce=reduce, dense_rows=dense_rows,
+                        reduce=reduce, dense_blocks=dense_blocks,
                         use_kernels=use_kernels, interpret=interpret,
                         n_vcs=n_vcs, packed_react=pk)
                 )(st, sd_b, par_b, packed_b)
@@ -711,6 +713,16 @@ class Sweep:
         n_samples, k = _resolve_steps(cfg0, n_steps, trace_every)
         scns = [p.scenario for p in self.points]
         sd_b, padded, n_sw = stack_scenarios(scns, n_vcs=self.n_vcs)
+        dense_blocks, layout = dense_engine(
+            padded, self.n_vcs,
+            batch_dense_rows(padded, self.n_vcs, reduce, dense_rows),
+            pinned=dense_rows is not None,
+            mega=kernel_tier(use_kernels) == "mega")
+        if layout is not None:
+            red_idx, red_back, n_rows = layout
+            sd_b = sd_b._replace(red_idx=red_idx, red_back=red_back)
+            obs.count("sweep.reduce_slots", red_idx.size)
+            obs.count("sweep.reduce_rows", n_rows)
         if min_switches is not None:
             n_sw = max(n_sw, int(min_switches))
         D = max(delay_depth(s) for s in padded)
@@ -734,14 +746,12 @@ class Sweep:
                 [x] + [x[-1:]] * pad_r, axis=0)
             st_b, sd_b, par_b = (jax.tree.map(rep, t)
                                  for t in (st_b, sd_b, par_b))
-        dense_rows = batch_dense_rows(padded, self.n_vcs, reduce,
-                                      dense_rows)
         # the substep-block depth (the megakernel's in-kernel scan
         # length) is part of the executable signature: a mega sweep
         # re-blocked at a different trace_every is a different program
         substep_block = k if kernel_tier(use_kernels) == "mega" else 0
         static = (n_samples, k, float(cfg0.sim.dt), n_sw, reduce,
-                  int(dense_rows), use_kernels, interpret, self.n_vcs,
+                  dense_blocks, use_kernels, interpret, self.n_vcs,
                   substep_block, mesh)
         return static, (st_b, sd_b, par_b), n_samples, k
 
@@ -776,10 +786,12 @@ class Sweep:
           * ``min_delay_slots`` floors the delay-line depth (normally
             sized from the batch's worst RTT, which varies with batch
             mix; extra slots are inert by construction);
-          * ``dense_rows`` overrides the dense-CSR row count (``None``
-            = derive from the batch; an explicit value that cannot
-            cover the batch's skew falls back to 0, the segment-sum
-            path, which is bit-identical).
+          * ``dense_rows`` pins the dense reduction to the rectangle
+            of every queue x that many positions, whose shape does not
+            depend on the batch's content (``None`` = the jagged layout
+            derived from the batch; a value that cannot cover the
+            batch's skew falls back to 0, the segment-sum path; all are
+            bit-identical).
 
         ``temperature`` > 0 runs the soft-relaxed dynamics
         (``repro.tune.soft``) — smoothed marking/PFC/notification

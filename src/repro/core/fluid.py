@@ -165,6 +165,11 @@ class ScenarioDev(NamedTuple):
     # stably sorted by sink switch (host sinks -> scratch segment)
     pool_perm: jnp.ndarray    # [L] int32
     pool_seg: jnp.ndarray     # [L] int32
+    # the dense reduction's jagged layout (``dense_layout``, attached
+    # per batch): each slot's row of the flattened channels, and each
+    # queue's rank among the layout's rows.  None on the other engines.
+    red_idx: "jnp.ndarray | None" = None     # [T] int32
+    red_back: "jnp.ndarray | None" = None    # [S + 1] int32
 
 
 class StepParams(NamedTuple):
@@ -338,16 +343,22 @@ def _cached_put(x: np.ndarray, dtype) -> jnp.ndarray:
                      lambda: jnp.asarray(x))
 
 
+def _incidence_key(alt_routes: np.ndarray, n_links: int,
+                   vc: np.ndarray | None, n_vcs: int) -> tuple:
+    key = _digest(alt_routes) + (n_links, n_vcs)
+    if n_vcs > 1 and vc is not None:
+        key = key + _digest(vc)
+    return key
+
+
 def _incidence(alt_routes: np.ndarray, n_links: int,
                vc: np.ndarray | None = None, n_vcs: int = 1):
     """``link_incidence`` memoised on route-stack content (the sort is
     O(FKH log FKH) on host; grid points sharing a fabric pay it once).
     The key carries the VC layout too: the same routes under a
     different VC assignment sort into different (wire, VC) queues."""
-    key = _digest(alt_routes) + (n_links, n_vcs)
-    if n_vcs > 1 and vc is not None:
-        key = key + _digest(vc)
-    return _memo_lru(_INC_CACHE, _INC_CACHE_SIZE, key,
+    return _memo_lru(_INC_CACHE, _INC_CACHE_SIZE,
+                     _incidence_key(alt_routes, n_links, vc, n_vcs),
                      lambda: link_incidence(alt_routes, n_links,
                                             vc=vc, n_vcs=n_vcs))
 
@@ -405,29 +416,190 @@ def _scenario_vc(scn: Scenario, alt_routes: np.ndarray,
     return np.where(alt_routes == PAD, 0, vc).astype(np.int32)
 
 
-def dense_reduce_rows(scn: Scenario, n_vcs: int = 1) -> int:
-    """Static row count for the dense-CSR fused reduction (0 = disable).
-
-    The fused reduction can run scatter-free: lay each (wire, VC)
-    queue's (sorted) contributors out as a dense [L * n_vcs, rows]
-    table derived from the CSR offsets and accumulate positions
-    left-to-right — bit-identical to the sequential scatter, but pure
-    gathers + vector adds.  The table blows up with load skew (rows =
-    max contributors on one queue), so scenarios past
-    ``DENSE_ROWS_CAP`` — or whose table would dwarf the incidence
-    itself — report 0 and use the segment-sum engine.
-    """
+def _queue_incidence(scn: Scenario, n_vcs: int):
+    """``(key, perm, off, S)``: a scenario's per-queue incidence
+    (``link_incidence``, memoised) with its content key."""
     alt = scn.routes[:, None, :] if scn.alt_routes is None \
         else scn.alt_routes
     alt = np.asarray(alt, np.int32)
     L = scn.capacity.shape[0]
-    if L == 0:
-        return 0
     vc = _scenario_vc(scn, alt, n_vcs)
-    S = L * n_vcs
-    _, _, off = _incidence(alt, L, vc, n_vcs)
+    key = _incidence_key(alt, L, vc, n_vcs)
+    perm, _, off = _memo_lru(_INC_CACHE, _INC_CACHE_SIZE, key,
+                             lambda: link_incidence(alt, L, vc=vc,
+                                                    n_vcs=n_vcs))
+    return key, perm, off, L * n_vcs
+
+
+def dense_reduce_rows(scn: Scenario, n_vcs: int = 1) -> int:
+    """Longest per-queue contributor list, for the dense reduction
+    (0 = disable).
+
+    The fused reduction can run scatter-free: gather each (wire, VC)
+    queue's (sorted) contributors position by position and accumulate
+    positions left-to-right — bit-identical to the sequential scatter,
+    but pure gathers + vector adds (``dense_layout``).  Scenarios whose
+    longest list passes ``DENSE_ROWS_CAP`` — or whose rectangle of
+    queues x that many positions would dwarf the incidence itself —
+    report 0 and use the segment-sum engine.
+    """
+    if scn.capacity.shape[0] == 0:
+        return 0
+    _, perm, off, S = _queue_incidence(scn, n_vcs)
     ml = int(np.max(off[1:S + 1] - off[:S]))
-    return clamp_dense_rows(ml, S, alt.size)
+    return clamp_dense_rows(ml, S, perm.size)
+
+
+def jagged_blocks(counts: np.ndarray) -> tuple[tuple[int, int], ...]:
+    """Block shapes ``((width, positions), ...)`` of the jagged-diagonal
+    reduction layout for ``[R, S]`` per-queue contributor counts.
+
+    Each run ranks its queues by contributor count, longest first.
+    Position p is ``w_p`` slots wide: the most queues, over the runs,
+    that hold more than p contributors, so no slot is kept for a queue
+    that is empty there in every run.  Consecutive positions share a
+    block (one small ``[positions, width]`` rectangle at the block's
+    first, widest, width) until the width falls to half of that or
+    below, which keeps the padding under the real slots.
+    """
+    counts = np.asarray(counts, np.int64)
+    counts = counts.reshape(-1, counts.shape[-1])
+    P = int(counts.max(initial=0))
+    S = counts.shape[1]
+    pos = np.arange(P)
+    w = np.max([S - np.searchsorted(np.sort(c), pos, side="right")
+                for c in counts], axis=0)
+    blocks, p = [], 0
+    while p < P:
+        n = 1
+        while p + n < P and 2 * w[p + n] > w[p]:
+            n += 1
+        blocks.append((int(w[p]), n))
+        p += n
+    return tuple(blocks)
+
+
+def jagged_index(perm: np.ndarray, off: np.ndarray, n_queues: int,
+                 blocks: tuple) -> tuple[np.ndarray, np.ndarray]:
+    """One run's ``(idx, back)`` under the block shapes ``blocks``.
+
+    ``idx`` [sum of width x positions] lists, block by block, position
+    by position, the rank-ordered queues' contributor rows in the
+    flattened ``[F*K*H]`` channel order (``perm`` composed in); a slot
+    past its queue's list reads the sentinel ``F*K*H``.  ``back``
+    [S + 1] maps each queue (and the scratch slot S) to its rank among
+    the first block's rows, or to the zero row past them.  A layout
+    whose slots cannot hold every contributor raises.
+    """
+    N = perm.shape[0]
+    cnt = (off[1:n_queues + 1] - off[:n_queues]).astype(np.int64)
+    order = np.argsort(-cnt, kind="stable")
+    idx, p0 = [], 0
+    for w, n in blocks:
+        q = order[:w]
+        p = p0 + np.arange(n)[:, None]
+        ok = p < cnt[q][None, :]
+        idx.append(np.where(ok, perm[np.where(ok, off[q] + p, 0)], N)
+                   .reshape(-1))
+        p0 += n
+    idx = np.concatenate(idx).astype(np.int32)
+    if np.count_nonzero(idx != N) != cnt.sum():
+        raise ValueError(
+            f"reduction layout {blocks} cannot hold every contributor "
+            f"(longest queue list {cnt.max(initial=0)})")
+    W = blocks[0][0]
+    rank = np.empty((n_queues,), np.int64)
+    rank[order] = np.arange(n_queues)
+    back = np.append(np.minimum(rank, W), W).astype(np.int32)
+    return idx, back
+
+
+#: Jagged layouts of whole batches, keyed on their runs' incidence.
+_LAYOUT_CACHE: "collections.OrderedDict[tuple, tuple]" = \
+    collections.OrderedDict()
+_LAYOUT_CACHE_SIZE = 32
+
+
+def dense_layout(scns, n_vcs: int = 1, rows: int | None = None):
+    """``(blocks, red_idx [R, T], red_back [R, S + 1], n_rows)`` of the
+    dense reduction over a batch of same-shape (padded) scenarios.
+
+    ``rows=None`` derives the jagged blocks from the batch's counts
+    (``jagged_blocks``); an explicit ``rows`` is the plain rectangle,
+    one block of every queue x ``rows`` positions, whose shape does not
+    depend on the batch's content.  ``n_rows`` counts the real
+    contributors over the runs.  Memoised on the runs' incidence
+    content, and uploaded through the placement cache, so relaunching
+    a batch stages nothing new.
+    """
+    incs = [_queue_incidence(s, n_vcs) for s in scns]
+    key = (tuple(k for k, *_ in incs), rows)
+
+    def build():
+        S = incs[0][3]
+        counts = np.stack([off[1:S + 1] - off[:S] for _, _, off, _ in incs])
+        blocks = jagged_blocks(counts) if rows is None \
+            else ((S, int(rows)),)
+        idx, back = zip(*(jagged_index(perm, off, S, blocks)
+                          for _, perm, off, _ in incs))
+        return (blocks, _cached_put(np.stack(idx), np.int32),
+                _cached_put(np.stack(back), np.int32), int(counts.sum()))
+
+    return _memo_lru(_LAYOUT_CACHE, _LAYOUT_CACHE_SIZE, key, build)
+
+
+def dense_engine(scns, n_vcs: int, rows: int, *, pinned: bool,
+                 mega: bool = False):
+    """``(dense_blocks, layout)``: the dense reduction a batch of
+    padded scenarios runs with.
+
+    ``rows`` is the batch's longest contributor list, or a pinned row
+    count; 0 keeps the segment-sum engine (``((), None)``).  The
+    megakernel walks its own rectangle, ``((S, rows),)``, and takes no
+    layout; otherwise ``layout`` is ``(red_idx, red_back, n_rows)`` of
+    ``dense_layout``: the jagged layout, or with ``pinned`` the
+    rectangle of every queue x ``rows`` positions.
+    """
+    if not rows:
+        return (), None
+    if mega:
+        return ((scns[0].capacity.shape[0] * n_vcs, int(rows)),), None
+    blocks, idx, back, n_rows = dense_layout(
+        scns, n_vcs, int(rows) if pinned else None)
+    return blocks, (idx, back, n_rows)
+
+
+def jagged_sums(data: jnp.ndarray, idx: jnp.ndarray, back: jnp.ndarray,
+                blocks: tuple) -> list:
+    """Per-queue sums ``[S + 1]`` of each channel of ``data`` [N, C]
+    under one run's jagged layout (``dense_layout``).
+
+    One gather of every slot straight from the channels (the sentinel
+    N reads an appended zero row), then, block by block, positions
+    added left to right onto the first ``width`` ranked queues (a loop
+    over a block's positions: on a v5e it beat the unrolled chain), then
+    each queue's sum taken back from its rank (empty queues and the
+    scratch slot read 0).  Every queue adds ``0 + d0 + d1 + ...`` in
+    its incidence order: bit-identical to the sequential scatter.
+    """
+    C = data.shape[-1]
+    zero = jnp.zeros((1, C), jnp.float32)
+    table = jnp.take(jnp.concatenate([data, zero]), idx, axis=0)
+    W = blocks[0][0]
+    acc = jnp.zeros((W, C), jnp.float32)
+    start = 0
+    for w, n in blocks:
+        tab = table[start:start + w * n].reshape(n, w, C)
+        start += w * n
+        if n == 1:
+            head = acc[:w] + tab[0]
+        else:
+            head = jax.lax.fori_loop(
+                0, n, lambda p, h, tab=tab: h + jax.lax.dynamic_index_in_dim(
+                    tab, p, keepdims=False), acc[:w])
+        acc = head if w == W else jnp.concatenate([head, acc[w:]])
+    sums = jnp.take(jnp.concatenate([acc, zero]), back, axis=0)
+    return [sums[:, c] for c in range(C)]
 
 
 def scenario_device(scn: Scenario, n_vcs: int = 1) -> ScenarioDev:
@@ -602,7 +774,7 @@ def _refuse_soft_kernels(tier: str, temperature) -> None:
 
 def fluid_step(st: FluidState, sd: ScenarioDev, par: StepParams, *,
                dt: float, n_switches: int, reduce: str = "fused",
-               dense_rows: int = 0, use_kernels: "bool | str" = False,
+               dense_blocks: tuple = (), use_kernels: "bool | str" = False,
                interpret: bool = False, n_vcs: int = 1,
                packed_react: dict | None = None):
     """One ``dt`` update: (state, scenario, params) -> (state, trace).
@@ -624,12 +796,14 @@ def fluid_step(st: FluidState, sd: ScenarioDev, par: StepParams, *,
       * ``"scat"`` — the legacy one-scatter-per-quantity path, kept as
         the parity/benchmark baseline.
 
-    ``dense_rows`` (static, from ``dense_reduce_rows``) upgrades the
-    ``"fused"`` engine to the scatter-free dense-CSR form: each pass
-    gathers contributors into a [L, dense_rows] table and accumulates
-    positions left-to-right — the fastest path when link load is not
-    pathologically skewed, still bit-identical.  Must cover the longest
-    per-link contributor list; 0 keeps the segment-sum engine.
+    ``dense_blocks`` (static, the block shapes of ``dense_layout``)
+    upgrades the ``"fused"`` engine to the scatter-free dense form:
+    each pass gathers the contributors of the jagged layout in
+    ``sd.red_idx`` straight from the channels and accumulates positions
+    left-to-right (``jagged_sums``) — the fastest path when link load
+    is not pathologically skewed, still bit-identical.  ``()`` keeps
+    the segment-sum engine.  The megakernel takes one block of every
+    queue, ``((S, rows),)``, and walks its own rectangle.
 
     ``use_kernels`` selects the Pallas tier (see ``kernel_tier``):
       * ``False`` — pure jnp step (the parity reference).
@@ -669,22 +843,23 @@ def fluid_step(st: FluidState, sd: ScenarioDev, par: StepParams, *,
     if tier == "mega":
         from repro.kernels.fluid_step import megastep
         body = step_body_fn(dt=dt, n_switches=n_switches, reduce=reduce,
-                            dense_rows=dense_rows, n_vcs=n_vcs)
+                            dense_blocks=dense_blocks, n_vcs=n_vcs)
         return megastep(st, sd, par, body=body, interpret=interpret)
     return _step_body(st, sd, par, dt=dt, n_switches=n_switches,
-                      reduce=reduce, dense_rows=dense_rows,
+                      reduce=reduce, dense_blocks=dense_blocks,
                       use_kernels=(tier == "flow"), interpret=interpret,
                       n_vcs=n_vcs, packed_react=packed_react)
 
 
 def step_body_fn(*, dt: float, n_switches: int, reduce: str = "fused",
-                 dense_rows: int = 0, n_vcs: int = 1):
+                 dense_blocks: tuple = (), n_vcs: int = 1):
     """The in-kernel step closure: ``(st, sd, par) -> (state, trace)``.
 
     This is the single definition of the update the megakernel executes
     — statics baked, stage dispatch through each stage's
     ``kernel_body`` (falling back to its jnp ``step``), and the dense
-    engine in its tiled on-chip form.  It is the *same* jnp math as the
+    engine in its tiled on-chip form (``dense_blocks`` one block of
+    every queue: ``((S, rows),)``).  It is the *same* jnp math as the
     plain path (same primitives, same order), which is what holds the
     mega tier bit-exact to the reference.
     """
@@ -696,7 +871,7 @@ def step_body_fn(*, dt: float, n_switches: int, reduce: str = "fused",
 
     def body(st, sd, par):
         return _step_body(st, sd, par, dt=dt, n_switches=n_switches,
-                          reduce=reduce, dense_rows=dense_rows,
+                          reduce=reduce, dense_blocks=dense_blocks,
                           use_kernels=False, interpret=False,
                           n_vcs=n_vcs, dense_tiled=True, in_kernel=True)
 
@@ -705,14 +880,14 @@ def step_body_fn(*, dt: float, n_switches: int, reduce: str = "fused",
 
 def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
                dt: float, n_switches: int, reduce: str,
-               dense_rows: int, use_kernels: bool, interpret: bool,
+               dense_blocks: tuple, use_kernels: bool, interpret: bool,
                n_vcs: int, dense_tiled: bool = False,
                in_kernel: bool = False,
                packed_react: dict | None = None):
     """The step update itself (see ``fluid_step`` for semantics).
 
-    ``dense_tiled`` swaps the dense-CSR accumulation for its
-    ``[S, block]``-tiled on-chip form (bit-identical, see
+    ``dense_tiled`` swaps the jagged dense accumulation for the
+    megakernel's ``[S, block]``-tiled rectangle (bit-identical, see
     ``repro.kernels.fluid_step.dense_reduce_tiled``); ``in_kernel``
     marks that this trace runs inside the megakernel launch, which
     routes every cc dispatch through the stages' ``kernel_body``
@@ -758,10 +933,11 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
         return r, h
 
     with obs.scope("fluid.reduce"):
-        if fused and dense_rows:
-            # dense-CSR row table, shared by every reduction pass this
-            # step: position p of queue q reads sorted row off[q] + p (the
-            # sentinel F*K*H reads an all-zero row).
+        if fused and dense_blocks and dense_tiled:
+            # the megakernel's rectangle, shared by every reduction pass
+            # this step: position p of queue q reads sorted row
+            # off[q] + p (the sentinel F*K*H reads an all-zero row).
+            ((_, dense_rows),) = dense_blocks
             _lens = sd.red_off[1:S + 1] - sd.red_off[:S]        # [S]
             _pos = jnp.arange(dense_rows, dtype=jnp.int32)[None, :]
             dense_idx = jnp.where(_pos < _lens[:, None],
@@ -776,9 +952,10 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
         order; one [F*K*H, C] pass produces every [S+1] per-(wire, VC)
         vector at once instead of C scatters (S == L when V == 1, in
         which case "queue" is just "wire").  The pass is summed by
-        the dense-CSR tiles, the Pallas kernel, or a sorted segment
-        sum — all three accumulate each queue's contributors in the
-        same order, so the result is bit-identical across engines.
+        the jagged dense layout, the megakernel's tiles, the Pallas
+        kernel, or a sorted segment sum — all accumulate each queue's
+        contributors in the same order, so the result is bit-identical
+        across engines.
         """
         with obs.scope("fluid.reduce"):
             data = jnp.stack(channels, axis=-1)                 # [F, H, C]
@@ -788,30 +965,21 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
                           == k_sel[:, None])                    # [F, K]
                 data = data[:, None] * \
                     onehot[:, :, None, None].astype(jnp.float32)
-            data = jnp.take(data.reshape(F * K * H, C), sd.red_perm, axis=0)
+            data = data.reshape(F * K * H, C)
+            if dense_blocks and reduce == "fused" and not dense_tiled:
+                return jagged_sums(data, sd.red_idx, sd.red_back,
+                                   dense_blocks)
+            data = jnp.take(data, sd.red_perm, axis=0)
             if reduce == "pallas":
                 from repro.kernels.fluid_reduce import segment_reduce
                 sums = segment_reduce(data, sd.red_seg, S + 1,
                                       interpret=interpret)
-            elif dense_rows:
+            elif dense_blocks:                 # the megakernel's rectangle
+                from repro.kernels.fluid_step import dense_reduce_tiled
                 data_ext = jnp.concatenate(
                     [data, jnp.zeros((1, C), jnp.float32)])
-                if dense_tiled:
-                    from repro.kernels.fluid_step import dense_reduce_tiled
-                    sums = dense_reduce_tiled(data_ext, dense_idx, S,
-                                              dense_rows)
-                else:
-                    dense = jnp.take(data_ext, dense_idx,
-                                     axis=0).reshape(S, dense_rows, C)
-
-                    def body(p, acc):
-                        return acc + jax.lax.dynamic_slice_in_dim(
-                            dense, p, 1, 1)[:, 0]
-
-                    acc = jax.lax.fori_loop(0, dense_rows, body,
-                                            jnp.zeros((S, C), jnp.float32))
-                    sums = jnp.concatenate(
-                        [acc, jnp.zeros((1, C), jnp.float32)])
+                sums = dense_reduce_tiled(data_ext, dense_idx, S,
+                                          dense_rows)
             else:
                 sums = jax.ops.segment_sum(data, sd.red_seg,
                                            num_segments=S + 1,
@@ -1207,8 +1375,9 @@ def make_step_fn(scn: Scenario, cfg: "CCConfig | CCSpec",
     default the depth is sized from the scenario (``delay_depth``).
     ``reduce`` / ``use_kernels`` / ``interpret`` select the reduction
     engine and the Pallas tier (see ``fluid_step``);
-    ``dense_rows=None`` auto-sizes the dense-CSR engine from the
-    scenario (``dense_reduce_rows``), 0 forces the segment-sum engine.
+    ``dense_rows=None`` lays the dense reduction out from the
+    scenario (``dense_layout``), an explicit count pins its rectangle
+    (``dense_engine``), 0 forces the segment-sum engine.
     ``temperature`` selects the soft-relaxed dynamics (``repro.tune``)
     — only valid on the pure-jnp tier, since the kernels implement the
     hard model only (a positive value under any kernel tier raises).
@@ -1228,9 +1397,13 @@ def make_step_fn(scn: Scenario, cfg: "CCConfig | CCSpec",
     par = step_params(cfg, temperature=temperature)
     n_sw = int(scn.n_switches)
     dt = float(cfg.sim.dt)
-    if dense_rows is None:
-        dense_rows = dense_reduce_rows(scn, n_vcs) \
-            if reduce == "fused" else 0
+    rows = 0 if reduce != "fused" else dense_reduce_rows(scn, n_vcs) \
+        if dense_rows is None else int(dense_rows)
+    dense_blocks, layout = dense_engine(
+        [scn], n_vcs, rows, pinned=dense_rows is not None,
+        mega=tier == "mega")
+    if layout is not None:
+        sd = sd._replace(red_idx=layout[0][0], red_back=layout[1][0])
     # flow tier: prepack the reaction kernels' SMEM param rows once per
     # step *function*, so a scanned step stops rebuilding them every
     # substep (they are pure functions of the run's constants).
@@ -1239,7 +1412,7 @@ def make_step_fn(scn: Scenario, cfg: "CCConfig | CCSpec",
 
     def step(st: FluidState):
         return fluid_step(st, sd, par, dt=dt, n_switches=n_sw,
-                          reduce=reduce, dense_rows=dense_rows,
+                          reduce=reduce, dense_blocks=dense_blocks,
                           use_kernels=use_kernels, interpret=interpret,
                           n_vcs=n_vcs, packed_react=packed)
 

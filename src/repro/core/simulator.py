@@ -143,19 +143,21 @@ def make_block_fn(scn: Scenario, cfg: CCConfig, trace_every: int, *,
     exact ones ``decimating_scan`` uses, so traces are bit-identical to
     the per-step path.
     """
-    from .fluid import (check_routing_paths, dense_reduce_rows,
-                        scenario_device, step_body_fn, step_params)
+    from .fluid import (check_routing_paths, dense_engine,
+                        dense_reduce_rows, scenario_device, step_body_fn,
+                        step_params)
     from repro.kernels.fluid_step import megastep_block
     check_routing_paths(cfg, scn)
     n_vcs = int(getattr(cfg.link, "n_vcs", 1))
     sd = scenario_device(scn, n_vcs=n_vcs)
     par = step_params(cfg)
     dt = float(cfg.sim.dt)
-    if dense_rows is None:
-        dense_rows = dense_reduce_rows(scn, n_vcs) \
-            if reduce == "fused" else 0
+    rows = 0 if reduce != "fused" else dense_reduce_rows(scn, n_vcs) \
+        if dense_rows is None else int(dense_rows)
+    dense_blocks, _ = dense_engine([scn], n_vcs, rows, pinned=True,
+                                   mega=True)
     body = step_body_fn(dt=dt, n_switches=int(scn.n_switches),
-                        reduce=reduce, dense_rows=dense_rows,
+                        reduce=reduce, dense_blocks=dense_blocks,
                         n_vcs=n_vcs)
 
     def block(st: FluidState):

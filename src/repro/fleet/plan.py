@@ -25,9 +25,11 @@ one-launch result:
     the scheduler's work-stealing problem.
 
 Bitwise discipline: a shard pinned to the plan's envelope runs the
-exact program the full batch would — PAD flows/links, extra delay
-slots, extra switch rows and replicated pad runs are all inert by
-construction — so the merged fleet result is bitwise the uninterrupted
+arithmetic the full batch would — PAD flows/links, extra delay slots,
+extra switch rows and replicated pad runs are all inert by
+construction, and the pinned dense rows (the content-free rectangle)
+add each queue's contributors in the order of the full batch's jagged
+layout — so the merged fleet result is bitwise the uninterrupted
 ``Sweep.run()`` (asserted in ``tests/test_fleet.py``).
 """
 

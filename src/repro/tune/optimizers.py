@@ -341,7 +341,7 @@ class Evaluator:
                     temperature=jnp.asarray(tau, jnp.float32))
                 step = lambda s: fluid_step(
                     s, sd, par, dt=dt, n_switches=n_sw,
-                    reduce="fused", dense_rows=0)
+                    reduce="fused", dense_blocks=())
                 final, tr = decimating_scan(step, st0, n_samples, k, dt)
                 return obj_fn(final, tr, ctx)
 

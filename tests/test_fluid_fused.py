@@ -435,3 +435,169 @@ def test_scenario_device_upload_cache_and_jitter():
         assert getattr(sd1, f) is getattr(sd2, f), f
     F = sd1.gen_rate.shape[0]
     np.testing.assert_array_equal(np.asarray(sd1.jitter), _flow_jitter(F))
+
+
+# ---------------------------------------------------------------------------
+# the jagged dense layout: parity, and the layout built on the host
+# ---------------------------------------------------------------------------
+
+def _skew_batch() -> Sweep:
+    """Two runs of one fabric whose skew differs: an incast of 12 hosts
+    onto host 0's down-link, and a uniform permutation."""
+    ft = FabricSpec.fat_tree(4, taper=2)
+    incast = ScenarioSpec.flows([(i, 0) for i in range(1, 13)], fabric=ft,
+                                t_start=0.0, t_stop=0.5e-3)
+    uniform = ScenarioSpec.permutation(16, seed=3, fabric=ft, t_start=0.0,
+                                       t_stop=0.5e-3)
+    return Sweep.grid(
+        configs={s.name: PAPER_CONFIG.replace(scheme=s) for s in CCScheme},
+        scenarios={"incast": incast, "uniform": uniform})
+
+
+def _multipath_grid() -> Sweep:
+    cfgs = {r: PAPER_CONFIG.replace(scheme=CCScheme.DCQCN_REV, routing=r)
+            for r in ("valiant", "ugal")}
+    return Sweep.grid(configs=cfgs,
+                      scenarios={"ft_perm": _grid_scenarios()["ft_perm"]})
+
+
+@pytest.mark.parametrize("batch", [_grid, _grid_v2, _multipath_grid,
+                                   _skew_batch],
+                         ids=["golden", "two_vcs", "multipath", "mixed_skew"])
+def test_jagged_layout_matches_scat(batch):
+    """The derived layout is jagged (several blocks, fewer slots than
+    the rectangle of every queue x the longest list) and the sweep it
+    runs is bitwise the scatter engine's."""
+    sweep = batch()
+    static, (_, sd, _), _, _ = sweep._prepare(60, 10)
+    blocks = static[5]
+    S = (sd.cap_ext.shape[1] - 1) * sweep.n_vcs
+    assert len(blocks) > 1
+    assert sd.red_idx.shape[1] == sum(w * n for w, n in blocks)
+    assert sd.red_idx.shape[1] < S * sum(n for _, n in blocks)
+    _assert_bitwise(sweep.run(n_steps=60), sweep.run(n_steps=60,
+                                                     reduce="scat"),
+                    "jagged-vs-scat")
+
+
+def test_mixed_skew_pads_each_position_to_the_widest_run():
+    """Each position is as wide as the run with the most queues that
+    long; the incast run alone reaches the last positions."""
+    from repro.core.experiments import stack_scenarios
+    from repro.core.fluid import dense_layout, jagged_blocks
+    _, padded, _ = stack_scenarios([p.scenario for p in
+                                    _skew_batch().points])
+    blocks, idx, back, n_rows = dense_layout(padded)
+    counts = []
+    for s in padded:
+        perm, _, off = link_incidence(s.routes[:, None, :],
+                                      s.capacity.shape[0])
+        counts.append(np.diff(off)[:-1])
+    counts = np.stack(counts)
+    assert blocks == jagged_blocks(counts)
+    assert sum(n for _, n in blocks) == counts.max() == 12
+    assert blocks[-1][0] == 1                     # the incast link alone
+    assert n_rows == counts.sum()
+
+
+def _random_incidence(rng, F, K, H, L, hot):
+    """A [F, K, H] candidate stack whose hops pile onto ``hot`` links
+    with probability one half, PAD-padded at random lengths."""
+    alt = np.where(rng.random((F, K, H)) < 0.5,
+                   rng.integers(0, hot, (F, K, H)),
+                   rng.integers(0, L, (F, K, H)))
+    hops = rng.integers(1, H + 1, (F, K, 1))
+    return np.where(np.arange(H)[None, None, :] < hops, alt,
+                    PAD).astype(np.int32)
+
+
+def _queue_lists(idx, back, blocks, N):
+    """Each queue's contributor rows, read back out of the layout in
+    slot order (sentinels dropped)."""
+    lists = {}
+    start = 0
+    for w, n in blocks:
+        tab = idx[start:start + w * n].reshape(n, w)
+        start += w * n
+        for q, r in enumerate(back[:-1]):
+            if r < w:
+                lists.setdefault(q, []).extend(v for v in tab[:, r] if v != N)
+    return lists
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_jagged_index_holds_each_contributor_once_in_order(seed):
+    """Host layout: every contributor appears exactly once, each queue's
+    contributors in ascending (incidence) order, every other slot is the
+    sentinel, and the slots stay within twice the positions' widths."""
+    from repro.core.fluid import jagged_blocks, jagged_index
+    rng = np.random.default_rng(seed)
+    F, K, H, L = 40, 1 + seed % 3, 5, 30
+    runs = [link_incidence(_random_incidence(rng, F, K, H, L, 1 + r), L)
+            for r in range(3)]
+    counts = np.stack([np.diff(off)[:L] for _, _, off in runs])
+    blocks = jagged_blocks(counts)
+    widths = [int((counts > p).sum(axis=1).max())
+              for p in range(counts.max())]
+    assert sum(n for _, n in blocks) == len(widths)
+    total = sum(w * n for w, n in blocks)
+    assert total <= 2 * sum(widths)
+    N = F * K * H
+    for (perm, _, off), c in zip(runs, counts):
+        idx, back = jagged_index(perm, off, L, blocks)
+        assert idx.shape == (total,) and back.shape == (L + 1,)
+        assert back[L] == blocks[0][0]                    # scratch -> zero
+        lists = _queue_lists(idx, back, blocks, N)
+        for q in range(L):
+            want = list(perm[off[q]:off[q + 1]])
+            assert lists.get(q, []) == want, q
+            assert want == sorted(want)
+        real = np.sort(idx[idx != N])
+        np.testing.assert_array_equal(real, np.sort(perm[:off[L]]))
+        assert np.count_nonzero(idx == N) == total - c.sum()
+
+
+def test_jagged_index_refuses_a_layout_too_short():
+    from repro.core.fluid import jagged_index
+    alt = np.zeros((3, 1, 1), np.int32)               # 3 flows on link 0
+    perm, _, off = link_incidence(alt, 2)
+    with pytest.raises(ValueError, match="cannot hold"):
+        jagged_index(perm, off, 2, ((2, 2),))
+
+
+def test_pinned_dense_rows_compiles_once_across_contents():
+    """A pinned ``dense_rows`` is the one-block rectangle: batches of
+    different content (and skew) share one executable."""
+    from repro.core.experiments import SWEEP_EXEC_CACHE
+    ft = FabricSpec.fat_tree(4, taper=2)
+    cfg = PAPER_CONFIG.replace(scheme=CCScheme.DCQCN)
+    batches = [
+        Sweep([("a", cfg, ScenarioSpec.flows(
+            [(i, 0) for i in range(1, 9)], fabric=ft))]),
+        Sweep([("b", cfg, ScenarioSpec.permutation(8, seed=5, fabric=ft))]),
+    ]
+    kw = dict(dense_rows=12, min_delay_slots=16)
+    prepared = [b._prepare(20, 10, **kw) for b in batches]
+    L = prepared[0][1][1].cap_ext.shape[-1] - 1
+    assert prepared[0][0] == prepared[1][0]
+    assert prepared[0][0][5] == ((L, 12),)
+    misses0 = SWEEP_EXEC_CACHE.stats().misses
+    runs = [b.run(n_steps=20, **kw) for b in batches]
+    assert SWEEP_EXEC_CACHE.stats().misses - misses0 <= 1
+    for b, r in zip(batches, runs):
+        _assert_bitwise(r, b.run(n_steps=20, reduce="scat",
+                                 min_delay_slots=16), "pinned")
+
+
+def test_dense_layout_is_memoised_on_content():
+    """Relaunching a batch stages nothing new: the layout and its device
+    arrays come back from the cache."""
+    from repro.core.experiments import stack_scenarios
+    from repro.core.fluid import dense_layout
+    _, padded, _ = stack_scenarios([p.scenario for p in
+                                    _skew_batch().points])
+    a, b = dense_layout(padded), dense_layout(list(padded))
+    assert all(x is y for x, y in zip(a, b))
+    pinned = dense_layout(padded, rows=12)
+    assert pinned[0] == ((padded[0].capacity.shape[0], 12),)
+    assert pinned[1] is not a[1]

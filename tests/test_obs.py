@@ -198,6 +198,7 @@ def test_scopes_leave_the_compiled_program_alone(scoped, monkeypatch):
 def test_run_leaves_one_span_of_each_part():
     sweep = Sweep(_paper_clos())
     sweep.run(n_steps=20, trace_every=10)           # compiles
+    sd = sweep._prepare(20, 10)[1][1]
     before = obs.stats()
     res = sweep.run(n_steps=20, trace_every=10)
     d = obs.stats() - before
@@ -205,10 +206,44 @@ def test_run_leaves_one_span_of_each_part():
         assert d.span(f"repro.sweep.{part}").n == 1, part
     assert d.span("repro.exec_cache.build").n == 0          # a cache hit
     nbytes = sum(np.asarray(x).nbytes for x in jax.tree.leaves((res.traces, res.final)))
-    assert d.counts == {"sweep.fetch_bytes": nbytes}
+    rows = int((sd.red_idx != sd.alt_routes[0].size).sum())
+    assert d.counts == {"sweep.fetch_bytes": nbytes,
+                        "sweep.reduce_slots": sd.red_idx.size,
+                        "sweep.reduce_rows": rows}
     assert "jit_scan_fn" in obs.sweep_op_scopes()
     assert "fluid.reduce" in obs.sweep_op_scopes()["jit_scan_fn"].values()
 
+
+
+def _bench_reader(name: str):
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__))), "bench", "metrics", f"{name}.py")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_reduce_fill_reads_the_share_of_slots_with_a_contributor(
+        monkeypatch):
+    """``sweep.reduce_rows`` / ``sweep.reduce_slots`` of a launch is the
+    share of the jagged layout's slots that are not the sentinel, and
+    the benchmark's reader reports it; the segment-sum engine counts
+    nothing, so the reader reports nothing there."""
+    reader = _bench_reader("reduce_fill.sweep")
+    sweep = Sweep(_paper_clos())
+    sd = sweep._prepare(20, 10)[1][1]
+    fill = np.mean(np.asarray(sd.red_idx) != sd.alt_routes[0].size)
+    stats = obs.stats
+    before = stats()
+    monkeypatch.setattr(obs, "stats", lambda: stats() - before)
+    sweep.run(n_steps=20, trace_every=10, dense_rows=0)
+    assert reader.read({}) is None
+    sweep.run(n_steps=20, trace_every=10)
+    assert reader.read({}) == pytest.approx(fill)
+    assert 0.5 <= reader.read({}) <= 1.0
 
 def test_routes_build_once_per_fabric():
     fab = FabricSpec.xgft((3, 3), (1, 2))                   # no other test builds it

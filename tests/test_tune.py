@@ -126,7 +126,7 @@ def _soft_values(ev: Evaluator, thetas: np.ndarray,
         par = par._replace(temperature=jnp.asarray(tau, jnp.float32))
         step = lambda s: fluid_step(s, ev.sd, par, dt=ev.dt,
                                     n_switches=ev.n_sw,
-                                    reduce="fused", dense_rows=0)
+                                    reduce="fused", dense_blocks=())
         final, tr = decimating_scan(step, ev.st0, ev.n_samples, ev.k,
                                     ev.dt)
         return ev.obj_fn(final, tr, ev.ctx)
