@@ -299,6 +299,49 @@ def _flow_jitter(n: int) -> np.ndarray:
     return (x.astype(np.float64) / 2**31 - 1.0).astype(np.float32)
 
 
+def _split12(a: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``a == hi + lo`` exactly, ``hi`` the float32 ``a`` rounded to 12
+    significant bits and ``|lo| <= ulp(hi) / 2`` (Veltkamp's split, made
+    with integer ops so that no multiply-add can be contracted into it)."""
+    bits = jax.lax.bitcast_convert_type(a, jnp.uint32)
+    hi = jax.lax.bitcast_convert_type(
+        (bits + jnp.uint32(0x800)) & jnp.uint32(0xFFFFF000), jnp.float32)
+    return hi, a - hi
+
+
+def round_quotient(q: jnp.ndarray, x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
+    """``q``, a float32 quotient within a few ulps of ``x / y``, corrected
+    to the float32 nearest ``x / y``: the residual ``x - q * y`` is formed
+    exactly (Dekker's product), and ``q + residual / y`` rounds to the
+    nearest float unless ``x / y`` lies within about 2**-21 ulp of a tie.
+    An exact quotient (``x / x``) comes out exact.  Non-finite corrections
+    (``y`` zero or infinite, overflow) keep ``q``."""
+    p = q * y
+    qh, ql = _split12(q)
+    yh, yl = _split12(y)
+    e = ((qh * yh - p) + qh * yl + ql * yh) + ql * yl      # q * y == p + e
+    r = (x - p) - e
+    q1 = q + r / y
+    return jnp.where(jnp.isfinite(q1), q1, q)
+
+
+def fdiv(x, y) -> jnp.ndarray:
+    """``x / y`` in float32, rounded to nearest on every backend.
+
+    The TPU divides as a refined reciprocal times ``x``: on a TPU v5e
+    34.6% of random quotients are 1-2 ulps off, and ``x / x`` can read
+    below 1 (a queue served its whole backlog keeps a residue), which is
+    enough for a pause, mark or path to be decided the other way a few
+    steps later.  One exact-residual correction (``round_quotient``)
+    brings the quotient to what the CPU gives; elsewhere ``fdiv`` is
+    ``x / y``, bit for bit."""
+    x = jnp.asarray(x, jnp.float32)
+    y = jnp.asarray(y, jnp.float32)
+    q = x / y
+    return jax.lax.platform_dependent(
+        q, x, y, tpu=round_quotient, default=lambda q, x, y: q)
+
+
 @functools.lru_cache(maxsize=128)
 def _index_consts(F: int, H: int) -> tuple[np.ndarray, np.ndarray]:
     """(arange_h [1, H], fidx [F]) — shared across traces of one shape."""
@@ -1110,7 +1153,7 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
             sum_w = scat(weight)
         # FIFO factor is per (wire, VC) queue — a paused-head VC no longer
         # stalls its siblings, only its own lane (the HoL fix VCs buy).
-        fifo_ok = jnp.where(den > 0, num / jnp.maximum(den, 1e-9), 1.0)
+        fifo_ok = jnp.where(den > 0, fdiv(num, jnp.maximum(den, 1e-9)), 1.0)
         # ... but the byte budget is per *wire*: capacity is shared across
         # VCs in proportion to drainable backlog.  fifo_ok <= 1, so the
         # summed per-VC grants never exceed the wire's C*dt.
@@ -1118,7 +1161,7 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
 
         budget = caps_w * dt * fifo_ok[qidx]
         share = jnp.where(sum_w_w[widx] > 0,
-                          budget * weight / jnp.maximum(sum_w_w[widx], 1e-9),
+                          fdiv(budget * weight, jnp.maximum(sum_w_w[widx], 1e-9)),
                           0.0)
         T = jnp.minimum(weight, share)                     # bytes crossing h
 
@@ -1130,7 +1173,7 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
         delivered = st.delivered + deliv_step
 
         # crossing-rate EWMA (doubles as arrival-into-queue estimate)
-        est = (1 - par.ecp_beta) * st.est + par.ecp_beta * (T / dt)
+        est = (1 - par.ecp_beta) * st.est + par.ecp_beta * fdiv(T, dt)
 
         # Demand to cross wire h = arrival rate into the queue feeding it
         # (pre-stall, so FIFO-blocked victims keep their true demand).
@@ -1203,7 +1246,7 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
         B1_w = B1[qidx]
         present = (qh > 0) | (T > 0)
 
-        share0 = caps_w / jnp.maximum(n_act_w[widx], 1.0)
+        share0 = fdiv(caps_w, jnp.maximum(n_act_w[widx], 1.0))
         under = dem < share0
         if fused:
             surplus, n_heavy = link_sums(
@@ -1216,7 +1259,7 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
         n_heavy_w = to_wire(n_heavy)
         grant = jnp.where(
             under, dem,
-            share0 + surplus_w[widx] / jnp.maximum(n_heavy_w[widx], 1.0))
+            share0 + fdiv(surplus_w[widx], jnp.maximum(n_heavy_w[widx], 1.0)))
         grant = jnp.where(act, grant, caps_w)
         # wire h oversubscribed?  (soft: sigmoid in the demand excess; the
         # PAD slot's cap is inf, so the soft gate is exactly 0 there too)
@@ -1324,8 +1367,8 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
         # Pallas form route through it behind ``use_kernels``.  The queuing-
         # delay estimate (bytes queued along the path / line rate) feeds the
         # mark-free delay-based stages.
-        qdelay = jnp.sum(jnp.where(holds_queue, qh, 0.0),
-                         axis=1) / par.line_rate
+        qdelay = fdiv(jnp.sum(jnp.where(holds_queue, qh, 0.0), axis=1),
+                      par.line_rate)
         react_out, cc_react = cc.dispatch(
             cc.REACTION, par.react_code, par.react,
             cc.ReactCtx(rate=st.rate, rp_target=st.rp_target, alpha=st.alpha,
@@ -1352,7 +1395,7 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
     with obs.scope("fluid.decimate"):
         rate = react_out.rate
         trace = StepTrace(
-            delivered=delivered, rate=rate, inst_thr=deliv_step / dt,
+            delivered=delivered, rate=rate, inst_thr=fdiv(deliv_step, dt),
             max_q=jnp.max(B),
             n_paused=jnp.sum((paused > 0.5).astype(jnp.int32)),
             marked=marked, cnp=cnp > 0,

@@ -20,9 +20,9 @@ from .routing import (RouteSet, RouteTable, clos_route_set,
                       clos_route_table, clos_valiant_path,
                       dragonfly_path, dragonfly_route_set,
                       dragonfly_route_table, dragonfly_valiant_path,
-                      stage_balance, validate_route_set, validate_table,
-                      xgft_path, xgft_route_set, xgft_route_table,
-                      xgft_valiant_path)
+                      stage_balance, validate_pair_routes,
+                      validate_route_set, validate_table, xgft_path,
+                      xgft_route_set, xgft_route_table, xgft_valiant_path)
 from .topologies import (DragonflyIndex, XGFTIndex, make_dragonfly,
                          make_fat_tree, make_xgft)
 
@@ -30,8 +30,8 @@ __all__ = [
     "FabricSpec", "RouteSet", "RouteTable", "clos_route_set",
     "clos_route_table", "clos_valiant_path", "dragonfly_path",
     "dragonfly_route_set", "dragonfly_route_table",
-    "dragonfly_valiant_path", "stage_balance", "validate_route_set",
-    "validate_table", "xgft_path", "xgft_route_set", "xgft_route_table",
-    "xgft_valiant_path", "DragonflyIndex", "XGFTIndex",
+    "dragonfly_valiant_path", "stage_balance", "validate_pair_routes",
+    "validate_route_set", "validate_table", "xgft_path", "xgft_route_set",
+    "xgft_route_table", "xgft_valiant_path", "DragonflyIndex", "XGFTIndex",
     "make_dragonfly", "make_fat_tree", "make_xgft",
 ]

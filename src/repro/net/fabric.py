@@ -7,7 +7,8 @@ key jit/result caches.  ``build`` / ``route_table`` materialise the
 line_rate) — sweeping 3 CC schemes over one fabric builds its table a
 single time.  ``route_set(k, seed)`` is the multi-path analogue
 (minimal + Valiant detour candidates, cached per (spec, k, seed)) that
-adaptive routing modes select from at run time.
+adaptive routing modes select from at run time; ``flow_route_set``
+builds the same candidates for a scenario's pairs alone.
 
 Families:
   * ``clos3``      — the paper's 3-stage CLOS (closed-form D-mod-K,
@@ -27,10 +28,11 @@ import numpy as np
 from repro.core import obs
 from repro.core.topology import Topology, make_clos3
 
-from .routing import (RouteSet, RouteTable, clos_route_set,
-                      clos_route_table, dragonfly_route_set,
-                      dragonfly_route_table, validate_route_set,
-                      validate_table, xgft_route_set, xgft_route_table)
+from .routing import (PathFns, RouteSet, RouteTable, clos_path_fns,
+                      clos_route_table, dragonfly_path_fns,
+                      dragonfly_route_table, validate_pair_routes,
+                      validate_route_set, validate_table, xgft_path_fns,
+                      xgft_route_table)
 from .topologies import (DragonflyIndex, XGFTIndex, fat_tree_mw,
                          make_dragonfly, make_xgft)
 
@@ -162,7 +164,10 @@ class FabricSpec:
     def flow_route_set(self, pairs, k_paths: int = 4, seed: int = 0):
         """([F, K, H_MAX] candidate routes, [F, K] hops) for pairs,
         cached per (spec hash, pairs, k, seed); read-only like
-        ``flow_routes``."""
+        ``flow_routes``.  Builds and validates the given pairs' rows
+        only, bitwise ``route_set(k_paths, seed)`` sliced to them: a
+        1056-host dragonfly's pairs take about a second where its whole
+        set would take minutes."""
         return _flow_route_set(self._structural,
                                tuple(tuple(p) for p in pairs),
                                int(k_paths), int(seed))
@@ -270,25 +275,31 @@ def _flow_routes(spec: FabricSpec, pairs: tuple):
 
 
 @functools.lru_cache(maxsize=256)
+@obs.span("repro.routes.build")
 def _flow_route_set(spec: FabricSpec, pairs: tuple, k: int, seed: int):
-    rset = _build_route_set(spec, k, seed)
-    return (_frozen(rset.routes_for_pairs(pairs)),
-            _frozen(rset.hops_for_pairs(pairs)))
+    """Build + validate the candidates of ``pairs`` alone; cached."""
+    paths, hops = _path_fns(spec, k).rows(pairs, k, seed)
+    validate_pair_routes(_build_topo(spec, 12.5e9), pairs, paths, hops)
+    return _frozen(paths), _frozen(hops)
+
+
+def _path_fns(spec: FabricSpec, k: int) -> PathFns:
+    """The fabric family's candidate path functions."""
+    if spec.kind == "clos3":
+        return clos_path_fns(spec.arity, roll=spec.roll)
+    if spec.kind == "xgft":
+        _, idx = make_xgft(spec.m, spec.w)
+        return xgft_path_fns(idx, roll=spec.roll)
+    if spec.kind == "dragonfly":
+        _, idx = make_dragonfly(spec.a, spec.p, spec.h, groups=spec.groups)
+        return dragonfly_path_fns(idx, k)
+    raise ValueError(f"unknown fabric kind: {spec.kind!r}")
 
 
 @functools.lru_cache(maxsize=64)
 @obs.span("repro.routes.build")
 def _build_route_set(spec: FabricSpec, k: int, seed: int) -> RouteSet:
     """Build + validate one fabric's multi-path RouteSet; cached."""
-    if spec.kind == "clos3":
-        rset = clos_route_set(spec.arity, k=k, seed=seed, roll=spec.roll)
-    elif spec.kind == "xgft":
-        _, idx = make_xgft(spec.m, spec.w)
-        rset = xgft_route_set(idx, k=k, seed=seed, roll=spec.roll)
-    elif spec.kind == "dragonfly":
-        _, idx = make_dragonfly(spec.a, spec.p, spec.h, groups=spec.groups)
-        rset = dragonfly_route_set(idx, k=k, seed=seed)
-    else:
-        raise ValueError(f"unknown fabric kind: {spec.kind!r}")
+    rset = _path_fns(spec, k).route_set(k, seed)
     validate_route_set(_build_topo(spec, 12.5e9), rset)
     return rset

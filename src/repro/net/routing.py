@@ -30,9 +30,11 @@ hop costs — see ``repro.core.fluid``).
 from __future__ import annotations
 
 import dataclasses
+from typing import Callable
 
 import numpy as np
 
+from repro.core import obs
 from repro.core.routing import PAD, assign_vc, clos_route, link_incidence
 from repro.core.topology import ClosIndex, Topology
 
@@ -230,28 +232,56 @@ def _rng_for(seed: int, s: int, d: int, k: int) -> np.random.RandomState:
         np.array([seed & 0x7FFFFFFF, s, d, k], np.uint32))
 
 
-def _route_set_from_fns(n: int, h_max: int, k: int, seed: int,
-                        min_fn, alt_fn) -> RouteSet:
-    """Assemble a RouteSet: slot 0 = ``min_fn(s, d)``; slots 1..k-1 =
-    ``alt_fn(s, d, rng)`` with a deterministic per-(s, d, slot) rng."""
-    if k < 1:
-        raise ValueError(f"need k >= 1 candidate paths, got {k}")
-    paths = np.full((n, n, k, h_max), PAD, np.int32)
-    hops = np.zeros((n, n, k), np.int32)
-    for s in range(n):
-        for d in range(n):
+@dataclasses.dataclass(frozen=True)
+class PathFns:
+    """One fabric's candidate paths: slot 0 is ``minimal(s, d)``, slots
+    1..K-1 are ``detour(s, d, rng)`` with the order-free per-(seed, s,
+    d, slot) stream of ``_rng_for``; each at most ``h_max`` links, over
+    hosts ``[0, n_nodes)``.  Because no draw depends on another pair,
+    the candidates of any pair list are bitwise the full ``RouteSet``'s
+    rows for those pairs."""
+
+    n_nodes: int
+    h_max: int
+    minimal: Callable
+    detour: Callable
+
+    def rows(self, pairs, k: int, seed: int):
+        """``([M, k, h_max] paths, [M, k] hops)`` of the (s, d) pairs:
+        PAD-padded link ids, all-PAD with 0 hops where s == d.  Adds
+        the candidate paths built to the ``routes.paths_built``
+        counter."""
+        if k < 1:
+            raise ValueError(f"need k >= 1 candidate paths, got {k}")
+        idx = _pair_index(pairs, self.n_nodes) if len(pairs) \
+            else np.empty((0, 2), np.int64)
+        paths = np.full((len(idx), k, self.h_max), PAD, np.int32)
+        hops = np.zeros((len(idx), k), np.int32)
+        built = 0
+        for i, (s, d) in enumerate(idx.tolist()):
             if s == d:
                 continue
             for j in range(k):
-                p = min_fn(s, d) if j == 0 else \
-                    alt_fn(s, d, _rng_for(seed, s, d, j))
-                if len(p) > h_max:
+                p = self.minimal(s, d) if j == 0 else \
+                    self.detour(s, d, _rng_for(seed, s, d, j))
+                if len(p) > self.h_max:
                     raise ValueError(
                         f"path {s}->{d} slot {j} has {len(p)} hops "
-                        f"> H_MAX={h_max}")
-                paths[s, d, j, : len(p)] = p
-                hops[s, d, j] = len(p)
-    return RouteSet(paths=paths, hops=hops)
+                        f"> H_MAX={self.h_max}")
+                paths[i, j, : len(p)] = p
+                hops[i, j] = len(p)
+            built += k
+        obs.count("routes.paths_built", built)
+        return paths, hops
+
+    def route_set(self, k: int, seed: int) -> RouteSet:
+        """The full set: ``rows`` of every ordered (s, d) pair."""
+        n = self.n_nodes
+        grid = np.stack(np.meshgrid(np.arange(n), np.arange(n),
+                                    indexing="ij"), -1).reshape(-1, 2)
+        paths, hops = self.rows(grid, k, seed)
+        return RouteSet(paths=paths.reshape(n, n, k, self.h_max),
+                        hops=hops.reshape(n, n, k))
 
 
 # ---------------------------------------------------------------------------
@@ -439,34 +469,46 @@ def dragonfly_valiant_path(idx: DragonflyIndex, s: int, d: int,
 DFLY_VLB_H_MAX = 7        # up + local + global + local + global + local + dn
 
 
+def clos_path_fns(arity: int = 4, roll: int = 0) -> PathFns:
+    """Minimal D-mod-K + random-spine candidates; H_MAX = 6."""
+    idx = ClosIndex(arity)
+    return PathFns(arity ** 3, 6,
+                   lambda s, d: clos_route(idx, s, d, roll=roll),
+                   lambda s, d, rng: clos_valiant_path(idx, s, d, rng))
+
+
+def xgft_path_fns(idx: XGFTIndex, roll: int = 0) -> PathFns:
+    """Minimal D-mod-K + random-root VLB candidates; H_MAX = 2h."""
+    return PathFns(idx.n_hosts, 2 * idx.h,
+                   lambda s, d: xgft_path(idx, s, d, roll=roll),
+                   lambda s, d, rng: xgft_valiant_path(idx, s, d, rng))
+
+
+def dragonfly_path_fns(idx: DragonflyIndex, k: int = 4) -> PathFns:
+    """Minimal + intermediate-group VLB candidates; H_MAX = 7 (the VLB
+    worst case) once any detour slot exists (``k > 1``), else 5."""
+    return PathFns(idx.n_hosts, DFLY_VLB_H_MAX if k > 1 else 5,
+                   lambda s, d: dragonfly_path(idx, s, d),
+                   lambda s, d, rng: dragonfly_valiant_path(idx, s, d, rng))
+
+
 def clos_route_set(arity: int = 4, k: int = 4, seed: int = 0,
                    roll: int = 0) -> RouteSet:
     """Minimal D-mod-K + k-1 random-spine candidates; H_MAX = 6."""
-    idx = ClosIndex(arity)
-    return _route_set_from_fns(
-        arity ** 3, 6, k, seed,
-        lambda s, d: clos_route(idx, s, d, roll=roll),
-        lambda s, d, rng: clos_valiant_path(idx, s, d, rng))
+    return clos_path_fns(arity, roll).route_set(k, seed)
 
 
 def xgft_route_set(idx: XGFTIndex, k: int = 4, seed: int = 0,
                    roll: int = 0) -> RouteSet:
     """Minimal D-mod-K + k-1 random-root VLB candidates; H_MAX = 2h."""
-    return _route_set_from_fns(
-        idx.n_hosts, 2 * idx.h, k, seed,
-        lambda s, d: xgft_path(idx, s, d, roll=roll),
-        lambda s, d, rng: xgft_valiant_path(idx, s, d, rng))
+    return xgft_path_fns(idx, roll).route_set(k, seed)
 
 
 def dragonfly_route_set(idx: DragonflyIndex, k: int = 4,
                         seed: int = 0) -> RouteSet:
     """Minimal + k-1 intermediate-group VLB candidates; H_MAX = 7
     (the VLB worst case) once any detour slot exists, else 5."""
-    h_max = DFLY_VLB_H_MAX if k > 1 else 5
-    return _route_set_from_fns(
-        idx.n_hosts, h_max, k, seed,
-        lambda s, d: dragonfly_path(idx, s, d),
-        lambda s, d, rng: dragonfly_valiant_path(idx, s, d, rng))
+    return dragonfly_path_fns(idx, k).route_set(k, seed)
 
 
 # ---------------------------------------------------------------------------
@@ -474,35 +516,34 @@ def dragonfly_route_set(idx: DragonflyIndex, k: int = 4,
 # ---------------------------------------------------------------------------
 
 
-def validate_table(topo: Topology, table: RouteTable) -> None:
-    """Structural validity of a full route table (vectorised).
+def validate_rows(topo: Topology, src, dst, paths: np.ndarray,
+                  hops: np.ndarray) -> None:
+    """Structural validity of route rows: row i is the path
+    ``src[i] -> dst[i]`` (``paths`` [M, H_MAX] PAD-padded, ``hops`` [M]).
 
-    Raises AssertionError unless, for every (s, d) pair with s != d:
-    the first link leaves host s, the last link delivers to host d,
-    consecutive links share a switch (sink(h) == source(h+1)), all
-    link ids are in range, and padding is trailing-only.
+    Raises AssertionError unless every row with src != dst starts at
+    its source host, ends at its destination host, has consecutive
+    links sharing a switch (sink(h) == source(h+1)), link ids in range
+    and trailing-only padding; rows with src == dst must be empty.
     """
-    n, h = table.n_nodes, table.h_max
-    paths, hops = table.paths, table.hops
-    if topo.n_nodes != n:
-        raise AssertionError(
-            f"table is for {n} hosts, topology has {topo.n_nodes}")
+    src, dst = np.asarray(src), np.asarray(dst)
+    h = paths.shape[-1]
     valid = paths != PAD
     # trailing-only padding, and hops consistent with the mask
-    want = np.arange(h)[None, None, :] < hops[..., None]
-    if not (valid == want).all():
+    if not (valid == (np.arange(h)[None, :] < hops[:, None])).all():
         raise AssertionError("non-trailing PAD or hops/path mismatch")
-    off = ~np.eye(n, dtype=bool)
-    if not (hops[off] >= 2).all() or (hops.diagonal() != 0).any():
+    off = src != dst
+    if not (hops[off] >= 2).all() or (hops[~off] != 0).any():
         raise AssertionError("every s != d path needs >= 2 links "
                              "(host up + host down); s == s must be empty")
     ids = paths[valid]
     if ids.size and (ids.min() < 0 or ids.max() >= topo.n_links):
         raise AssertionError("link id out of range")
     # endpoint checks
-    s_idx, d_idx = np.nonzero(off)
-    first = paths[s_idx, d_idx, 0]
-    last = paths[s_idx, d_idx, hops[s_idx, d_idx] - 1]
+    s_idx, d_idx = src[off], dst[off]
+    rows, n_hops = paths[off], hops[off]
+    first = rows[:, 0]
+    last = rows[np.arange(len(rows)), n_hops - 1]
     if not (topo.link_src[first] == -(s_idx + 1)).all():
         bad = int(np.argmax(topo.link_src[first] != -(s_idx + 1)))
         raise AssertionError(
@@ -514,16 +555,28 @@ def validate_table(topo: Topology, table: RouteTable) -> None:
             f"path {s_idx[bad]}->{d_idx[bad]} does not sink at its "
             f"destination host")
     # consecutive links share a switch
-    a, b = paths[..., :-1], paths[..., 1:]
+    a, b = paths[:, :-1], paths[:, 1:]
     both = (a != PAD) & (b != PAD)
     sink = topo.link_dst[np.where(both, a, 0)]
     srcn = topo.link_src[np.where(both, b, 0)]
     ok = ~both | ((sink == srcn) & (sink >= 0))
     if not ok.all():
-        s, d, j = (int(x[0]) for x in np.nonzero(~ok))
+        i, j = (int(x[0]) for x in np.nonzero(~ok))
         raise AssertionError(
-            f"path {s}->{d}: hop {j} sinks at {topo.link_dst[paths[s,d,j]]}"
-            f" but hop {j+1} departs {topo.link_src[paths[s,d,j+1]]}")
+            f"path {src[i]}->{dst[i]}: hop {j} sinks at "
+            f"{topo.link_dst[paths[i, j]]} but hop {j+1} departs "
+            f"{topo.link_src[paths[i, j + 1]]}")
+
+
+def validate_table(topo: Topology, table: RouteTable) -> None:
+    """Structural validity of a full route table (vectorised): every
+    (s, d) row passes ``validate_rows``."""
+    n, h = table.n_nodes, table.h_max
+    if topo.n_nodes != n:
+        raise AssertionError(
+            f"table is for {n} hosts, topology has {topo.n_nodes}")
+    validate_rows(topo, np.repeat(np.arange(n), n), np.tile(np.arange(n), n),
+                  table.paths.reshape(n * n, h), table.hops.reshape(n * n))
 
 
 def validate_route_set(topo: Topology, rset: RouteSet) -> None:
@@ -536,6 +589,19 @@ def validate_route_set(topo: Topology, rset: RouteSet) -> None:
     for k in range(rset.k_paths):
         try:
             validate_table(topo, rset.slot(k))
+        except AssertionError as e:
+            raise AssertionError(f"candidate layer {k}: {e}") from e
+
+
+def validate_pair_routes(topo: Topology, pairs, paths: np.ndarray,
+                         hops: np.ndarray) -> None:
+    """``validate_route_set``'s checks on the candidate rows of
+    ``pairs`` alone (``paths`` [M, K, H_MAX], ``hops`` [M, K])."""
+    idx = np.asarray(pairs, np.int64).reshape(-1, 2)
+    for k in range(paths.shape[1]):
+        try:
+            validate_rows(topo, idx[:, 0], idx[:, 1], paths[:, k],
+                          hops[:, k])
         except AssertionError as e:
             raise AssertionError(f"candidate layer {k}: {e}") from e
 
