@@ -951,6 +951,23 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
             return x_ext
         return jnp.concatenate(
             [x_ext[:S].reshape(L, V).sum(axis=1), x_ext[S:]])
+
+    def to_queues(x_wire):
+        """Spread a per-wire [L + 1] vector over the wire's V queues,
+        [S + 1] (scratch L -> S).  Static identity at V == 1."""
+        if V == 1:
+            return x_wire
+        return jnp.concatenate([jnp.repeat(x_wire[:L], V), x_wire[L:]])
+
+    def hop_reads(*cols):
+        """Read [S + 1] per-queue vectors at every flow's hops in ONE
+        gather (the columns of ``[C, S + 1]`` by ``qidx``: on the chip
+        the cost is per gather and per index), one [F, H] each.
+        Per-wire values come in through ``to_queues``: ``qidx == S``
+        exactly where ``widx == L``, so each hop reads the float that
+        ``x[widx]`` reads."""
+        cols_fh = jnp.stack(cols)[:, qidx]                    # [C, F, H]
+        return [cols_fh[c] for c in range(len(cols))]
     # soft-relaxation temperature: every hard gate below is written
     # ``soft.select(tau, soft_expr, hard_expr)`` with the hard branch
     # verbatim, so tau == 0 is bitwise the hard model (repro.tune).
@@ -1133,7 +1150,8 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
         src_q = jnp.where(valid, src_q, 0.0)
 
         pause_q = jnp.concatenate([st.paused, jnp.zeros((1,), jnp.float32)])
-        wire_open = 1.0 - pause_q[qidx]                    # [F,H] 1 = drainable
+        pause_h, caps_w = hop_reads(pause_q, to_queues(sd.cap_ext))
+        wire_open = 1.0 - pause_h                          # [F,H] 1 = drainable
 
         # strict-FIFO HoL factor per link queue: share of the queue whose
         # *next* wire is currently drainable.  ``wire_open`` is an exact
@@ -1143,7 +1161,6 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
             [wire_open[:, 1:], jnp.ones((F, 1), jnp.float32)], axis=1)
         q_here = jnp.where(holds_queue, st.qh, 0.0)        # queue at sink(h)
         weight = src_q * wire_open
-        caps_w = sd.cap_ext[widx]                          # [F,H]
         if fused:
             num, den, sum_w = link_sums(
                 [q_here * next_open, q_here, weight], path_idx)
@@ -1157,11 +1174,11 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
         # ... but the byte budget is per *wire*: capacity is shared across
         # VCs in proportion to drainable backlog.  fifo_ok <= 1, so the
         # summed per-VC grants never exceed the wire's C*dt.
-        sum_w_w = to_wire(sum_w)
+        fifo_h, sum_w_h = hop_reads(fifo_ok, to_queues(to_wire(sum_w)))
 
-        budget = caps_w * dt * fifo_ok[qidx]
-        share = jnp.where(sum_w_w[widx] > 0,
-                          fdiv(budget * weight, jnp.maximum(sum_w_w[widx], 1e-9)),
+        budget = caps_w * dt * fifo_h
+        share = jnp.where(sum_w_h > 0,
+                          fdiv(budget * weight, jnp.maximum(sum_w_h, 1e-9)),
                           0.0)
         T = jnp.minimum(weight, share)                     # bytes crossing h
 
@@ -1195,9 +1212,6 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
             B = scat(jnp.where(holds_queue, qh, 0.0))[:S]
             n_act = scat(act.astype(jnp.float32), init=0.0)
             sum_dem = scat(jnp.where(act, dem, 0.0))
-        # fair grants / oversubscription below are per-wire notions
-        n_act_w = to_wire(n_act)
-        sum_dem_w = to_wire(sum_dem)
         # xoff/xon hysteresis per queue: hard = set above xoff, clear below
         # xon, hold in between; soft = the pause level relaxes toward 1 (0)
         # through a sigmoid band O(tau * port_buffer) wide around each
@@ -1241,12 +1255,14 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
     # ---- 4. marking (cc.MARKING dispatch) ---------------------------------
     with obs.scope("fluid.mark"):
         # B1_w: occupancy of the flow's own (wire, VC) queue — marking sees
-        # the lane the flow actually sits in, not its siblings' backlog
+        # the lane the flow actually sits in, not its siblings' backlog;
+        # the fair grants / oversubscription below are per-wire notions
         B1 = jnp.concatenate([B, jnp.zeros((1,), jnp.float32)])
-        B1_w = B1[qidx]
+        B1_w, n_act_h, sum_dem_h = hop_reads(
+            B1, to_queues(to_wire(n_act)), to_queues(to_wire(sum_dem)))
         present = (qh > 0) | (T > 0)
 
-        share0 = fdiv(caps_w, jnp.maximum(n_act_w[widx], 1.0))
+        share0 = fdiv(caps_w, jnp.maximum(n_act_h, 1.0))
         under = dem < share0
         if fused:
             surplus, n_heavy = link_sums(
@@ -1255,18 +1271,17 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
         else:
             surplus = scat(jnp.where(act & under, share0 - dem, 0.0))
             n_heavy = scat((act & ~under).astype(jnp.float32))
-        surplus_w = to_wire(surplus)
-        n_heavy_w = to_wire(n_heavy)
+        surplus_h, n_heavy_h = hop_reads(to_queues(to_wire(surplus)),
+                                         to_queues(to_wire(n_heavy)))
         grant = jnp.where(
-            under, dem,
-            share0 + fdiv(surplus_w[widx], jnp.maximum(n_heavy_w[widx], 1.0)))
+            under, dem, share0 + fdiv(surplus_h, jnp.maximum(n_heavy_h, 1.0)))
         grant = jnp.where(act, grant, caps_w)
         # wire h oversubscribed?  (soft: sigmoid in the demand excess; the
         # PAD slot's cap is inf, so the soft gate is exactly 0 there too)
         oversub = soft.select(
             tau,
-            soft.unit_gate(sum_dem_w[widx] - caps_w, tau, par.line_rate),
-            (sum_dem_w[widx] > caps_w).astype(jnp.float32))
+            soft.unit_gate(sum_dem_h - caps_w, tau, par.line_rate),
+            (sum_dem_h > caps_w).astype(jnp.float32))
         # ... all shifted to the *next* wire (the flow's requested output)
         inf_col = jnp.full((F, 1), jnp.inf, jnp.float32)
         grant_next = jnp.concatenate([grant[:, 1:], inf_col], axis=1)

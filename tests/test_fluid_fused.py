@@ -601,3 +601,47 @@ def test_dense_layout_is_memoised_on_content():
     pinned = dense_layout(padded, rows=12)
     assert pinned[0] == ((padded[0].capacity.shape[0], 12),)
     assert pinned[1] is not a[1]
+
+
+# ---------------------------------------------------------------------------
+# per-hop reads: one gather per data-dependency phase
+# ---------------------------------------------------------------------------
+
+def _hop_gathers(jaxpr, F, H, stack=""):
+    """Name stacks of the gathers in ``jaxpr`` (and its sub-jaxprs)
+    whose result ends in the per-hop shape ``[F, H]`` (``[F, H]`` from
+    one vector, ``[C, F, H]`` from C stacked ones)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        names = f"{stack}/{eqn.source_info.name_stack}"
+        if (eqn.primitive.name == "gather"
+                and eqn.outvars[0].aval.shape[-2:] == (F, H)):
+            found.append(names)
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    found += _hop_gathers(inner, F, H, names)
+    return found
+
+
+@pytest.mark.parametrize("multipath", [False, True],
+                         ids=["k1_v1", "k4_v2"])
+def test_step_reads_hop_values_in_four_gathers(multipath):
+    """Each phase's per-queue and per-wire values reach the flows' hops
+    through one gather of a ``[C, S + 1]`` table: four a step, whatever
+    K and V (UGAL's candidate reads under ``fluid.select`` aside)."""
+    from repro.core.params import LinkParams
+    if multipath:
+        cfg = CCSpec(routing="ugal", link=LinkParams(n_vcs=2))
+        kw = dict(n_paths=4, route_seed=0)
+    else:
+        cfg, kw = PAPER_CONFIG, {}
+    scn = ScenarioSpec.permutation(
+        16, seed=2, fabric=FabricSpec.fat_tree(4, taper=2), **kw).build(cfg)
+    st = init_state(scn, cfg)
+    F, _, H = scenario_device(scn, n_vcs=2 if multipath else 1
+                              ).alt_routes.shape
+    jaxpr = jax.make_jaxpr(make_step_fn(scn, cfg))(st).jaxpr
+    hop = [s for s in _hop_gathers(jaxpr, F, H) if "fluid.select" not in s]
+    assert len(hop) == 4, hop
