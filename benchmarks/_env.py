@@ -3,7 +3,7 @@
 Numbers from a CPU interpreter and numbers from a TPU are different
 experiments; a bench record that omits the platform invites comparing
 them.  Every bench merges :func:`bench_env` into its record so the
-backend, device kind and interpret-mode flag ride with the data.
+backend and device kind ride with the data.
 """
 
 from __future__ import annotations
@@ -42,16 +42,7 @@ def use_compile_cache() -> str:
     return path
 
 
-def pallas_interpret() -> bool:
-    """Whether Pallas kernels must run in interpret mode here: only on a
-    CPU backend.  On a TPU they compile, and a kernel the compiler
-    refuses fails loudly instead of falling back to the interpreter."""
-    import jax
-
-    return jax.default_backend() == "cpu"
-
-
-def bench_env(interpret: bool = False) -> dict:
+def bench_env() -> dict:
     """Backend/platform facts for a bench record (cheap, no device
     work beyond enumerating what jax already initialised).
 
@@ -69,7 +60,6 @@ def bench_env(interpret: bool = False) -> dict:
         "device_kind": devs[0].device_kind if devs else "none",
         "n_devices": len(devs),
         "jax_version": jax.__version__,
-        "interpret": bool(interpret),
         "compile_cache": (jax.config.jax_compilation_cache_dir
                           if jax.config.jax_enable_compilation_cache
                           else None) or None,

@@ -19,21 +19,14 @@ N_STEPS = 4000
 N_STEPS_QUICK = 1200
 
 
-def run_matrix(quick: bool = False,
-               use_kernels: "bool | str" = False) -> dict:
-    """Execute the registry product; returns the BENCH record.
-
-    ``use_kernels="mega"`` runs the same matrix through the whole-step
-    megakernel (interpret mode on a CPU backend only): the stage codes are traced
-    data *inside* the kernel, so the full combination product must
-    still resolve to exactly one executable build.
-    """
+def run_matrix(quick: bool = False) -> dict:
+    """Execute the registry product; returns the BENCH record."""
     from repro.core import CCSpec, ScenarioSpec, Sweep, cc
     from repro.core.experiments import SWEEP_EXEC_CACHE
     try:
-        from ._env import bench_env, pallas_interpret
+        from ._env import bench_env
     except ImportError:              # `python benchmarks/cc_matrix.py`
-        from _env import bench_env, pallas_interpret
+        from _env import bench_env
 
     from repro.core import DCQCNParams, SimParams
 
@@ -57,11 +50,10 @@ def run_matrix(quick: bool = False,
     scn = ScenarioSpec.paper_incast(roll=0, t_start=0.1e-3,
                                     label="hol")
     n_steps = (N_STEPS_QUICK if quick else N_STEPS) * 4
-    interpret = bool(use_kernels) and pallas_interpret()
     misses0 = SWEEP_EXEC_CACHE.stats().misses
     t0 = time.perf_counter()
     res = Sweep.grid(configs=configs, scenarios={"hol": scn}).run(
-        n_steps=n_steps, use_kernels=use_kernels, interpret=interpret)
+        n_steps=n_steps)
     wall = time.perf_counter() - t0
     compiles = SWEEP_EXEC_CACHE.stats().misses - misses0
     points = []
@@ -76,9 +68,8 @@ def run_matrix(quick: bool = False,
         })
     return {
         "unix_time": int(time.time()),
-        **bench_env(interpret=interpret),
+        **bench_env(),
         "quick": quick,
-        "use_kernels": str(use_kernels),
         "n_steps": n_steps,
         "n_points": len(points),
         "compiles": compiles,
@@ -131,21 +122,6 @@ def main(quick: bool = False) -> list[tuple]:
         rows.append(("cc_matrix.one_launch", record["wall_s"] * 1e6,
                      f"{record['n_points']} combos, 1 compile, "
                      f"{record['wall_s']:.1f}s"))
-    # the same matrix through the megakernel: stage dispatch rides the
-    # traced codes inside the single pallas_call, so the whole product
-    # must again be ONE executable build (always at quick depth — this
-    # pass gates the compile counter, not throughput)
-    mega = run_matrix(quick=True, use_kernels="mega")
-    append_matrix_record(mega)
-    if mega["compiles"] != 1:
-        rows.append(("cc_matrix.MEGA_RECOMPILE", 0.0,
-                     f"{mega['n_points']} stage combinations took "
-                     f"{mega['compiles']} megakernel builds; the "
-                     f"matrix must ride ONE kernel build"))
-    else:
-        rows.append(("cc_matrix.mega_one_launch", mega["wall_s"] * 1e6,
-                     f"{mega['n_points']} combos through the "
-                     f"megakernel, 1 compile, {mega['wall_s']:.1f}s"))
     return rows
 
 
@@ -154,5 +130,5 @@ if __name__ == "__main__":
     rows = main(quick="--quick" in sys.argv)
     for row in rows:
         print(",".join(str(x) for x in row))
-    if any("RECOMPILE" in r[0] for r in rows):   # covers MEGA_RECOMPILE
+    if any("RECOMPILE" in r[0] for r in rows):
         raise SystemExit(1)

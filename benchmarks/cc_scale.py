@@ -2,9 +2,8 @@
 
 The paper's scenario has 5 flows; a datacenter NIC fleet runs the RP/ERP
 machine for 10^5+ flows.  This measures flow-updates/second of the
-reaction-point update at increasing F (jnp reference path; the Pallas
-cc_step kernel targets TPU and is validated in interpret mode by tests),
-the full fluid-model step at permutation-traffic scale, and the batched
+reaction-point update at increasing F (the jnp reference path), the
+full fluid-model step at permutation-traffic scale, and the batched
 Sweep engine's run-throughput (an incast-degree x scheme grid as one
 launch vs the legacy one-run-at-a-time loop).
 """

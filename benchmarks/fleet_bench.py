@@ -113,7 +113,7 @@ def run_fleet_bench(quick: bool = False) -> dict:
     record = {
         "unix_time": int(time.time()),
         "quick": quick,
-        **_env().bench_env(interpret=False),
+        **_env().bench_env(),
         "n_points": len(sweep.points),
         "n_shards": s.n_shards,
         "n_workers": cfg.n_workers,

@@ -220,7 +220,7 @@ def append_bench_record(records: list[dict], path: str = BENCH_PATH) -> None:
         from _env import bench_env
     doc.setdefault("runs", []).append({
         "unix_time": int(time.time()),
-        **bench_env(interpret=False),
+        **bench_env(),
         "records": records,
     })
     with open(path, "w") as f:

@@ -5,20 +5,10 @@ F/L scaling curve of single-device grid points:
 
   * steps/sec of the jitted decimating scan, per reduction engine
     (``scat`` = legacy scatter baseline, ``fused`` = sorted-incidence
-    one-pass reduction with the dense-CSR tiles when load skew allows)
-    plus the ``mega`` whole-step kernel (one launch per trace window,
-    interpret mode on a CPU backend only)
+    one-pass reduction with the dense layout when load skew allows)
   * compile seconds per engine (first call minus steady state)
   * incidence shape per point (F, L, K, H, rows = N = F*K*H,
     ``dense_rows`` = max per-link contributors)
-  * ``ops_per_step`` — jaxpr equations per substep for the fused
-    reference vs the megakernel block, and their ratio
-    (``op_reduction``).  On CPU the megakernel runs in interpreter
-    mode, so its *wall clock* does not show the launch fusion; the op
-    count is the machine-independent form of "one launch instead of a
-    few hundred ops per substep", and it is what the mega gate checks
-    (``op_reduction`` must hold >= MEGA_OP_REDUCTION_FLOOR and not
-    regress > TOLERANCE vs the committed baseline).
 
 Every invocation appends a run record to ``BENCH_fluid.json`` at the
 repo root — the perf trajectory the ROADMAP's "fast as the hardware
@@ -55,11 +45,6 @@ TOLERANCE = 0.20
 #: scatter/segment-sum lowering differences.
 FLOOR_CAP = 2.0
 
-#: the megakernel must fold at least this many jaxpr equations per
-#: substep into its single launch (the acceptance bar is 5x; the
-#: measured reduction is ~100x, so this is a collapse detector)
-MEGA_OP_REDUCTION_FLOOR = 5.0
-
 N_STEPS = 400
 N_STEPS_QUICK = 200
 
@@ -88,33 +73,19 @@ def _grid(quick: bool):
     return points
 
 
-def _interpret() -> bool:
-    """Interpret mode for the mega cells: on a CPU backend only."""
-    try:
-        from ._env import pallas_interpret
-    except ImportError:              # `python benchmarks/perf_fluid.py`
-        from _env import pallas_interpret
-    return pallas_interpret()
-
-
 def _bench_point(spec, n_steps: int, engine: str) -> dict:
     import jax
     from repro.core import PAPER_CONFIG
     from repro.core.fluid import init_state, make_step_fn
-    from repro.core.simulator import decimating_scan, make_block_fn
+    from repro.core.simulator import decimating_scan
 
     cfg = PAPER_CONFIG
     scn = spec.build(cfg)
     st0 = init_state(scn, cfg)
     k = 10
-    if engine == "mega":
-        block = make_block_fn(scn, cfg, k, interpret=_interpret())
-        fn = jax.jit(lambda st: decimating_scan(
-            None, st, n_steps // k, k, cfg.sim.dt, block_fn=block))
-    else:
-        step = make_step_fn(scn, cfg, reduce=engine)
-        fn = jax.jit(lambda st: decimating_scan(step, st, n_steps // k, k,
-                                                cfg.sim.dt))
+    step = make_step_fn(scn, cfg, reduce=engine)
+    fn = jax.jit(lambda st: decimating_scan(step, st, n_steps // k, k,
+                                            cfg.sim.dt))
     t0 = time.perf_counter()
     jax.block_until_ready(fn(st0))
     compile_s = time.perf_counter() - t0
@@ -125,32 +96,6 @@ def _bench_point(spec, n_steps: int, engine: str) -> dict:
         best = min(best, time.perf_counter() - t0)
     return {"steps_per_s": round(n_steps / best, 1),
             "compile_s": round(compile_s - best, 2)}
-
-
-def _ops_per_step(spec, k: int = 10) -> dict:
-    """Jaxpr equations per substep: fused reference vs megakernel block.
-
-    The fused step traces to a few hundred equations, each an XLA op
-    (and on TPU, one or more kernel launches); the megakernel block is
-    a single ``pallas_call`` equation covering ``k`` substeps.  The
-    ratio is the machine-independent measure of the launch fusion —
-    wall-clock on the CPU interpret path cannot show it.
-    """
-    import jax
-    from repro.core import PAPER_CONFIG
-    from repro.core.fluid import init_state, make_step_fn
-    from repro.core.simulator import make_block_fn
-
-    cfg = PAPER_CONFIG
-    scn = spec.build(cfg)
-    st0 = init_state(scn, cfg)
-    step = make_step_fn(scn, cfg)
-    ref_eqns = len(jax.make_jaxpr(step)(st0).eqns)
-    block = make_block_fn(scn, cfg, k, interpret=_interpret())
-    blk_eqns = len(jax.make_jaxpr(block)(st0).eqns)
-    return {"ref": ref_eqns, "mega_block": blk_eqns,
-            "mega": round(blk_eqns / k, 2),
-            "reduction": round(ref_eqns / (blk_eqns / k), 1)}
 
 
 def run_perf(quick: bool = False) -> dict:
@@ -173,23 +118,14 @@ def run_perf(quick: bool = False) -> dict:
             "dense_rows": dense_reduce_rows(scn),
             "steps": n_steps,
         }
-        for engine in ("scat", "fused", "mega"):
+        for engine in ("scat", "fused"):
             rec[engine] = _bench_point(spec, n_steps, engine)
         rec["speedup"] = round(
             rec["fused"]["steps_per_s"] / rec["scat"]["steps_per_s"], 2)
-        # interpret-mode wall clock, recorded honestly (CPU pays the
-        # interpreter; the launch fusion shows in ops_per_step)
-        rec["mega_speedup"] = round(
-            rec["mega"]["steps_per_s"] / rec["fused"]["steps_per_s"], 2)
-        rec["ops_per_step"] = _ops_per_step(spec)
         points.append(rec)
         print(f"perf.{name}: scat={rec['scat']['steps_per_s']:.0f}/s "
               f"fused={rec['fused']['steps_per_s']:.0f}/s "
               f"speedup={rec['speedup']:.2f}x "
-              f"mega={rec['mega']['steps_per_s']:.0f}/s "
-              f"ops/step {rec['ops_per_step']['ref']}->"
-              f"{rec['ops_per_step']['mega']:g} "
-              f"({rec['ops_per_step']['reduction']:.0f}x fewer) "
               f"(F={F} L={rec['L']} K={K} dense_rows={rec['dense_rows']})")
     try:
         from ._env import bench_env
@@ -197,8 +133,7 @@ def run_perf(quick: bool = False) -> dict:
         from _env import bench_env
     return {
         "unix_time": int(time.time()),
-        # the mega cells' mode; the scat/fused cells never interpret
-        **bench_env(interpret=_interpret()),
+        **bench_env(),
         "quick": quick,
         "points": points,
     }
@@ -245,23 +180,6 @@ def check_regression(record: dict, baseline: dict | None = None,
                 f"{p['name']}: fused/scat speedup {p['speedup']:.2f}x "
                 f"< {floor:.2f}x (baseline {b['speedup']:.2f}x "
                 f"- {tolerance:.0%}, capped at {FLOOR_CAP:.1f}x)")
-        # megakernel gate: the per-substep op reduction (the launch
-        # fusion, machine-independent) must hold the absolute floor
-        # and stay within TOLERANCE of the committed baseline's
-        ops = p.get("ops_per_step")
-        if ops is None:
-            continue
-        mega_floor = MEGA_OP_REDUCTION_FLOOR
-        if b.get("ops_per_step"):
-            mega_floor = max(mega_floor, (1.0 - tolerance) *
-                             b["ops_per_step"]["reduction"])
-        if ops["reduction"] < mega_floor:
-            fails.append(
-                f"{p['name']}: megakernel op reduction "
-                f"{ops['reduction']:.1f}x < {mega_floor:.1f}x "
-                f"(ref {ops['ref']} eqns/step vs mega "
-                f"{ops['mega']:g}; floor {MEGA_OP_REDUCTION_FLOOR:.0f}x"
-                f" abs / baseline - {tolerance:.0%})")
     return fails
 
 
@@ -275,10 +193,7 @@ def main(quick: bool = False, check: bool = False) -> list[tuple]:
         rows.append((f"perf_fluid.{p['name']}",
                      1e6 / p["fused"]["steps_per_s"],
                      f"fused={p['fused']['steps_per_s']:.0f}/s "
-                     f"speedup={p['speedup']:.2f}x "
-                     f"mega_ops {p['ops_per_step']['ref']}->"
-                     f"{p['ops_per_step']['mega']:g}/step "
-                     f"({p['ops_per_step']['reduction']:.0f}x)"))
+                     f"speedup={p['speedup']:.2f}x"))
     for f in fails:
         rows.append(("perf_fluid.REGRESSION", 0.0, f))
     return rows

@@ -1,5 +1,5 @@
 """§Roofline — three-term roofline from the dry-run artifacts, plus
-the analytic roofline of the CC hot-loop kernels.
+the analytic roofline of the CC fluid step.
 
 Per (arch x shape) on the single-pod mesh:
     compute    = HLO_FLOPs / peak_FLOPs            (per device)
@@ -8,13 +8,13 @@ Per (arch x shape) on the single-pod mesh:
 plus MODEL_FLOPS = 6·N·D (6·N_active·D for MoE) and the useful-compute
 ratio MODEL_FLOPS / HLO_FLOPs (catches remat/redundancy waste).
 
-CC kernels (``cc_kernel_rows``): the fluid-reduce segment reduction
-and the fused per-flow block (gen/np-timer + RP + ERP) are pure
-bandwidth shapes — a handful of adds per element over many state
-vectors — so their roofline is the HBM term: one read of every input
-vector + one write of every output per dt.  The rows report the
-bytes-per-step each kernel moves at DC scale and the implied ceiling
-on steps/sec, alongside the attention kernels' measured cells.
+CC fluid step (``fluid_step_rows``): the link reductions and the
+per-flow block are pure bandwidth shapes — a handful of adds per
+element over many state vectors — so the step's roofline is the HBM
+term: one read of every input vector + one write of every output per
+dt.  The rows report the bytes-per-step the step moves at DC scale and
+the implied ceiling on steps/sec, alongside the attention kernels'
+measured cells.
 
 Hardware constants (TPU v5e per assignment): 197 TFLOP/s bf16,
 819 GB/s HBM, ~50 GB/s/link ICI.
@@ -101,18 +101,14 @@ def to_markdown(rows: list[dict]) -> str:
     return hdr + body
 
 
-def cc_kernel_rows() -> list[dict]:
-    """Analytic roofline cells for the fluid hot-loop kernels.
+def fluid_step_rows() -> list[dict]:
+    """Analytic roofline cells for one fluid step at DC scale.
 
     Shapes follow the perf harness's scaling curve extrapolated to DC
-    scale (10^5..10^6 flows).  Bytes are f32 vectors moved per dt:
-
-      * fluid_reduce — per reduction pass: [N, C] data + int32 segment
-        ids in, [S, C] sums out; the fluid step runs 3 passes with
-        (3, 3, 2) channels over N = F*K*H rows, S = L+1 links.
-      * cc_flow_block — gen/np-timer (9 in / 4 out), RP (9/8) and ERP
-        (5/5 incl. params) per-flow kernels: 40 [F] vectors total, the
-        "one HBM round trip per state vector" budget.
+    scale (10^5..10^6 flows).  Bytes are the f32 vectors the step moves
+    per dt: three reduction passes with (3, 3, 2) channels over
+    N = F*K*H rows into S = L+1 links, and 40 [F] per-flow vectors
+    (generation, notification timer, reaction state).
 
     The bytes model is :func:`repro.fleet.plan.fluid_step_bytes` — the
     SAME formula the fleet planner balances shards with, imported so
@@ -122,87 +118,20 @@ def cc_kernel_rows() -> list[dict]:
 
     rows = []
     for F, K, H, L in [(1 << 17, 1, 6, 1 << 14), (1 << 20, 4, 6, 1 << 16)]:
-        n = F * K * H
-        flow_bytes = 40 * F * 4
-        red_bytes = fluid_step_bytes(F, K, H, L) - flow_bytes
-        red_flops = sum(c * n for c in (3, 3, 2))
-        flow_flops = 60 * F
-        for name, byts, flops in [
-                ("fluid_reduce", red_bytes, red_flops),
-                ("cc_flow_block", flow_bytes, flow_flops)]:
-            t_mem = byts / HBM_BW
-            t_comp = flops / PEAK_FLOPS
-            rows.append({
-                "kernel": name,
-                "shape": f"f{F}k{K}l{L}",
-                "bytes_per_step": byts,
-                "memory_s": t_mem,
-                "compute_s": t_comp,
-                "dominant": "memory" if t_mem >= t_comp else "compute",
-                "steps_per_s_ceiling": 1.0 / max(t_mem, t_comp),
-            })
+        byts = fluid_step_bytes(F, K, H, L)
+        flops = sum(c * F * K * H for c in (3, 3, 2)) + 60 * F
+        t_mem = byts / HBM_BW
+        t_comp = flops / PEAK_FLOPS
+        rows.append({
+            "kernel": "fluid_step",
+            "shape": f"f{F}k{K}l{L}",
+            "bytes_per_step": byts,
+            "memory_s": t_mem,
+            "compute_s": t_comp,
+            "dominant": "memory" if t_mem >= t_comp else "compute",
+            "steps_per_s_ceiling": 1.0 / max(t_mem, t_comp),
+        })
     return rows
-
-
-def megakernel_rows(blocks: tuple = (1, 10, 100)) -> list[dict]:
-    """Analytic roofline cells for the whole-step megakernel
-    (``repro.kernels.fluid_step``), per substep-block size.
-
-    One launch runs ``block`` substeps with the fluid state
-    VMEM-resident; HBM traffic per launch is one read of state +
-    scenario and one write of state + the decimated ``TraceSample``
-    row, so bytes *per substep* fall as ``1/block`` while in-kernel
-    FLOPs per substep stay constant (the reduction + per-flow update
-    math).  The VMEM footprint (state in + out + scenario — the number
-    ``mega_footprint`` checks against ``MEGA_VMEM_CAP``) is
-    block-independent: blocking buys bandwidth, not residency.
-
-    State model (f32): 2 [F, H] hop tensors (queues + EWMA), ~23 [F]
-    flow vectors (counters, rates, CC state dict), 2 [D, F] delay-line
-    rings (D = 32 slots); scenario: [F, H] routes + 3 [F*K*H]
-    incidence/alt tables + per-link capacity/sink; sample: ~11 [F]
-    trace channels.
-    """
-    D = 32
-    rows = []
-    for F, K, H, L in [(1 << 17, 1, 6, 1 << 14), (1 << 20, 4, 6, 1 << 16)]:
-        state = 4 * (2 * F * H + 23 * F + 2 * D * F)
-        scen = 4 * (F * H + 3 * F * K * H + 2 * (L + 2))
-        sample = 4 * 11 * F
-        n = F * K * H
-        flops = sum(c * n for c in (3, 3, 2)) + 60 * F   # per substep
-        vmem = 2 * state + scen
-        for blk in blocks:
-            byts = (2 * state + scen + sample) / blk
-            t_mem = byts / HBM_BW
-            t_comp = flops / PEAK_FLOPS
-            rows.append({
-                "kernel": f"fluid_megastep_k{blk}",
-                "shape": f"f{F}k{K}l{L}",
-                "block": blk,
-                "bytes_per_step": byts,
-                "flops_per_step": flops,
-                "vmem_bytes": vmem,
-                "memory_s": t_mem,
-                "compute_s": t_comp,
-                "dominant": "memory" if t_mem >= t_comp else "compute",
-                "steps_per_s_ceiling": 1.0 / max(t_mem, t_comp),
-            })
-    return rows
-
-
-def mega_to_markdown(rows: list[dict]) -> str:
-    hdr = ("| kernel | shape | block | MB/step | MFLOP/step | VMEM MB | "
-           "dominant | steps/s ceiling |\n|---|---|---|---|---|---|---|"
-           "---|\n")
-    body = ""
-    for r in rows:
-        body += (f"| {r['kernel']} | {r['shape']} | {r['block']} | "
-                 f"{r['bytes_per_step'] / 2**20:.1f} | "
-                 f"{r['flops_per_step'] / 1e6:.1f} | "
-                 f"{r['vmem_bytes'] / 2**20:.1f} | **{r['dominant']}** | "
-                 f"{r['steps_per_s_ceiling']:.3e} |\n")
-    return hdr + body
 
 
 def cc_to_markdown(rows: list[dict]) -> str:
@@ -219,16 +148,12 @@ def cc_to_markdown(rows: list[dict]) -> str:
 
 def main() -> list[tuple]:
     rows = build_table()
-    cc_rows = cc_kernel_rows()
-    mega_rows = megakernel_rows()
+    cc_rows = fluid_step_rows()
     os.makedirs("artifacts", exist_ok=True)
     with open("artifacts/roofline.md", "w") as f:
         f.write(to_markdown(rows))
-        f.write("\n## CC hot-loop kernels (analytic)\n\n")
+        f.write("\n## CC fluid step (analytic)\n\n")
         f.write(cc_to_markdown(cc_rows))
-        f.write("\n## Whole-step megakernel vs substep block (analytic)"
-                "\n\n")
-        f.write(mega_to_markdown(mega_rows))
     out = []
     for r in rows:
         out.append((f"roofline.{r['arch']}.{r['shape']}",
@@ -240,12 +165,6 @@ def main() -> list[tuple]:
         out.append((f"roofline.cc.{r['kernel']}.{r['shape']}",
                     r["memory_s"] * 1e6,
                     f"dom={r['dominant']} "
-                    f"ceil={r['steps_per_s_ceiling']:.2e}steps/s"))
-    for r in mega_rows:
-        out.append((f"roofline.cc.{r['kernel']}.{r['shape']}",
-                    max(r["memory_s"], r["compute_s"]) * 1e6,
-                    f"dom={r['dominant']} "
-                    f"vmem={r['vmem_bytes'] / 2**20:.0f}MB "
                     f"ceil={r['steps_per_s_ceiling']:.2e}steps/s"))
     return out
 

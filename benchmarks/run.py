@@ -14,14 +14,10 @@ single jitted launch) and exits non-zero on failure — the CI hook.
 ``--scale`` runs only the fabric matrix and appends a record to
 ``BENCH_net.json`` (``--quick`` shrinks it to CI size).
 ``--perf`` runs the fluid hot-loop F/L scaling curve (legacy scatter
-path vs fused one-pass reduction vs the whole-step megakernel) and
-appends a record to ``BENCH_fluid.json``; with ``--check`` it exits
-non-zero when the fused/scat speedup falls below 80% of the committed
-baseline's (floor capped at 2.0x for cross-runner noise) or when the
-megakernel's per-substep op reduction drops below 5x / regresses >20%
-vs baseline (the launch-fusion gate; CPU wall clock runs the
-interpreter, so the jaxpr op count is the machine-stable metric) —
-the CI perf-smoke gate.
+path vs fused one-pass reduction) and appends a record to
+``BENCH_fluid.json``; with ``--check`` it exits non-zero when the
+fused/scat speedup falls below 80% of the committed baseline's (floor
+capped at 2.0x for cross-runner noise) — the CI perf-smoke gate.
 ``--serve`` replays the mixed what-if query stream through
 ``CCQueryEngine`` and appends a record to ``BENCH_serve.json``; with
 ``--check`` it exits non-zero on a p99 latency regression vs the
@@ -44,10 +40,7 @@ baseline (the CI fleet-smoke gate).
 ``--cc-matrix`` enumerates the ``repro.core.cc`` stage registries
 (every marking x notification x reaction combination) as ONE Sweep
 launch, appends the rows to ``BENCH_fluid.json`` under ``cc_matrix``
-and exits non-zero if the matrix needed more than one compile — then
-repeats the matrix through the megakernel (``use_kernels="mega"``),
-where the same one-build assertion must hold on the single
-pallas_call.
+and exits non-zero if the matrix needed more than one compile.
 """
 
 from __future__ import annotations
@@ -154,8 +147,7 @@ def main() -> None:
                     help="with --perf: fail when fused/scat speedup "
                          "drops below 80%% of the committed "
                          "BENCH_fluid.json baseline (floor capped at "
-                         "2.0x for cross-runner noise) or the "
-                         "megakernel op reduction below 5x/-20%%")
+                         "2.0x for cross-runner noise)")
     ap.add_argument("--serve", action="store_true",
                     help="what-if query engine replay -> BENCH_serve.json "
                          "(--check gates on p99 regression, hit-rate "

@@ -119,7 +119,7 @@ def run_replay(quick: bool = False) -> dict:
         from _env import bench_env
     return {
         "unix_time": int(time.time()),
-        **bench_env(interpret=False),
+        **bench_env(),
         "quick": quick,
         "n_queries": n_queries,
         "n_steps": n_steps,

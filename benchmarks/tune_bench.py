@@ -97,7 +97,7 @@ def run_tune(quick: bool = False) -> dict:
         from _env import bench_env
     return {
         "unix_time": int(time.time()),
-        **bench_env(interpret=False),
+        **bench_env(),
         "quick": quick,
         "scenario": SCENARIO,
         "n_steps": N_STEPS,

@@ -27,8 +27,6 @@ One-chip phases, in order:
               and a warm launch split into host staging, execution and
               fetch; peak device memory, sanity, and the CC counters
               of each scheme (the storm must engage the loop)
-  kernels     ``reduce="pallas"`` and the flow-kernel tier on that grid
-              against the default engine
   whatif      8 queries over 2 shape signatures through
               ``CCQueryEngine``, each against a standalone ``Sweep.run``
   fleet       the paper grid through ``run_fleet`` (2 threads) against
@@ -54,7 +52,7 @@ sys.path[:0] = [os.path.join(ROOT, "src"), os.path.join(ROOT, "tests")]
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from benchmarks._env import pallas_interpret, use_compile_cache  # noqa: E402
+from benchmarks._env import use_compile_cache  # noqa: E402
 from repro.core import (SWEEP_EXEC_CACHE, CCSpec, ScenarioSpec,  # noqa: E402
                         Sweep, config_grid, obs)
 from repro.core.experiments import _sweep_executable  # noqa: E402
@@ -338,17 +336,6 @@ def phase_real_size():
     check(s["PFC_ONLY"]["pause_s"] > s["DCQCN"]["pause_s"]
           > s["DCQCN_REV"]["pause_s"],
           "pause time PFC_ONLY > DCQCN > DCQCN_REV")
-    return sweep, res
-
-
-def phase_kernels(sweep: Sweep, ref) -> None:
-    p = "kernels"
-    for label, kw in (('reduce="pallas"', {"reduce": "pallas"}),
-                      ("use_kernels=True", {"use_kernels": True})):
-        got, compile_s, wall = _timed_run(
-            sweep, n_steps=REAL_STEPS, interpret=pallas_interpret(), **kw)
-        say(p, f"{label}: compile_s={compile_s!r} wall_s={wall!r}")
-        compare_engines(p, label, ref, got)
 
 
 def phase_whatif() -> None:
@@ -454,8 +441,7 @@ def main(argv=None) -> int:
         phase_four_chips()
     else:
         paper, paper_res = phase_paper()
-        real, real_res = phase_real_size()
-        phase_kernels(real, real_res)
+        phase_real_size()
         phase_whatif()
         phase_fleet(paper, paper_res)
     say("done", f"compile_s_total={SWEEP_EXEC_CACHE.stats().build_s!r} "

@@ -28,17 +28,6 @@ The protocol (one entry per ``StageRegistry``):
     what lets any (marking x notification x reaction x param grid)
     product compile to ONE ``Sweep`` launch — exactly like
     ``route_code`` for adaptive routing.
-  * ``kernel_step`` — optional Pallas form of ``step`` (same signature
-    + ``interpret=`` and an optional ``packed=`` prepacked SMEM param
-    row, see ``pack_react_rows``), used when
-    ``fluid_step(use_kernels=True)``.
-  * ``kernel_body`` — optional *in-kernel* form of ``step``: the body
-    the whole-step megakernel (``use_kernels="mega"``) traces inside
-    its single ``pallas_call``.  It must stay plain jnp — no nested
-    ``pallas_call`` — and defaults to ``step`` itself (the built-in
-    stages' updates are already elementwise/small-reduction jnp, which
-    is exactly the in-kernel contract).  Register a dedicated body only
-    when a stage's ``step`` does something a kernel trace cannot.
 
 Dispatch (``dispatch``) evaluates every registered stage and selects by
 the traced integer code — stage selection is *data*, so a grid mixing
@@ -125,10 +114,6 @@ class Stage:
     step: Callable                    # (params, ctx, state) -> (out, upd)
     int_params: frozenset = frozenset()   # fields traced as int32
     init_state: Callable | None = None
-    kernel_step: Callable | None = None
-    # in-kernel (megakernel) form of ``step``; None falls back to
-    # ``step`` itself, which is valid whenever the update is plain jnp
-    kernel_body: Callable | None = None
     # reaction stages only: does this stage read the mark/CNP feedback?
     # Mark-free reactions (swift's delay signal) make the marking axis
     # dead — ablation grids cross it only for consumers.
@@ -146,8 +131,6 @@ class StageRegistry:
                  params: dict | None = None,
                  int_params: tuple = (),
                  init_state: Callable | None = None,
-                 kernel_step: Callable | None = None,
-                 kernel_body: Callable | None = None,
                  consumes_marks: bool = True) -> Stage:
         if name in self._stages:
             raise ValueError(
@@ -156,8 +139,6 @@ class StageRegistry:
                       code=len(self._stages), params=dict(params or {}),
                       int_params=frozenset(int_params),
                       step=step, init_state=init_state,
-                      kernel_step=kernel_step,
-                      kernel_body=kernel_body,
                       consumes_marks=consumes_marks)
         self._stages[name] = stage
         return stage
@@ -254,38 +235,18 @@ def _select(code, outs):
 
 
 def dispatch(registry: StageRegistry, code, params: dict, ctx,
-             state: dict, *, use_kernels: bool = False,
-             interpret: bool = False, in_kernel: bool = False,
-             packed: dict | None = None):
+             state: dict):
     """Evaluate every stage of ``registry`` and select by traced code.
 
     Returns ``(outputs, family_state)`` where ``family_state`` maps
     every state key any stage of this family owns to its post-step
     value (non-selected stages pass their keys through unchanged, so
     merging families back into ``FluidState.cc`` is a dict union).
-
-    ``in_kernel`` marks a trace already inside the megakernel launch:
-    stages run their ``kernel_body`` (default: ``step``) and must not
-    open a nested ``pallas_call``, so ``use_kernels`` is ignored.
-    ``packed`` optionally maps stage names to prepacked kernel param
-    rows (``pack_react_rows``); it is forwarded to ``kernel_step`` only
-    when present, keeping third-party kernel stages (which may not
-    accept the kwarg) working unchanged.
     """
     outs = []
     owned: set[str] = set()
     for stage in registry.stages():
-        if in_kernel:
-            main, upd = (stage.kernel_body or stage.step)(params, ctx,
-                                                          state)
-        elif use_kernels and stage.kernel_step is not None:
-            kw = {}
-            if packed is not None and stage.name in packed:
-                kw["packed"] = packed[stage.name]
-            main, upd = stage.kernel_step(params, ctx, state,
-                                          interpret=interpret, **kw)
-        else:
-            main, upd = stage.step(params, ctx, state)
+        main, upd = stage.step(params, ctx, state)
         owned.update(upd)
         outs.append((main, upd))
     full = []
@@ -294,39 +255,6 @@ def dispatch(registry: StageRegistry, code, params: dict, ctx,
         merged.update(upd)
         full.append((main, merged))
     return _select(code, full)
-
-
-def pack_react_rows(react: dict, line_rate, dt) -> dict:
-    """Prepacked ``(1, NP)`` SMEM param rows per built-in reaction stage.
-
-    The per-flow reaction kernels (``repro.kernels.cc_step``) take
-    their scalars as one packed row; rebuilding it inside a scanned
-    step re-traces the stack every substep.  The rows are pure
-    functions of a run's constants, so callers holding the traced
-    params (``make_step_fn``, the sweep engine) pack them ONCE per
-    launch and thread the result through ``dispatch(packed=...)``.
-    Row layouts live with the kernels (``cc_step.pack_rp_params`` and
-    friends) so the order has a single definition.
-    """
-    from repro.kernels import cc_step
-    from repro.kernels.ref import ERPParams, RPParams, SwiftKParams
-    rp = RPParams(g=react["rp_g"], rate_decrease=react["rp_rdf"],
-                  timer_T=react["rp_timer"], byte_B=react["rp_byte"],
-                  rai=react["rp_rai"], rhai=react["rp_rhai"],
-                  fr_stages=react["rp_fr_stages"].astype(jnp.float32),
-                  min_rate=react["rp_min_rate"], line_rate=line_rate,
-                  dt=dt)
-    erp = ERPParams(settle=react["erp_settle"], hold=react["erp_hold"],
-                    min_rate=react["erp_min_rate"], line_rate=line_rate,
-                    dt=dt)
-    swift = SwiftKParams(target=react["swift_target"],
-                         beta=react["swift_beta"], ai=react["swift_ai"],
-                         guard=react["swift_guard"],
-                         min_rate=react["swift_min_rate"],
-                         line_rate=line_rate, dt=dt)
-    return {"rp": cc_step.pack_rp_params(rp),
-            "erp": cc_step.pack_erp_params(erp),
-            "swift": cc_step.pack_swift_params(swift)}
 
 
 # ---------------------------------------------------------------------------
@@ -629,35 +557,6 @@ def _react_rp(p, ctx: ReactCtx, state):
     return out, {}
 
 
-def _react_rp_kernel(p, ctx: ReactCtx, state, *, interpret, packed=None):
-    from repro.kernels.cc_step import rp_step
-    from repro.kernels.ref import RPParams, RPState
-    out = rp_step(
-        RPState(ctx.rate, ctx.rp_target, ctx.alpha, ctx.byte_cnt,
-                ctx.tmr, ctx.alpha_tmr,
-                ctx.bc_stage.astype(jnp.float32),
-                ctx.t_stage.astype(jnp.float32)),
-        ctx.cnp,
-        RPParams(g=p["rp_g"], rate_decrease=p["rp_rdf"],
-                 timer_T=p["rp_timer"], byte_B=p["rp_byte"],
-                 rai=p["rp_rai"], rhai=p["rp_rhai"],
-                 fr_stages=p["rp_fr_stages"].astype(jnp.float32),
-                 min_rate=p["rp_min_rate"], line_rate=ctx.line_rate,
-                 dt=ctx.dt),
-        interpret=interpret, packed=packed)
-    res = _passthrough(ctx)._replace(
-        rate=out.rate, rp_target=out.target, alpha=out.alpha,
-        byte_cnt=out.byte_cnt, tmr=out.tmr, alpha_tmr=out.alpha_tmr,
-        bc_stage=out.bc_stage.astype(jnp.int32),
-        t_stage=out.t_stage.astype(jnp.int32))
-    return res, {}
-
-
-def _erp_slope(p, ctx: ReactCtx):
-    """Per-flow desynchronised recovery slope (deterministic jitter)."""
-    return p["erp_rai"] * (1.0 + p["erp_jitter"] * ctx.jitter)
-
-
 def _react_erp(p, ctx: ReactCtx, state):
     """ERP: settle to signalled fair share, hold, additive recovery.
 
@@ -672,7 +571,8 @@ def _react_erp(p, ctx: ReactCtx, state):
     settle = jnp.maximum(p["erp_settle"] * ctx.tgt_rx, p["erp_min_rate"])
     rate = pk(settle, ctx.rate)
     hold = pk(p["erp_hold"], jnp.maximum(ctx.hold - dt, 0.0))
-    slope = _erp_slope(p, ctx) * dt
+    # per-flow desynchronised recovery slope (deterministic jitter)
+    slope = p["erp_rai"] * (1.0 + p["erp_jitter"] * ctx.jitter) * dt
     rec_s = (1.0 - c) * soft.unit_gate(-hold, tau, p["erp_hold"] + 1e-9)
     rate = soft.select(tau, rate + rec_s * slope,
                        jnp.where(~cnp & (hold <= 0), rate + slope, rate))
@@ -681,23 +581,11 @@ def _react_erp(p, ctx: ReactCtx, state):
     return _passthrough(ctx)._replace(rate=rate, hold=hold), {}
 
 
-def _react_erp_kernel(p, ctx: ReactCtx, state, *, interpret, packed=None):
-    from repro.kernels.cc_step import erp_step
-    from repro.kernels.ref import ERPParams
-    rate, hold = erp_step(
-        ctx.rate, ctx.hold, ctx.cnp, ctx.tgt_rx, _erp_slope(p, ctx),
-        ERPParams(settle=p["erp_settle"], hold=p["erp_hold"],
-                  min_rate=p["erp_min_rate"], line_rate=ctx.line_rate,
-                  dt=ctx.dt),
-        interpret=interpret, packed=packed)
-    return _passthrough(ctx)._replace(rate=rate, hold=hold), {}
-
-
 def _react_swift(p, ctx: ReactCtx, state):
     """Delay-target throttling on the path queuing-delay estimate.
 
-    Hard path = ``swift_update_ref`` verbatim (the single definition
-    the Pallas kernel reproduces).  Soft path: the over-target and
+    Hard path = ``swift_update_ref`` verbatim (the single definition,
+    in ``repro.kernels.ref``).  Soft path: the over-target and
     cool-down gates become sigmoids, blending the multiplicative
     decrease against the additive recovery — target_delay/beta/ai get
     gradients (the qdelay signal itself is already differentiable).
@@ -728,20 +616,6 @@ def _react_swift(p, ctx: ReactCtx, state):
     return _passthrough(ctx)._replace(rate=rate), {"swift_cool": cool}
 
 
-def _react_swift_kernel(p, ctx: ReactCtx, state, *, interpret,
-                        packed=None):
-    from repro.kernels.cc_step import swift_step
-    from repro.kernels.ref import SwiftKParams
-    rate, cool = swift_step(
-        ctx.rate, state["swift_cool"], ctx.qdelay,
-        SwiftKParams(target=p["swift_target"], beta=p["swift_beta"],
-                     ai=p["swift_ai"], guard=p["swift_guard"],
-                     min_rate=p["swift_min_rate"], line_rate=ctx.line_rate,
-                     dt=ctx.dt),
-        interpret=interpret, packed=packed)
-    return _passthrough(ctx)._replace(rate=rate), {"swift_cool": cool}
-
-
 # ---------------------------------------------------------------------------
 # built-in registration (codes frozen in this order)
 # ---------------------------------------------------------------------------
@@ -751,24 +625,17 @@ def _zeros_f(scn) -> np.ndarray:
     return np.zeros((scn.routes.shape[0],), np.float32)
 
 
-# Every built-in registers an explicit ``kernel_body`` — the in-kernel
-# form the megakernel dispatches on.  For these stages the jnp ``step``
-# IS a valid kernel body (elementwise + [F, H]-axis reductions, no
-# nested pallas_call), so the entries alias it; the point of spelling
-# them out is that the whole marking x notification x reaction matrix
-# is declared megakernel-clean, and a future TPU-hostile stage opts out
-# by registering a dedicated body instead.
 MARKING.register(
-    "cp", step=_mark_cp, kernel_body=_mark_cp,
+    "cp", step=_mark_cp,
     params={"cp_kmin": lambda s: s.dcqcn.kmin,
             "drain_gain": lambda s: s.rev.erp_drain_gain})
 MARKING.register(
-    "ecp", step=_mark_ecp, kernel_body=_mark_ecp,
+    "ecp", step=_mark_ecp,
     params={"ecp_thresh": lambda s: s.rev.detect_threshold,
             "ecp_slack": lambda s: s.rev.ecp_fairness_slack,
             "drain_gain": lambda s: s.rev.erp_drain_gain})
 MARKING.register(
-    "slope", step=_mark_slope, kernel_body=_mark_slope,
+    "slope", step=_mark_slope,
     params={"slope_kmin": lambda s: s.dcqcn.kmin,
             "slope_kmax": lambda s: s.dcqcn.kmax,
             "slope_pmax": lambda s: s.dcqcn.pmax,
@@ -776,21 +643,19 @@ MARKING.register(
     init_state=lambda scn: {"slope_acc": _zeros_f(scn)})
 
 NOTIFICATION.register(
-    "np", step=_notif_np, kernel_body=_notif_np,
+    "np", step=_notif_np,
     params={"np_window": lambda s: s.dcqcn.cnp_window})
 NOTIFICATION.register(
-    "enp", step=_notif_enp, kernel_body=_notif_enp,
+    "enp", step=_notif_enp,
     params={"enp_window": lambda s: s.rev.enp_coalesce})
 NOTIFICATION.register(
-    "fncc", step=_notif_fncc, kernel_body=_notif_fncc,
+    "fncc", step=_notif_fncc,
     params={"fncc_window": lambda s: s.fncc.coalesce,
             "fncc_scale": lambda s: s.fncc.rtt_scale})
 
-REACTION.register("pfc", step=_react_pfc, kernel_body=_react_pfc,
-                  consumes_marks=False)
+REACTION.register("pfc", step=_react_pfc, consumes_marks=False)
 REACTION.register(
-    "rp", step=_react_rp, kernel_step=_react_rp_kernel,
-    kernel_body=_react_rp,
+    "rp", step=_react_rp,
     params={"rp_g": lambda s: s.dcqcn.g,
             "rp_rdf": lambda s: s.dcqcn.rate_decrease_factor,
             "rp_timer": lambda s: s.dcqcn.timer_T,
@@ -801,16 +666,14 @@ REACTION.register(
             "rp_min_rate": lambda s: s.dcqcn.min_rate},
     int_params=("rp_fr_stages",))
 REACTION.register(
-    "erp", step=_react_erp, kernel_step=_react_erp_kernel,
-    kernel_body=_react_erp,
+    "erp", step=_react_erp,
     params={"erp_settle": lambda s: s.rev.erp_settle,
             "erp_rai": lambda s: s.rev.erp_rai,
             "erp_jitter": lambda s: s.rev.erp_jitter,
             "erp_hold": lambda s: s.rev.erp_hold,
             "erp_min_rate": lambda s: s.rev.min_rate})
 REACTION.register(
-    "swift", step=_react_swift, kernel_step=_react_swift_kernel,
-    kernel_body=_react_swift, consumes_marks=False,
+    "swift", step=_react_swift, consumes_marks=False,
     params={"swift_target": lambda s: s.swift.target_delay,
             "swift_beta": lambda s: s.swift.beta,
             "swift_ai": lambda s: s.swift.ai,

@@ -7,11 +7,10 @@ exactly once" and to report hit rates and compile-time split as
 first-class metrics.  ``ExecutableCache`` is that cache made explicit:
 
   * bounded LRU keyed by the caller's structural signature (static
-    scan configuration — including the kernel tier and the megakernel's
-    substep-block depth, since a mega sweep re-blocked at a different
-    ``trace_every`` is a different program — plus the input pytree
-    treedef and leaf shapes/dtypes, so a hit really means "this
-    executable can run these arrays as-is");
+    scan configuration — scan depth, reduction engine and its block
+    shapes, VC count, mesh — plus the input pytree treedef and leaf
+    shapes/dtypes, so a hit really means "this executable can run these
+    arrays as-is");
   * hit / miss / eviction counters plus cumulative build (compile)
     seconds, snapshotable as :class:`CacheStats` — deltas subtract, so
     a serving engine can report per-window stats off a shared cache;

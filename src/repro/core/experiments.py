@@ -40,17 +40,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import cc, obs
+from . import obs
 from .exec_cache import ExecutableCache, structural_signature
 from .fluid import (FluidState, Scenario, check_routing_paths,
                     clamp_dense_rows, delay_depth, dense_engine,
                     dense_reduce_rows, fluid_step, init_state,
-                    kernel_tier, scenario_device, step_body_fn,
-                    step_params)
+                    scenario_device, step_params)
 from .params import CCConfig, CCSpec
 from .routing import PAD, route_hops
-from .simulator import (SimResult, _acc_update, _resolve_steps,
-                        _window_sample, _zero_accum, decimating_scan)
+from .simulator import SimResult, _resolve_steps, decimating_scan
 from .topology import Topology
 
 if TYPE_CHECKING:           # real import is lazy: repro.net imports core
@@ -519,8 +517,7 @@ SWEEP_EXEC_CACHE = ExecutableCache(capacity=32, name="sweep")
 
 def _sweep_scan_fn(n_samples: int, trace_every: int, dt: float,
                    n_switches: int, reduce: str, dense_blocks: tuple,
-                   use_kernels: "bool | str", interpret: bool,
-                   n_vcs: int, substep_block: int, mesh):
+                   n_vcs: int, mesh):
     """Build the (unjitted) sweep scan for one static configuration.
 
     The whole sweep is one vmap-of-(decimating)-scan.  With ``mesh`` the
@@ -528,53 +525,18 @@ def _sweep_scan_fn(n_samples: int, trace_every: int, dt: float,
     device advances (and decimates the traces of) its own slice of the
     run batch, with zero cross-device communication, so a sharded sweep
     is bitwise the single-device sweep cut into ``mesh.size`` pieces.
-
-    ``substep_block`` is the megakernel's in-kernel scan depth (0 on the
-    non-mega tiers): with ``use_kernels="mega"`` the inner per-step scan
-    is replaced by one vmapped whole-window ``megastep_block`` launch
-    per trace sample, ``substep_block`` (= ``trace_every``) substeps
-    deep, the fluid state staying kernel-resident throughout.
     """
-    tier = kernel_tier(use_kernels)
-    if tier == "mega":
-        body = step_body_fn(dt=dt, n_switches=n_switches, reduce=reduce,
-                            dense_blocks=dense_blocks, n_vcs=n_vcs)
-        from repro.kernels.fluid_step import megastep_block
+    def scan_fn(st_b, sd_b, par_b):
+        def step(st):
+            return jax.vmap(
+                lambda s, sd, par: fluid_step(
+                    s, sd, par, dt=dt, n_switches=n_switches,
+                    reduce=reduce, dense_blocks=dense_blocks,
+                    n_vcs=n_vcs)
+            )(st, sd_b, par_b)
 
-        def scan_fn(st_b, sd_b, par_b):
-            def block(st):
-                return jax.vmap(
-                    lambda s, sd, par: megastep_block(
-                        s, sd, par, body=body,
-                        n_substeps=substep_block,
-                        acc_init=_zero_accum, acc_update=_acc_update,
-                        make_sample=_window_sample, n_vcs=n_vcs, dt=dt,
-                        interpret=interpret)
-                )(st, sd_b, par_b)
-
-            return decimating_scan(None, st_b, n_samples, trace_every,
-                                   dt, n_vcs, block_fn=block)
-    else:
-        def scan_fn(st_b, sd_b, par_b):
-            # flow tier: hoist the reaction kernels' SMEM param rows out
-            # of the scan — packed once per trace, reused every substep
-            # (None on the other tiers: an empty pytree vmaps freely).
-            packed_b = jax.vmap(
-                lambda par: cc.pack_react_rows(
-                    par.react, par.line_rate, jnp.float32(dt))
-            )(par_b) if tier == "flow" else None
-
-            def step(st):
-                return jax.vmap(
-                    lambda s, sd, par, pk: fluid_step(
-                        s, sd, par, dt=dt, n_switches=n_switches,
-                        reduce=reduce, dense_blocks=dense_blocks,
-                        use_kernels=use_kernels, interpret=interpret,
-                        n_vcs=n_vcs, packed_react=pk)
-                )(st, sd_b, par_b, packed_b)
-
-            return decimating_scan(step, st_b, n_samples, trace_every,
-                                   dt, n_vcs)
+        return decimating_scan(step, st_b, n_samples, trace_every,
+                               dt, n_vcs)
 
     if mesh is None:
         return scan_fn
@@ -691,8 +653,7 @@ class Sweep:
     @obs.span("repro.sweep.stage")
     def _prepare(self, n_steps: int | None = None,
                  trace_every: int | None = None, *, mesh=None,
-                 reduce: str = "fused", use_kernels: bool = False,
-                 interpret: bool = False, pad_runs_to: int | None = None,
+                 reduce: str = "fused", pad_runs_to: int | None = None,
                  min_delay_slots: int | None = None,
                  dense_rows: int | None = None,
                  temperature: float = 0.0,
@@ -705,10 +666,6 @@ class Sweep:
         ``static`` for per-window execution but must otherwise stage
         the bit-identical program.
         """
-        if temperature and use_kernels:
-            raise ValueError(
-                "temperature > 0 needs use_kernels=False: the Pallas "
-                "kernel tiers implement the hard dynamics only")
         cfg0 = self.points[0].cfg
         n_samples, k = _resolve_steps(cfg0, n_steps, trace_every)
         scns = [p.scenario for p in self.points]
@@ -716,8 +673,7 @@ class Sweep:
         dense_blocks, layout = dense_engine(
             padded, self.n_vcs,
             batch_dense_rows(padded, self.n_vcs, reduce, dense_rows),
-            pinned=dense_rows is not None,
-            mega=kernel_tier(use_kernels) == "mega")
+            pinned=dense_rows is not None)
         if layout is not None:
             red_idx, red_back, n_rows = layout
             sd_b = sd_b._replace(red_idx=red_idx, red_back=red_back)
@@ -746,19 +702,13 @@ class Sweep:
                 [x] + [x[-1:]] * pad_r, axis=0)
             st_b, sd_b, par_b = (jax.tree.map(rep, t)
                                  for t in (st_b, sd_b, par_b))
-        # the substep-block depth (the megakernel's in-kernel scan
-        # length) is part of the executable signature: a mega sweep
-        # re-blocked at a different trace_every is a different program
-        substep_block = k if kernel_tier(use_kernels) == "mega" else 0
         static = (n_samples, k, float(cfg0.sim.dt), n_sw, reduce,
-                  dense_blocks, use_kernels, interpret, self.n_vcs,
-                  substep_block, mesh)
+                  dense_blocks, self.n_vcs, mesh)
         return static, (st_b, sd_b, par_b), n_samples, k
 
     def run(self, n_steps: int | None = None,
             trace_every: int | None = None, *, mesh=None,
-            reduce: str = "fused", use_kernels: bool = False,
-            interpret: bool = False, pad_runs_to: int | None = None,
+            reduce: str = "fused", pad_runs_to: int | None = None,
             min_delay_slots: int | None = None,
             dense_rows: int | None = None,
             temperature: float = 0.0,
@@ -772,10 +722,8 @@ class Sweep:
         shard decimates its own traces.  Results are bitwise identical
         to the single-device launch, run for run.
 
-        ``reduce`` / ``use_kernels`` / ``interpret`` select the per-step
-        reduction engine and the Pallas tier (see ``fluid_step``);
-        ``use_kernels="mega"`` runs each trace window as one whole-step
-        megakernel launch per run, ``trace_every`` substeps deep.
+        ``reduce`` selects the per-step reduction engine (see
+        ``fluid_step``).
 
         The remaining knobs exist for serving (``repro.serve.whatif``),
         which must keep the executable-cache key stable across batches
@@ -797,9 +745,7 @@ class Sweep:
         (``repro.tune.soft``) — smoothed marking/PFC/notification
         gates for differentiable tuning.  The default 0 is the exact
         hard model (bitwise; temperature is traced data, so both share
-        one compiled executable).  Soft runs require
-        ``use_kernels=False`` (the Pallas per-flow kernels implement
-        the hard path only).
+        one compiled executable).
 
         ``min_switches`` floors the static switch count the scan is
         built for (normally the batch max) — the fleet planner pins it
@@ -808,7 +754,6 @@ class Sweep:
         """
         static, args, n_samples, k = self._prepare(
             n_steps, trace_every, mesh=mesh, reduce=reduce,
-            use_kernels=use_kernels, interpret=interpret,
             pad_runs_to=pad_runs_to, min_delay_slots=min_delay_slots,
             dense_rows=dense_rows, temperature=temperature,
             min_switches=min_switches)

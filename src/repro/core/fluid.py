@@ -157,7 +157,7 @@ class ScenarioDev(NamedTuple):
     # flattened [F*K*H] candidate entries stably sorted by link id.
     # Every per-link scatter-add of the step becomes one gather by
     # ``red_perm`` + sorted multi-channel segment sum over ``red_seg``;
-    # ``red_off`` are the CSR offsets the Pallas kernel tiles by.
+    # ``red_off`` are the CSR offsets (each link's span of the order).
     red_perm: jnp.ndarray     # [F*K*H] int32
     red_seg: jnp.ndarray      # [F*K*H] int32
     red_off: jnp.ndarray      # [L+2] int32
@@ -591,22 +591,18 @@ def dense_layout(scns, n_vcs: int = 1, rows: int | None = None):
     return _memo_lru(_LAYOUT_CACHE, _LAYOUT_CACHE_SIZE, key, build)
 
 
-def dense_engine(scns, n_vcs: int, rows: int, *, pinned: bool,
-                 mega: bool = False):
+def dense_engine(scns, n_vcs: int, rows: int, *, pinned: bool):
     """``(dense_blocks, layout)``: the dense reduction a batch of
     padded scenarios runs with.
 
     ``rows`` is the batch's longest contributor list, or a pinned row
-    count; 0 keeps the segment-sum engine (``((), None)``).  The
-    megakernel walks its own rectangle, ``((S, rows),)``, and takes no
-    layout; otherwise ``layout`` is ``(red_idx, red_back, n_rows)`` of
-    ``dense_layout``: the jagged layout, or with ``pinned`` the
-    rectangle of every queue x ``rows`` positions.
+    count; 0 keeps the segment-sum engine (``((), None)``).  Otherwise
+    ``layout`` is ``(red_idx, red_back, n_rows)`` of ``dense_layout``:
+    the jagged layout, or with ``pinned`` the rectangle of every queue
+    x ``rows`` positions.
     """
     if not rows:
         return (), None
-    if mega:
-        return ((scns[0].capacity.shape[0] * n_vcs, int(rows)),), None
     blocks, idx, back, n_rows = dense_layout(
         scns, n_vcs, int(rows) if pinned else None)
     return blocks, (idx, back, n_rows)
@@ -771,55 +767,9 @@ def init_state(scn: Scenario, cfg: "CCConfig | CCSpec",
     )
 
 
-def kernel_tier(use_kernels) -> str:
-    """Normalise the ``use_kernels`` tiers.
-
-    ``False`` -> ``"off"`` (pure jnp step), ``True`` -> ``"flow"`` (the
-    per-flow ``repro.kernels.cc_step`` kernels of PR 4), ``"mega"`` ->
-    the whole-step megakernel (``repro.kernels.fluid_step``).  The
-    string forms are accepted directly so configs can spell the tier.
-    """
-    if use_kernels is False or use_kernels is None:
-        return "off"
-    if use_kernels is True:
-        return "flow"
-    if use_kernels in ("off", "flow", "mega"):
-        return use_kernels
-    raise ValueError(
-        f"use_kernels must be False, True or 'mega' "
-        f"(or the tier names 'off'/'flow'), got {use_kernels!r}")
-
-
-def _refuse_soft_kernels(tier: str, temperature) -> None:
-    """Every Pallas tier implements the *hard* dynamics only.
-
-    A positive soft-relaxation temperature (``repro.tune``) under
-    ``use_kernels`` used to be silently ignored — PR 7 guarded only
-    ``Sweep.run``.  Raise wherever the temperature is statically known
-    to be positive; a traced temperature (batched sweeps) cannot be
-    inspected here and stays guarded at the ``Sweep.run`` entry point.
-    """
-    if tier == "off":
-        return
-    if isinstance(temperature, jax.core.Tracer):
-        return
-    try:
-        tv = float(temperature)
-    except (TypeError, jax.errors.ConcretizationTypeError):
-        return
-    if tv > 0.0:
-        raise ValueError(
-            "temperature > 0 needs use_kernels=False: the Pallas "
-            "kernel tiers implement the hard dynamics only, so the "
-            "soft gates (PFC hysteresis, marking thresholds, CNP "
-            "windows) would be silently ignored")
-
-
 def fluid_step(st: FluidState, sd: ScenarioDev, par: StepParams, *,
                dt: float, n_switches: int, reduce: str = "fused",
-               dense_blocks: tuple = (), use_kernels: "bool | str" = False,
-               interpret: bool = False, n_vcs: int = 1,
-               packed_react: dict | None = None):
+               dense_blocks: tuple = (), n_vcs: int = 1):
     """One ``dt`` update: (state, scenario, params) -> (state, trace).
 
     Pure in all array arguments; ``dt`` / ``n_switches`` and the
@@ -833,9 +783,6 @@ def fluid_step(st: FluidState, sd: ScenarioDev, par: StepParams, *,
         scatter path (stable sort preserves each link's contributor
         order; interleaved +0.0 terms from unselected candidates are
         exact no-ops).
-      * ``"pallas"`` — same fused layout, summed by the
-        ``repro.kernels.fluid_reduce`` Pallas TPU kernel (all channels
-        resident in VMEM, ordered accumulation, so still bit-exact).
       * ``"scat"`` — the legacy one-scatter-per-quantity path, kept as
         the parity/benchmark baseline.
 
@@ -845,28 +792,7 @@ def fluid_step(st: FluidState, sd: ScenarioDev, par: StepParams, *,
     ``sd.red_idx`` straight from the channels and accumulates positions
     left-to-right (``jagged_sums``) — the fastest path when link load
     is not pathologically skewed, still bit-identical.  ``()`` keeps
-    the segment-sum engine.  The megakernel takes one block of every
-    queue, ``((S, rows),)``, and walks its own rectangle.
-
-    ``use_kernels`` selects the Pallas tier (see ``kernel_tier``):
-      * ``False`` — pure jnp step (the parity reference).
-      * ``True`` — the per-flow block (generation, notification timer,
-        RP/ERP/swift reaction) rides the ``repro.kernels.cc_step``
-        kernels — one HBM round trip per state vector instead of one
-        per intermediate.  ``packed_react`` optionally carries the
-        prepacked per-stage param rows (``cc.pack_react_rows``) so a
-        scanned step doesn't rebuild them every substep.
-      * ``"mega"`` — the ENTIRE step (all phases, link reductions
-        included) runs as ONE ``repro.kernels.fluid_step`` launch with
-        the state VMEM-resident; stage dispatch happens *inside* the
-        kernel on the traced codes, so the whole CCSpec matrix still
-        shares one build.  Requires ``reduce != "pallas"`` (the
-        reduction kernel cannot nest inside the launch).
-
-    Every kernel tier implements the hard dynamics only; combining one
-    with ``temperature > 0`` raises (see ``_refuse_soft_kernels``).
-    ``interpret=True`` runs every Pallas kernel in interpreter mode
-    (CPU tests).
+    the segment-sum engine.
 
     ``n_vcs`` (static, ``LinkParams.n_vcs``) splits every wire's input
     buffer into that many virtual-channel queues with independent
@@ -878,64 +804,9 @@ def fluid_step(st: FluidState, sd: ScenarioDev, par: StepParams, *,
     takes statically identical code paths to the legacy single-queue
     model — bitwise, not just numerically.
     """
-    if reduce not in ("fused", "pallas", "scat"):
+    if reduce not in ("fused", "scat"):
         raise ValueError(
-            f"reduce must be 'fused', 'pallas' or 'scat', got {reduce!r}")
-    tier = kernel_tier(use_kernels)
-    _refuse_soft_kernels(tier, par.temperature)
-    if tier == "mega":
-        from repro.kernels.fluid_step import megastep
-        body = step_body_fn(dt=dt, n_switches=n_switches, reduce=reduce,
-                            dense_blocks=dense_blocks, n_vcs=n_vcs)
-        return megastep(st, sd, par, body=body, interpret=interpret)
-    return _step_body(st, sd, par, dt=dt, n_switches=n_switches,
-                      reduce=reduce, dense_blocks=dense_blocks,
-                      use_kernels=(tier == "flow"), interpret=interpret,
-                      n_vcs=n_vcs, packed_react=packed_react)
-
-
-def step_body_fn(*, dt: float, n_switches: int, reduce: str = "fused",
-                 dense_blocks: tuple = (), n_vcs: int = 1):
-    """The in-kernel step closure: ``(st, sd, par) -> (state, trace)``.
-
-    This is the single definition of the update the megakernel executes
-    — statics baked, stage dispatch through each stage's
-    ``kernel_body`` (falling back to its jnp ``step``), and the dense
-    engine in its tiled on-chip form (``dense_blocks`` one block of
-    every queue: ``((S, rows),)``).  It is the *same* jnp math as the
-    plain path (same primitives, same order), which is what holds the
-    mega tier bit-exact to the reference.
-    """
-    if reduce == "pallas":
-        raise ValueError(
-            "use_kernels='mega' runs the link reductions inside the "
-            "launch; reduce must be 'fused' or 'scat' (the "
-            "fluid_reduce Pallas kernel cannot nest in the megakernel)")
-
-    def body(st, sd, par):
-        return _step_body(st, sd, par, dt=dt, n_switches=n_switches,
-                          reduce=reduce, dense_blocks=dense_blocks,
-                          use_kernels=False, interpret=False,
-                          n_vcs=n_vcs, dense_tiled=True, in_kernel=True)
-
-    return body
-
-
-def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
-               dt: float, n_switches: int, reduce: str,
-               dense_blocks: tuple, use_kernels: bool, interpret: bool,
-               n_vcs: int, dense_tiled: bool = False,
-               in_kernel: bool = False,
-               packed_react: dict | None = None):
-    """The step update itself (see ``fluid_step`` for semantics).
-
-    ``dense_tiled`` swaps the jagged dense accumulation for the
-    megakernel's ``[S, block]``-tiled rectangle (bit-identical, see
-    ``repro.kernels.fluid_step.dense_reduce_tiled``); ``in_kernel``
-    marks that this trace runs inside the megakernel launch, which
-    routes every cc dispatch through the stages' ``kernel_body``
-    entries and must not nest further ``pallas_call``s.
-    """
+            f"reduce must be 'fused' or 'scat', got {reduce!r}")
     fused = reduce != "scat"
     F, K, H = sd.alt_routes.shape
     L = sd.cap_ext.shape[0] - 1
@@ -973,16 +844,9 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
     # verbatim, so tau == 0 is bitwise the hard model (repro.tune).
     tau = par.temperature
 
-    if in_kernel:
-        # inside the megakernel trace, numpy-backed constants would be
-        # captured by the kernel jaxpr (pallas_call refuses); iota
-        # generates the same int32 indices on-chip — value-identical.
-        arange_h = jax.lax.iota(jnp.int32, H)[None, :]
-        fidx = jax.lax.iota(jnp.int32, F)
-    else:
-        _ah, _fi = _index_consts(F, H)
-        arange_h = jnp.asarray(_ah)
-        fidx = jnp.asarray(_fi)
+    _ah, _fi = _index_consts(F, H)
+    arange_h = jnp.asarray(_ah)
+    fidx = jnp.asarray(_fi)
     t_sec = st.t.astype(jnp.float32) * dt
 
     def pick_paths(k_idx):
@@ -992,18 +856,6 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
         h = jnp.take_along_axis(sd.alt_hops, k_idx[:, None], axis=1)[:, 0]
         return r, h
 
-    with obs.scope("fluid.reduce"):
-        if fused and dense_blocks and dense_tiled:
-            # the megakernel's rectangle, shared by every reduction pass
-            # this step: position p of queue q reads sorted row
-            # off[q] + p (the sentinel F*K*H reads an all-zero row).
-            ((_, dense_rows),) = dense_blocks
-            _lens = sd.red_off[1:S + 1] - sd.red_off[:S]        # [S]
-            _pos = jnp.arange(dense_rows, dtype=jnp.int32)[None, :]
-            dense_idx = jnp.where(_pos < _lens[:, None],
-                                  sd.red_off[:S, None] + _pos,
-                                  F * K * H).reshape(-1)
-
     def link_sums(channels, k_sel):
         """All per-queue sums of the [F, H] ``channels`` in ONE sweep.
 
@@ -1012,10 +864,9 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
         order; one [F*K*H, C] pass produces every [S+1] per-(wire, VC)
         vector at once instead of C scatters (S == L when V == 1, in
         which case "queue" is just "wire").  The pass is summed by
-        the jagged dense layout, the megakernel's tiles, the Pallas
-        kernel, or a sorted segment sum — all accumulate each queue's
-        contributors in the same order, so the result is bit-identical
-        across engines.
+        the jagged dense layout or a sorted segment sum — both
+        accumulate each queue's contributors in the same order, so the
+        result is bit-identical across engines.
         """
         with obs.scope("fluid.reduce"):
             data = jnp.stack(channels, axis=-1)                 # [F, H, C]
@@ -1026,24 +877,13 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
                 data = data[:, None] * \
                     onehot[:, :, None, None].astype(jnp.float32)
             data = data.reshape(F * K * H, C)
-            if dense_blocks and reduce == "fused" and not dense_tiled:
+            if dense_blocks:
                 return jagged_sums(data, sd.red_idx, sd.red_back,
                                    dense_blocks)
             data = jnp.take(data, sd.red_perm, axis=0)
-            if reduce == "pallas":
-                from repro.kernels.fluid_reduce import segment_reduce
-                sums = segment_reduce(data, sd.red_seg, S + 1,
-                                      interpret=interpret)
-            elif dense_blocks:                 # the megakernel's rectangle
-                from repro.kernels.fluid_step import dense_reduce_tiled
-                data_ext = jnp.concatenate(
-                    [data, jnp.zeros((1, C), jnp.float32)])
-                sums = dense_reduce_tiled(data_ext, dense_idx, S,
-                                          dense_rows)
-            else:
-                sums = jax.ops.segment_sum(data, sd.red_seg,
-                                           num_segments=S + 1,
-                                           indices_are_sorted=True)
+            sums = jax.ops.segment_sum(data, sd.red_seg,
+                                       num_segments=S + 1,
+                                       indices_are_sorted=True)
             return [sums[:, c] for c in range(C)]
 
     # ---- 0. path selection (min / valiant / ugal) -------------------------
@@ -1126,22 +966,15 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
 
     # ---- 1. generation ----------------------------------------------------
     with obs.scope("fluid.generate"):
-        if use_kernels:
-            from repro.kernels.cc_step import gen_np_step
-            nicq, offered, dropped, np_tmr_t = gen_np_step(
-                st.nicq, st.offered, st.dropped, st.np_tmr,
-                sd.gen_rate, sd.t_start, sd.t_stop, sd.volume, sd.nic_buffer,
-                t_sec=t_sec, dt=dt, interpret=interpret)
-        else:
-            active = (t_sec >= sd.t_start) & (t_sec < sd.t_stop)
-            gen = jnp.where(active, sd.gen_rate, 0.0) * dt
-            gen = jnp.minimum(gen, jnp.maximum(sd.volume - st.offered, 0.0))
-            nicq = st.nicq + gen
-            over = jnp.maximum(nicq - sd.nic_buffer, 0.0)
-            nicq = nicq - over
-            offered = st.offered + gen - over
-            dropped = st.dropped + over
-            np_tmr_t = st.np_tmr + dt              # notification-window tick
+        active = (t_sec >= sd.t_start) & (t_sec < sd.t_stop)
+        gen = jnp.where(active, sd.gen_rate, 0.0) * dt
+        gen = jnp.minimum(gen, jnp.maximum(sd.volume - st.offered, 0.0))
+        nicq = st.nicq + gen
+        over = jnp.maximum(nicq - sd.nic_buffer, 0.0)
+        nicq = nicq - over
+        offered = st.offered + gen - over
+        dropped = st.dropped + over
+        np_tmr_t = st.np_tmr + dt                  # notification-window tick
 
     # ---- 2. transfers -----------------------------------------------------
     with obs.scope("fluid.transfer"):
@@ -1301,7 +1134,7 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
                        dem_next=dem_next, grant_next=grant_next,
                        over_next=over_next, port_buffer=par.port_buffer,
                        line_rate=par.line_rate, tau=tau),
-            st.cc, in_kernel=in_kernel)
+            st.cc)
         # mark_fh is a [F, H] float mark intensity: exact 0/1 in hard mode,
         # sigmoid-graded under the soft model.
         mark_pos = mark_fh > 0.0
@@ -1340,7 +1173,7 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
             cc.NOTIFICATION, par.notif_code, par.notif,
             cc.NotifCtx(marked=mark_lvl, mark_fh=mark_fh, np_tmr_t=np_tmr_t,
                         hops=hops, rtt=sd.rtt, t=st.t, D=D, tau=tau),
-            st.cc, in_kernel=in_kernel)
+            st.cc)
         rslot = st.t % D
         if fused:
             # branch-free ring ops: one-hot compare instead of scatters.
@@ -1378,9 +1211,8 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
     with obs.scope("fluid.react"):
         # Every registered reaction (fixed-rate PFC source / DCQCN RP / the
         # paper's ERP / delay-target swift / ...) advances from the same
-        # context; the traced ``react_code`` selects one, and stages with a
-        # Pallas form route through it behind ``use_kernels``.  The queuing-
-        # delay estimate (bytes queued along the path / line rate) feeds the
+        # context; the traced ``react_code`` selects one.  The queuing-delay
+        # estimate (bytes queued along the path / line rate) feeds the
         # mark-free delay-based stages.
         qdelay = fdiv(jnp.sum(jnp.where(holds_queue, qh, 0.0), axis=1),
                       par.line_rate)
@@ -1393,8 +1225,7 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
                         tgt_rx=tgt_rx, qdelay=qdelay, jitter=sd.jitter,
                         gen_rate=sd.gen_rate, line_rate=par.line_rate, dt=dt,
                         tau=tau),
-            st.cc, use_kernels=use_kernels, interpret=interpret,
-            in_kernel=in_kernel, packed=packed_react)
+            st.cc)
 
         new = FluidState(
             qh=qh, nicq=nicq, delivered=delivered, offered=offered,
@@ -1424,32 +1255,21 @@ def _step_body(st: FluidState, sd: ScenarioDev, par: StepParams, *,
 def make_step_fn(scn: Scenario, cfg: "CCConfig | CCSpec",
                  delay_slots: int | None = None, *,
                  reduce: str = "fused", dense_rows: int | None = None,
-                 use_kernels: "bool | str" = False,
-                 interpret: bool = False, temperature: float = 0.0):
+                 temperature: float = 0.0):
     """Returns step(state) -> (state, StepTrace). Pure; closes over statics.
 
     ``delay_slots`` pins a fixed delay-line depth (legacy callers passing
     ``DELAY_SLOTS``); it raises if any flow's RTT would overflow it.  By
     default the depth is sized from the scenario (``delay_depth``).
-    ``reduce`` / ``use_kernels`` / ``interpret`` select the reduction
-    engine and the Pallas tier (see ``fluid_step``);
+    ``reduce`` selects the reduction engine (see ``fluid_step``);
     ``dense_rows=None`` lays the dense reduction out from the
     scenario (``dense_layout``), an explicit count pins its rectangle
     (``dense_engine``), 0 forces the segment-sum engine.
-    ``temperature`` selects the soft-relaxed dynamics (``repro.tune``)
-    — only valid on the pure-jnp tier, since the kernels implement the
-    hard model only (a positive value under any kernel tier raises).
+    ``temperature`` selects the soft-relaxed dynamics (``repro.tune``).
     """
     if delay_slots is not None:
         _check_delay(scn, delay_slots)
     check_routing_paths(cfg, scn)
-    tier = kernel_tier(use_kernels)
-    _refuse_soft_kernels(tier, temperature)
-    if tier == "mega" and reduce == "pallas":
-        raise ValueError(
-            "use_kernels='mega' runs the link reductions inside the "
-            "launch; reduce must be 'fused' or 'scat' (the "
-            "fluid_reduce Pallas kernel cannot nest in the megakernel)")
     n_vcs = int(getattr(cfg.link, "n_vcs", 1))
     sd = scenario_device(scn, n_vcs=n_vcs)
     par = step_params(cfg, temperature=temperature)
@@ -1458,20 +1278,13 @@ def make_step_fn(scn: Scenario, cfg: "CCConfig | CCSpec",
     rows = 0 if reduce != "fused" else dense_reduce_rows(scn, n_vcs) \
         if dense_rows is None else int(dense_rows)
     dense_blocks, layout = dense_engine(
-        [scn], n_vcs, rows, pinned=dense_rows is not None,
-        mega=tier == "mega")
+        [scn], n_vcs, rows, pinned=dense_rows is not None)
     if layout is not None:
         sd = sd._replace(red_idx=layout[0][0], red_back=layout[1][0])
-    # flow tier: prepack the reaction kernels' SMEM param rows once per
-    # step *function*, so a scanned step stops rebuilding them every
-    # substep (they are pure functions of the run's constants).
-    packed = cc.pack_react_rows(par.react, par.line_rate,
-                                jnp.float32(dt)) if tier == "flow" else None
 
     def step(st: FluidState):
         return fluid_step(st, sd, par, dt=dt, n_switches=n_sw,
                           reduce=reduce, dense_blocks=dense_blocks,
-                          use_kernels=use_kernels, interpret=interpret,
-                          n_vcs=n_vcs, packed_react=packed)
+                          n_vcs=n_vcs)
 
     return step

@@ -54,12 +54,7 @@ def _zero_accum(st: FluidState, n_vcs: int = 1):
 
 
 def _acc_update(acc, tr: StepTrace):
-    """Fold one step's trace into the window accumulators.
-
-    Shared by the host-side decimating scan AND the megakernel's
-    in-kernel dt-scan (``repro.kernels.fluid_step.megastep_block``) —
-    the single definition is what keeps the two trace paths bitwise
-    identical."""
+    """Fold one step's trace into the window accumulators."""
     mq, npz, mk, cn, nm, ct, pt, vs = acc
     with obs.scope("fluid.decimate"):
         return (jnp.maximum(mq, tr.max_q),
@@ -85,20 +80,10 @@ def _window_sample(st: FluidState, d0, acc, trace_every: int,
 
 
 def decimating_scan(step, st: FluidState, n_samples: int,
-                    trace_every: int, dt: float, n_vcs: int = 1, *,
-                    block_fn=None):
+                    trace_every: int, dt: float, n_vcs: int = 1):
     """Run ``n_samples * trace_every`` steps, emitting one TraceSample
     per ``trace_every`` steps.  Accumulation happens inside the scan, so
-    the full-resolution trace never materialises.
-
-    ``block_fn`` replaces the inner per-step scan with one call per
-    trace window (``block_fn(state) -> (state, TraceSample)``) — the
-    megakernel's whole-window launch; the outer scan then just chains
-    windows.  ``step``/``trace_every``/``dt``/``n_vcs`` are unused in
-    that form (the block closes over them)."""
-    if block_fn is not None:
-        return jax.lax.scan(lambda s, _: block_fn(s), st, None,
-                            length=n_samples)
+    the full-resolution trace never materialises."""
 
     def outer(st, _):
         d0 = st.delivered
@@ -121,53 +106,6 @@ def _run_scan(state: FluidState, step_fn, n_samples: int,
               trace_every: int, dt: float, n_vcs: int = 1):
     return decimating_scan(step_fn, state, n_samples, trace_every, dt,
                            n_vcs)
-
-
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _run_block_scan(state: FluidState, block_fn, n_samples: int):
-    return decimating_scan(None, state, n_samples, 0, 0.0,
-                           block_fn=block_fn)
-
-
-def make_block_fn(scn: Scenario, cfg: CCConfig, trace_every: int, *,
-                  reduce: str = "fused", dense_rows: int | None = None,
-                  interpret: bool = False):
-    """Megakernel analogue of ``make_step_fn``: one whole trace window
-    per launch.
-
-    Returns ``block(state) -> (state, TraceSample)`` running
-    ``trace_every`` substeps inside a single ``pallas_call`` with the
-    fluid state VMEM-resident throughout (see
-    ``repro.kernels.fluid_step.megastep_block``); only the decimated
-    sample row leaves the kernel.  The accumulation functions are the
-    exact ones ``decimating_scan`` uses, so traces are bit-identical to
-    the per-step path.
-    """
-    from .fluid import (check_routing_paths, dense_engine,
-                        dense_reduce_rows, scenario_device, step_body_fn,
-                        step_params)
-    from repro.kernels.fluid_step import megastep_block
-    check_routing_paths(cfg, scn)
-    n_vcs = int(getattr(cfg.link, "n_vcs", 1))
-    sd = scenario_device(scn, n_vcs=n_vcs)
-    par = step_params(cfg)
-    dt = float(cfg.sim.dt)
-    rows = 0 if reduce != "fused" else dense_reduce_rows(scn, n_vcs) \
-        if dense_rows is None else int(dense_rows)
-    dense_blocks, _ = dense_engine([scn], n_vcs, rows, pinned=True,
-                                   mega=True)
-    body = step_body_fn(dt=dt, n_switches=int(scn.n_switches),
-                        reduce=reduce, dense_blocks=dense_blocks,
-                        n_vcs=n_vcs)
-
-    def block(st: FluidState):
-        return megastep_block(
-            st, sd, par, body=body, n_substeps=trace_every,
-            acc_init=_zero_accum, acc_update=_acc_update,
-            make_sample=_window_sample, n_vcs=n_vcs, dt=dt,
-            interpret=interpret)
-
-    return block
 
 
 def _resolve_steps(cfg: CCConfig, n_steps: int | None,
@@ -391,32 +329,21 @@ class SimResult:
 
 
 def run(scn: Scenario, cfg: CCConfig, n_steps: int | None = None,
-        trace_every: int | None = None, *, reduce: str = "fused",
-        use_kernels: "bool | str" = False,
-        interpret: bool = False) -> SimResult:
+        trace_every: int | None = None, *,
+        reduce: str = "fused") -> SimResult:
     """Simulate one point and pull (decimated) traces to host.
 
     ``trace_every`` defaults to ``cfg.sim.trace_every``; pass 1 for a
     full-resolution trace.  ``n_steps`` is rounded up to a whole number
-    of trace windows.  ``reduce`` / ``use_kernels`` / ``interpret``
-    select the reduction engine and Pallas tier (see
-    ``repro.core.fluid.fluid_step``); ``use_kernels="mega"`` runs each
-    trace window as one whole-step megakernel launch with the fluid
-    state VMEM-resident across all ``trace_every`` substeps.
+    of trace windows.  ``reduce`` selects the reduction engine (see
+    ``repro.core.fluid.fluid_step``).
     """
     n_samples, k = _resolve_steps(cfg, n_steps, trace_every)
     st0 = init_state(scn, cfg)
     n_vcs = int(getattr(cfg.link, "n_vcs", 1))
-    from .fluid import kernel_tier
-    if kernel_tier(use_kernels) == "mega":
-        block = make_block_fn(scn, cfg, k, reduce=reduce,
-                              interpret=interpret)
-        final, tr = _run_block_scan(st0, block, n_samples)
-    else:
-        step = make_step_fn(scn, cfg, reduce=reduce,
-                            use_kernels=use_kernels, interpret=interpret)
-        final, tr = _run_scan(st0, step, n_samples, k,
-                              float(cfg.sim.dt), n_vcs)
+    step = make_step_fn(scn, cfg, reduce=reduce)
+    final, tr = _run_scan(st0, step, n_samples, k, float(cfg.sim.dt),
+                          n_vcs)
     # (i+1)*k first (exact int), then *dt — so decimated times are the
     # same floats as the strided full-resolution times
     times = (np.arange(n_samples) + 1) * k * cfg.sim.dt

@@ -59,10 +59,10 @@ def fluid_step_bytes(n_flows: int, n_paths: int, n_hops: int,
                      n_links: int, n_vcs: int = 1) -> float:
     """Analytic HBM bytes one fluid substep moves (f32 vectors).
 
-    The fluid-reduce segment reduction runs 3 passes with (3, 3, 2)
-    channels over N = F*K*H incidence rows into L*n_vcs (+1 PAD) link
-    sums, and the fused per-flow CC block budgets one HBM round trip
-    for its ~40 [F] state vectors.  This is the bandwidth term of the
+    The link reduction runs 3 passes with (3, 3, 2) channels over
+    N = F*K*H incidence rows into L*n_vcs (+1 PAD) link sums, and the
+    per-flow CC block budgets one HBM round trip for its ~40 [F] state
+    vectors.  This is the bandwidth term of the
     hot loop's roofline — the single cost model shared by the fleet
     planner and ``benchmarks/roofline.py``.
     """
@@ -163,8 +163,6 @@ class FleetPlan:
     n_samples: int
     k: int                          # resolved trace_every (steps/window)
     reduce: str
-    use_kernels: "bool | str"
-    interpret: bool
     temperature: float
     buckets: list[ShardBucket]
     shards: list[ShardSpec]
@@ -189,9 +187,7 @@ class FleetPlan:
         the full batch's program (one signature per bucket)."""
         b = self.buckets[shard.bucket]
         return dict(n_steps=self.n_steps, trace_every=self.trace_every,
-                    reduce=self.reduce, use_kernels=self.use_kernels,
-                    interpret=self.interpret,
-                    temperature=self.temperature,
+                    reduce=self.reduce, temperature=self.temperature,
                     pad_runs_to=b.width,
                     min_delay_slots=b.delay_slots,
                     min_switches=b.n_switches,
@@ -242,8 +238,7 @@ def plan_sweep(sweep: Sweep, n_steps: int | None = None,
                n_shards: int | None = None,
                max_points: int | None = None,
                bucket_by: str = "envelope",
-               reduce: str = "fused", use_kernels: "bool | str" = False,
-               interpret: bool = False, temperature: float = 0.0,
+               reduce: str = "fused", temperature: float = 0.0,
                min_delay_slots: int | None = None,
                dense_rows: int | None = None) -> FleetPlan:
     """Cut a sweep into a deterministic, content-addressed FleetPlan.
@@ -313,10 +308,13 @@ def plan_sweep(sweep: Sweep, n_steps: int | None = None,
     buckets: list[ShardBucket] = []
     shards: list[ShardSpec] = []
     plan_h = hashlib.sha256()
+    # "use_kernels" and "interpret" name options the step no longer has;
+    # they stay in the digest at their old defaults so that journals
+    # written before their removal still resume.
     plan_static = {
         "n_samples": n_samples, "k": k, "dt": float(cfg0.sim.dt),
         "n_vcs": sweep.n_vcs, "reduce": reduce,
-        "use_kernels": str(use_kernels), "interpret": bool(interpret),
+        "use_kernels": "False", "interpret": False,
         "temperature": float(temperature), "bucket_by": bucket_by,
     }
     plan_h.update(json.dumps(plan_static, sort_keys=True).encode())
@@ -352,7 +350,6 @@ def plan_sweep(sweep: Sweep, n_steps: int | None = None,
         plan_h.update(s.digest.encode())
     return FleetPlan(sweep=sweep, n_steps=n_steps,
                      trace_every=trace_every, n_samples=n_samples, k=k,
-                     reduce=reduce, use_kernels=use_kernels,
-                     interpret=interpret, temperature=temperature,
+                     reduce=reduce, temperature=temperature,
                      buckets=buckets, shards=shards,
                      digest=plan_h.hexdigest())

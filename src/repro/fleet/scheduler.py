@@ -558,7 +558,7 @@ def run_fleet(sweep: Sweep, n_steps: int | None = None,
     """Front door: plan (or take a plan) + schedule + merge.
 
     ``plan_kw`` forwards to :func:`~repro.fleet.plan.plan_sweep`
-    (``reduce``, ``use_kernels``, ``min_delay_slots``, …).
+    (``reduce``, ``min_delay_slots``, ``dense_rows``, …).
     """
     config = config or FleetConfig()
     if plan is None:

@@ -64,8 +64,7 @@ def stream_sweep(sweep: Sweep, n_steps: int | None = None,
                  trace_every: int | None = None, *,
                  spill_dir: str | None = None,
                  buffer_windows: int = 2,
-                 reduce: str = "fused", use_kernels: "bool | str" = False,
-                 interpret: bool = False,
+                 reduce: str = "fused",
                  pad_runs_to: int | None = None,
                  min_delay_slots: int | None = None,
                  dense_rows: int | None = None,
@@ -85,13 +84,12 @@ def stream_sweep(sweep: Sweep, n_steps: int | None = None,
         raise ValueError(f"buffer_windows must be >= 1: {buffer_windows}")
     static, args, n_samples, k = sweep._prepare(
         n_steps, trace_every, mesh=None, reduce=reduce,
-        use_kernels=use_kernels, interpret=interpret,
         pad_runs_to=pad_runs_to, min_delay_slots=min_delay_slots,
         dense_rows=dense_rows, temperature=temperature,
         min_switches=min_switches)
     st, sd_b, par_b = args
     # the window program: the same scan, outer depth 1.  Everything
-    # numeric (inner substep scan, reduction engine, kernel tier) is
+    # numeric (inner substep scan, reduction engine) is
     # bit-identical to the full-depth program; only the trace stacking
     # depth changes, so T windows chain to the full run exactly.
     exec_fn = _sweep_executable((1,) + static[1:], args)

@@ -2,18 +2,14 @@
 
   flash_attention  — blockwise causal/SWA/softcap attention (train/prefill)
   decode_attention — single-token GQA decode over long KV caches
-  cc_step          — DCQCN RP / paper-ERP rate updates at DC flow counts
-  fluid_step       — the whole-step megakernel: one launch per dt (or
-                     per decimated trace window), state VMEM-resident
   ops              — jit'd dispatchers (pallas | interpret | ref)
-  ref              — pure-jnp ground truth for all of the above
+  ref              — pure-jnp ground truth for the kernels above, and
+                     the reaction-point updates the CC stages are
+                     checked against
 """
 
 from . import ops, ref
 from .flash_attention import flash_attention
 from .decode_attention import decode_attention
-from .cc_step import erp_step, rp_step
-from .fluid_step import megastep, megastep_block
 
-__all__ = ["ops", "ref", "flash_attention", "decode_attention",
-           "erp_step", "rp_step", "megastep", "megastep_block"]
+__all__ = ["ops", "ref", "flash_attention", "decode_attention"]

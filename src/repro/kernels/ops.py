@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from .cc_step import erp_step, rp_step
 from .decode_attention import decode_attention
 from .flash_attention import flash_attention
 
@@ -51,19 +50,3 @@ def decode_attn(q, k, v, valid, *, softcap: float = 0.0,
     return decode_attention(q, k, v, valid, softcap=softcap, scale=scale,
                             interpret=(backend == "interpret"))
 
-
-def cc_rp_update(st, cnp, p, backend: str = "auto"):
-    if backend == "auto":
-        backend = "pallas" if _on_tpu() else "ref"
-    if backend == "ref":
-        return ref.rp_update_ref(st, cnp, p)
-    return rp_step(st, cnp, p, interpret=(backend == "interpret"))
-
-
-def cc_erp_update(rate, hold, cnp, tgt_rx, slope, p, backend: str = "auto"):
-    if backend == "auto":
-        backend = "pallas" if _on_tpu() else "ref"
-    if backend == "ref":
-        return ref.erp_update_ref(rate, hold, cnp, tgt_rx, slope, p)
-    return erp_step(rate, hold, cnp, tgt_rx, slope, p,
-                    interpret=(backend == "interpret"))

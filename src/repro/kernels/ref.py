@@ -1,7 +1,9 @@
-"""Pure-jnp oracles for every Pallas kernel (the correctness ground truth).
+"""Pure-jnp oracles (the correctness ground truth).
 
-Each function is the straight-line mathematical definition with no tiling;
-tests assert_allclose(kernel(interpret=True), ref) over shape/dtype sweeps.
+Each function is the straight-line mathematical definition with no tiling:
+tests compare the attention kernels (``interpret=True``) against the
+attention oracles over shape/dtype sweeps, and the fluid step's reaction
+stages (``repro.core.cc``) against the reaction-point oracles.
 """
 
 from __future__ import annotations
@@ -63,7 +65,7 @@ def decode_attention_ref(q, k, v, valid, *, softcap: float = 0.0,
 
 
 # ---------------------------------------------------------------------------
-# cc_step: the paper's reaction-point update at DC scale
+# reaction points: DCQCN RP, the paper's ERP, delay-target swift
 # ---------------------------------------------------------------------------
 
 class RPState(NamedTuple):
@@ -73,7 +75,7 @@ class RPState(NamedTuple):
     byte_cnt: jax.Array    # [F]
     tmr: jax.Array         # [F]
     alpha_tmr: jax.Array   # [F]
-    bc_stage: jax.Array    # [F] f32 (integral-valued; f32 for VPU tiling)
+    bc_stage: jax.Array    # [F] f32 (integral-valued)
     t_stage: jax.Array     # [F]
 
 
@@ -93,8 +95,8 @@ class RPParams(NamedTuple):
 def rp_update_ref(st: RPState, cnp: jax.Array, p: RPParams) -> RPState:
     """One dt of the DCQCN RP state machine, vectorised over flows.
 
-    Mirrors the DCQCN branch of repro.core.fluid (same semantics, f32
-    stages instead of int32 so the whole state is one dtype for tiling).
+    Mirrors the ``rp`` reaction stage of repro.core.cc (same semantics,
+    f32 stages instead of int32 so the whole state is one dtype).
     """
     g = p.g
     alpha_tmr = st.alpha_tmr + p.dt
@@ -150,16 +152,6 @@ def erp_update_ref(rate, hold, cnp, tgt_rx, slope, p: ERPParams):
     return rate, hold
 
 
-class SwiftKParams(NamedTuple):
-    target: float              # s, queuing-delay target
-    beta: float                # max multiplicative decrease
-    ai: float                  # B/s^2 additive recovery slope
-    guard: float               # s between decreases
-    min_rate: float
-    line_rate: float
-    dt: float
-
-
 def swift_update_ref(rate, cool, qdelay, *, target, beta, ai, guard,
                      min_rate, line_rate, dt):
     """One dt of the delay-target reaction (Swift-like), [F] f32.
@@ -167,8 +159,8 @@ def swift_update_ref(rate, cool, qdelay, *, target, beta, ai, guard,
     Multiplicative decrease proportional to the excess of the path
     queuing-delay estimate over ``target`` — bounded by ``beta`` and
     paced by the ``guard`` cool-down — additive recovery below target.
-    This is the single definition the jnp stage AND the Pallas kernel
-    reproduce (exact f32 parity is a tier-1 test).
+    The ``swift`` reaction stage of repro.core.cc calls this for its
+    hard path (exact f32 parity is a tier-1 test).
     """
     cool = jnp.maximum(cool - dt, 0.0)
     over = qdelay > target

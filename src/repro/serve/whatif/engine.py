@@ -130,8 +130,6 @@ class StructuralSignature:
     width: int                    # padded run-axis width
     reduce: str
     dense_rows: int
-    use_kernels: bool
-    interpret: bool
 
 
 def flow_bucket(n_flows: int, minimum: int = 4) -> int:
@@ -158,8 +156,6 @@ class EngineConfig:
     admission: AdmissionConfig = dataclasses.field(
         default_factory=AdmissionConfig)
     reduce: str = "fused"
-    use_kernels: bool = False
-    interpret: bool = False
     dense_rows: int = 0
     min_flow_bucket: int = 4
     max_results: int = 1024       # completed results retained for poll
@@ -286,9 +282,7 @@ class CCQueryEngine:
             link_key=(float(link.line_rate),
                       float(link.propagation_delay), float(link.mtu)),
             width=self.config.width, reduce=self.config.reduce,
-            dense_rows=self.config.dense_rows,
-            use_kernels=self.config.use_kernels,
-            interpret=self.config.interpret)
+            dense_rows=self.config.dense_rows)
         # delay-line floor from the signature's worst case (a flow
         # using every hop slot), so batch mix can't move the compiled
         # ring depth: matches ScenarioSpec.build's rtt quantisation
@@ -442,8 +436,6 @@ class CCQueryEngine:
                        for p in group])
         kw = dict(n_steps=q0.n_steps, trace_every=q0.trace_every,
                   reduce=self.config.reduce,
-                  use_kernels=self.config.use_kernels,
-                  interpret=self.config.interpret,
                   min_delay_slots=max(p.min_delay_slots for p in group),
                   dense_rows=self.config.dense_rows)
         via_fleet = self._oversized(group)

@@ -9,7 +9,7 @@ import pytest
 
 from repro.core import (CCScheme, CCSpec, DCQCNParams, LinkParams,
                         PAPER_CONFIG, ScenarioSpec, SimParams, Sweep, cc,
-                        run)
+                        dense_reduce_rows, run)
 
 SCENE = ScenarioSpec.paper_incast(roll=0, t_start=0.1e-3, t_stop=1.2e-3)
 
@@ -217,30 +217,17 @@ def test_swift_is_notification_independent(swift_res):
                                       np.asarray(getattr(b, f)), f)
 
 
-def test_swift_kernel_matches_jnp():
-    """use_kernels routes swift through its Pallas kernel (interpret
-    mode on CPU) — exact f32 equality against the jnp stage."""
-    cfg = CCSpec(reaction="swift")
-    scn = SCENE.build(cfg)
-    a = run(scn, cfg, n_steps=600)
-    b = run(scn, cfg, n_steps=600, use_kernels=True, interpret=True)
-    for f in ("delivered", "rate", "max_q"):
-        np.testing.assert_array_equal(np.asarray(getattr(a, f)),
-                                      np.asarray(getattr(b, f)), f)
-    np.testing.assert_array_equal(
-        np.asarray(a.final.cc["swift_cool"]),
-        np.asarray(b.final.cc["swift_cool"]))
-
-
 # ---------------------------------------------------------------------------
 # acceptance: a mixed stage matrix rides ONE jit
 # ---------------------------------------------------------------------------
 
-def test_mixed_stage_matrix_one_jit_no_recompile():
+@pytest.mark.parametrize("engine", ["jagged", "segment_sum", "pinned"])
+def test_mixed_stage_matrix_one_jit_no_recompile(engine):
     """>= 3 marking x 2 notification x 3 reaction variants — including
     slope, fncc and swift — in a single Sweep launch with exactly one
-    executable build, and the stage axes must be live (outputs differ
-    across combinations)."""
+    executable build on each link-reduction engine (the jagged layout,
+    the segment sum, the pinned rectangle), and the stage axes must be
+    live (outputs differ across combinations)."""
     from repro.core.experiments import SWEEP_EXEC_CACHE
     ramp = DCQCNParams(kmin=15 * 1024.0, kmax=90 * 1024.0, pmax=0.3)
     combos = [(m, n, r)
@@ -251,8 +238,12 @@ def test_mixed_stage_matrix_one_jit_no_recompile():
                                       reaction=r, dcqcn=ramp)
                for m, n, r in combos}
     sweep = Sweep.grid(configs=configs, scenarios={"hol": SCENE})
+    rows = {"jagged": None, "segment_sum": 0,
+            "pinned": max(dense_reduce_rows(p.scenario)
+                          for p in sweep.points)}[engine]
+    assert rows != 0 or engine == "segment_sum"
     before = SWEEP_EXEC_CACHE.stats()
-    res = sweep.run(n_steps=1200)
+    res = sweep.run(n_steps=1200, dense_rows=rows)
     assert (SWEEP_EXEC_CACHE.stats() - before).misses <= 1, \
         "mixed stage matrix must share one compiled executable"
     assert len(res) == 18
@@ -264,37 +255,6 @@ def test_mixed_stage_matrix_one_jit_no_recompile():
     assert delivered["ecp+enp+erp/hol"] != delivered["ecp+enp+swift/hol"]
     marks = {name: int(r.marked.sum()) for name, r in res.items()}
     assert marks["slope+enp+erp/hol"] != marks["cp+enp+erp/hol"]
-
-
-def test_mixed_stage_matrix_one_jit_on_kernel_tiers():
-    """The kernel tiers keep the one-jit property across the same
-    3 x 2 x 3 mixed matrix: the flow tier's prepacked SMEM param rows
-    are built from *traced* params (hoisted out of the scan, once per
-    trace), and the megakernel dispatches stages by traced codes inside
-    one pallas_call — so each tier resolves to exactly one executable
-    build, and the megakernel's 18 combos match the jnp engine bit for
-    bit."""
-    import jax
-    from repro.core.experiments import SWEEP_EXEC_CACHE
-    ramp = DCQCNParams(kmin=15 * 1024.0, kmax=90 * 1024.0, pmax=0.3)
-    configs = {f"{m}+{n}+{r}": CCSpec(marking=m, notification=n,
-                                      reaction=r, dcqcn=ramp)
-               for m in ("cp", "ecp", "slope")
-               for n in ("enp", "fncc")
-               for r in ("rp", "erp", "swift")}
-    sweep = Sweep.grid(configs=configs, scenarios={"hol": SCENE})
-    base = sweep.run(n_steps=600)
-    for tier in (True, "mega"):
-        before = SWEEP_EXEC_CACHE.stats()
-        res = sweep.run(n_steps=600, use_kernels=tier, interpret=True)
-        assert (SWEEP_EXEC_CACHE.stats() - before).misses <= 1, \
-            f"use_kernels={tier!r} must build one executable for the " \
-            f"whole mixed matrix"
-        if tier == "mega":
-            for a, b in zip(jax.tree.leaves((base.traces, base.final)),
-                            jax.tree.leaves((res.traces, res.final))):
-                np.testing.assert_array_equal(np.asarray(a),
-                                              np.asarray(b))
 
 
 def test_shim_and_spec_share_the_one_jit():
@@ -327,3 +287,152 @@ def test_ccspec_is_frozen_and_replaceable():
     assert s2.marking == "slope" and s.marking == "ecp"
     with pytest.raises(dataclasses.FrozenInstanceError):
         s.marking = "cp"
+
+
+# ---------------------------------------------------------------------------
+# the reaction stages against their oracles (repro.kernels.ref)
+# ---------------------------------------------------------------------------
+
+def _react_ctx(F, seed, cnp_p=0.5):
+    """Random reaction-point state and feedback for F flows, the hard
+    model's (temperature 0)."""
+    import jax.numpy as jnp
+    r = np.random.RandomState(seed)
+    f32 = lambda x: jnp.asarray(x, jnp.float32)       # noqa: E731
+    return cc.ReactCtx(
+        rate=f32(r.rand(F) * 12.5e9), rp_target=f32(r.rand(F) * 12.5e9),
+        alpha=f32(r.rand(F)), byte_cnt=f32(r.rand(F) * 10e6),
+        tmr=f32(r.rand(F) * 55e-6), alpha_tmr=f32(r.rand(F) * 55e-6),
+        bc_stage=jnp.asarray(r.randint(0, 8, F), jnp.int32),
+        t_stage=jnp.asarray(r.randint(0, 8, F), jnp.int32),
+        hold=f32(np.where(r.rand(F) > 0.5, r.rand(F) * 1e-4, 0.0)),
+        cnp=f32(r.rand(F) < cnp_p), tgt_rx=f32(r.rand(F) * 12.5e9),
+        qdelay=f32(np.where(r.rand(F) > 0.3, r.rand(F) * 2e-5, 0.0)),
+        jitter=f32(r.rand(F) * 2 - 1), gen_rate=f32(r.rand(F) * 12.5e9),
+        line_rate=f32(PAPER_CONFIG.link.line_rate),
+        dt=f32(PAPER_CONFIG.sim.dt), tau=f32(0.0))
+
+
+def _dispatch_reaction(name, ctx, state):
+    """The reaction family dispatched as the step dispatches it: every
+    stage evaluated, one selected by its traced code."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.fluid import step_params
+    react = step_params(CCSpec(reaction=name)).react
+    fn = jax.jit(lambda code, p, c, s: cc.dispatch(cc.REACTION, code, p,
+                                                   c, s))
+    out, upd = fn(jnp.asarray(cc.REACTION.code(name), jnp.int32), react,
+                  ctx, state)
+    return out, upd, {k: float(v) for k, v in react.items()}
+
+
+def _same(a, b, what):
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b), what)
+
+
+@pytest.mark.parametrize("F", [1, 5, 127, 129, 130, 8192, 8193, 100_001])
+def test_rp_stage_matches_oracle(F):
+    """The ``rp`` stage (DCQCN's alpha EWMA and staged recovery) equals
+    ``rp_update_ref`` field for field."""
+    from repro.kernels import ref
+    ctx = _react_ctx(F, F, cnp_p=0.4)
+    out, _, p = _dispatch_reaction("rp", ctx, {"swift_cool": ctx.hold})
+    import jax
+    # the oracle compiled as the stage is: each side's multiply-adds
+    # then round alike on a CPU's vector units
+    want = jax.jit(ref.rp_update_ref, static_argnums=2)(
+        ref.RPState(ctx.rate, ctx.rp_target, ctx.alpha, ctx.byte_cnt,
+                    ctx.tmr, ctx.alpha_tmr,
+                    ctx.bc_stage.astype(np.float32),
+                    ctx.t_stage.astype(np.float32)),
+        ctx.cnp > 0,
+        ref.RPParams(g=p["rp_g"], rate_decrease=p["rp_rdf"],
+                     timer_T=p["rp_timer"], byte_B=p["rp_byte"],
+                     rai=p["rp_rai"], rhai=p["rp_rhai"],
+                     fr_stages=p["rp_fr_stages"], min_rate=p["rp_min_rate"],
+                     line_rate=float(ctx.line_rate), dt=float(ctx.dt)))
+    for got, w, name in zip(
+            (out.rate, out.rp_target, out.alpha, out.byte_cnt, out.tmr,
+             out.alpha_tmr, out.bc_stage.astype(np.float32),
+             out.t_stage.astype(np.float32)),
+            want, ref.RPState._fields):
+        _same(got, w, f"F={F} {name}")
+    assert np.asarray(ctx.cnp).any() or F < 5
+
+
+@pytest.mark.parametrize("F", [1, 127, 129, 8193, 50_000])
+def test_erp_stage_matches_oracle(F):
+    """The ``erp`` stage (settle to the signalled share, hold, jittered
+    additive recovery) equals ``erp_update_ref``."""
+    import jax.numpy as jnp
+    from repro.kernels import ref
+    ctx = _react_ctx(F, 7)
+    out, _, p = _dispatch_reaction("erp", ctx, {"swift_cool": ctx.hold})
+    slope = p["erp_rai"] * (1.0 + p["erp_jitter"] * ctx.jitter)
+    rate, hold = ref.erp_update_ref(
+        ctx.rate, ctx.hold, ctx.cnp > 0, ctx.tgt_rx, jnp.asarray(slope),
+        ref.ERPParams(settle=p["erp_settle"], hold=p["erp_hold"],
+                      min_rate=p["erp_min_rate"],
+                      line_rate=float(ctx.line_rate), dt=float(ctx.dt)))
+    _same(out.rate, rate, f"F={F} rate")
+    _same(out.hold, hold, f"F={F} hold")
+
+
+@pytest.mark.parametrize("F", [1, 127, 129, 8193, 50_000])
+def test_swift_stage_matches_oracle(F):
+    """The ``swift`` stage (delay-target decrease paced by its guard,
+    additive recovery) equals ``swift_update_ref``, its cool-down
+    timer included."""
+    from repro.kernels import ref
+    ctx = _react_ctx(F, 11)
+    cool = ctx.hold                     # zeros and positive timers mixed
+    out, upd, p = _dispatch_reaction("swift", ctx, {"swift_cool": cool})
+    rate, cool_w = ref.swift_update_ref(
+        ctx.rate, cool, ctx.qdelay, target=p["swift_target"],
+        beta=p["swift_beta"], ai=p["swift_ai"], guard=p["swift_guard"],
+        min_rate=p["swift_min_rate"], line_rate=float(ctx.line_rate),
+        dt=float(ctx.dt))
+    _same(out.rate, rate, f"F={F} rate")
+    _same(upd["swift_cool"], cool_w, f"F={F} swift_cool")
+
+
+def test_reaction_params_ride_a_traced_grid():
+    """The stage constants are traced data: a grid of ERP parameters
+    vmapped through one jitted dispatch traces once, and each row equals
+    the oracle under that row's constants."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import RevParams
+    from repro.core.fluid import step_params
+    from repro.kernels import ref
+    F = 300
+    ctx = _react_ctx(F, 3)
+    grid = [CCSpec(reaction="erp", rev=RevParams(erp_settle=s, erp_rai=a))
+            for s, a in ((0.98, 5e12), (0.5, 5e12), (0.75, 1e12))]
+    react = jax.tree.map(lambda *xs: jnp.stack(xs),
+                         *[step_params(g).react for g in grid])
+    traces = []
+
+    @jax.jit
+    def f(code, p):
+        traces.append(None)            # traces once per shape, not per value
+        return jax.vmap(lambda q: cc.dispatch(
+            cc.REACTION, code, q, ctx, {"swift_cool": ctx.hold})[0])(p)
+
+    code = jnp.asarray(cc.REACTION.code("erp"), jnp.int32)
+    out = f(code, react)
+    f(code, jax.tree.map(lambda x: x[::-1], react))
+    assert len(traces) == 1
+    rates = np.asarray(out.rate)
+    assert not np.array_equal(rates[0], rates[1])
+    for i in range(len(grid)):
+        p = {k: float(v[i]) for k, v in react.items()}
+        slope = p["erp_rai"] * (1.0 + p["erp_jitter"] * ctx.jitter)
+        want, _ = ref.erp_update_ref(
+            ctx.rate, ctx.hold, ctx.cnp > 0, ctx.tgt_rx, slope,
+            ref.ERPParams(settle=p["erp_settle"], hold=p["erp_hold"],
+                          min_rate=p["erp_min_rate"],
+                          line_rate=float(ctx.line_rate),
+                          dt=float(ctx.dt)))
+        _same(rates[i], want, f"grid row {i}")

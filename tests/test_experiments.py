@@ -5,8 +5,9 @@ import numpy as np
 import pytest
 
 from repro.core import (CCConfig, CCScheme, PAPER_CONFIG, ScenarioSpec,
-                        Sweep, config_grid, delay_depth, init_state,
-                        make_step_fn, pad_scenario, paper_incast, run)
+                        Sweep, config_grid, delay_depth, dense_reduce_rows,
+                        init_state, make_step_fn, pad_scenario,
+                        paper_incast, run)
 
 CFG = PAPER_CONFIG
 N_STEPS = 3000
@@ -78,14 +79,27 @@ def test_config_grid_paths():
 # padding invariance
 # ---------------------------------------------------------------------------
 
-def test_padding_is_inert():
+def _run_on(engine: str, scn, n_steps: int):
+    """One point through ``run`` (the jagged layout it derives), or as a
+    one-point ``Sweep`` on the segment sum (``dense_rows=0``, what the
+    what-if service pads its power-of-two flow buckets on) or on the
+    rectangle pinned to the scenario's longest list."""
+    if engine == "jagged":
+        return run(scn, CFG, n_steps=n_steps)
+    rows = 0 if engine == "segment_sum" else dense_reduce_rows(scn)
+    assert rows >= 0 and (rows > 0) == (engine == "pinned")
+    return Sweep([("p", CFG, scn)]).run(n_steps=n_steps, dense_rows=rows)[0]
+
+
+@pytest.mark.parametrize("engine", ["jagged", "segment_sum", "pinned"])
+def test_padding_is_inert(engine):
     """Extra PAD flows/hops/links change nothing for the real flows."""
     scn = paper_incast(CFG, roll=0)
     F, H = scn.routes.shape
     L = scn.capacity.shape[0]
     padded = pad_scenario(scn, F + 3, H + 2, L + 5)
-    r0 = run(scn, CFG, n_steps=2000)
-    r1 = run(padded, CFG, n_steps=2000)
+    r0 = _run_on(engine, scn, 2000)
+    r1 = _run_on(engine, padded, 2000)
     np.testing.assert_array_equal(r0.delivered, r1.delivered[:, :F])
     np.testing.assert_array_equal(r0.inst_thr, r1.inst_thr[:, :F])
     np.testing.assert_array_equal(r0.max_q, r1.max_q)

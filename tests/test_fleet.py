@@ -88,6 +88,21 @@ def test_plan_deterministic_and_content_addressed(sweep):
     assert seen == list(range(len(sweep.points)))
 
 
+def test_plan_digest_matches_journals_written_before():
+    """A small plan's digests are the ones the planner wrote before the
+    step lost its kernel options, so a journal from then still binds
+    and resumes (the digest keeps their two keys at the old defaults)."""
+    sweep = Sweep.grid(
+        {s.name: PAPER_CONFIG.replace(scheme=s) for s in CCScheme},
+        {"inc": ScenarioSpec.paper_incast(roll=0)})
+    plan = plan_sweep(sweep, n_steps=100, trace_every=10, n_shards=2)
+    assert plan.digest == ("f54b1ffd6011160d11e79ff6b550e04a"
+                           "9d340ccdcc150325e5c2936eee514ed7")
+    assert [s.digest for s in plan.shards] == [
+        "517f300a0f65674b520964fa2190db4c1a8576caf02c3bf8a438eb661af33cac",
+        "66fb1f82d8f15354297409de46d6382f96cd570ba08e73331c9cb647ea081802"]
+
+
 def test_plan_envelope_is_one_bucket(sweep):
     plan = plan_sweep(sweep, N_STEPS, TRACE_EVERY, n_shards=4)
     assert len(plan.buckets) == 1

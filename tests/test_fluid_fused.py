@@ -1,18 +1,17 @@
 """Bit-exact parity suite for the one-pass hot loop.
 
-The fused segment-reduction rewrite and the Pallas per-flow kernels
-must be *indistinguishable* from the legacy paths: the golden suite
-freezes summaries, so even one reordered f32 add would show.  This
-module pins the strongest form — exact array equality — across the
-same 18-point scheme x fabric x routing grid the golden suite runs:
+The fused reduction engines must be *indistinguishable* from the
+legacy scatter path: the golden suite freezes summaries, so even one
+reordered f32 add would show.  This module pins the strongest form —
+exact array equality — across the same 18-point scheme x fabric x
+routing grid the golden suite runs, and more batches besides, for each
+engine ``reduce="fused"`` can run against ``reduce="scat"``:
 
-  * ``reduce="fused"``  vs ``reduce="scat"``  (segment sum vs scatter)
-  * ``reduce="pallas"`` vs ``reduce="fused"`` (fluid_reduce kernel,
-    interpret mode)
-  * ``use_kernels=True`` vs jnp per-flow block (gen/np-timer + RP/ERP
-    kernels, interpret mode)
-  * ``use_kernels="mega"`` vs ``reduce="scat"`` (the whole-step
-    megakernel, one launch per trace window, interpret mode)
+  * the jagged dense layout (``dense_rows=None``, the default);
+  * the sorted segment sum (``dense_rows=0``, what the what-if
+    service pins);
+  * the pinned rectangle (``dense_rows`` = the batch's longest list,
+    what the fleet pins);
 
 plus unit-level checks of the incidence precompute and the
 content-keyed device-placement cache.
@@ -28,7 +27,6 @@ from repro.core.fluid import (_flow_jitter, init_state, make_step_fn,
                               scenario_device)
 from repro.core.routing import PAD, link_incidence
 from repro.core.workloads import group_shift
-from repro.kernels.fluid_reduce import segment_reduce
 from repro.net import FabricSpec
 
 TRACE_FIELDS = ("delivered", "rate", "inst_thr", "max_q", "n_paused",
@@ -85,114 +83,6 @@ def test_fused_matches_scat_on_golden_grid():
                     "fused-vs-scat")
 
 
-def test_kernel_flow_block_matches_jnp_on_golden_grid():
-    """Pallas gen/np-timer + RP/ERP kernels (interpret mode) vs the jnp
-    per-flow block: exact f32 equality."""
-    sweep = _grid()
-    _assert_bitwise(
-        sweep.run(n_steps=60),
-        sweep.run(n_steps=60, use_kernels=True, interpret=True),
-        "kernels-vs-jnp")
-
-
-def test_megakernel_matches_scat_on_golden_grid():
-    """The whole-step megakernel — every phase of the step plus the
-    in-kernel trace-window scan inside one pallas_call — vs the scatter
-    engine: exact equality of all decimated traces and final states
-    (delay-line ring and per-flow CC state included) across the 18-point
-    grid."""
-    sweep = _grid()
-    _assert_bitwise(
-        sweep.run(n_steps=60, reduce="scat"),
-        sweep.run(n_steps=60, use_kernels="mega", interpret=True),
-        "mega-vs-scat")
-
-
-def test_megakernel_matches_scat_at_two_vcs():
-    """The megakernel carries the per-VC queue axis (and its stall
-    trace) bit-exactly too."""
-    sweep = _grid_v2()
-    _assert_bitwise(
-        sweep.run(n_steps=60, reduce="scat"),
-        sweep.run(n_steps=60, use_kernels="mega", interpret=True),
-        "mega-vs-scat-v2")
-
-
-def test_simulator_run_megakernel_bitexact():
-    """``simulator.run(use_kernels="mega")`` — the single-point entry —
-    matches the per-step scat path sample for sample."""
-    from repro.core import simulator as sim
-    cfg = PAPER_CONFIG
-    scn = ScenarioSpec.paper_incast(
-        roll=0, t_start=0.1e-3, t_stop=1.2e-3).build(cfg)
-    ra = sim.run(scn, cfg, n_steps=60, trace_every=10, reduce="scat")
-    rb = sim.run(scn, cfg, n_steps=60, trace_every=10,
-                 use_kernels="mega", interpret=True)
-    for f in TRACE_FIELDS:
-        assert np.array_equal(np.asarray(getattr(ra, f)),
-                              np.asarray(getattr(rb, f))), f
-    _assert_final_equal(ra.final, rb.final, ("sim-mega",))
-
-
-def test_megakernel_rejects_nested_pallas_reduce():
-    """reduce="pallas" cannot run inside the megakernel (no nested
-    pallas_call); the combination is refused up front."""
-    cfg = PAPER_CONFIG
-    scn = ScenarioSpec.paper_incast(roll=0).build(cfg)
-    with pytest.raises(ValueError, match="mega"):
-        make_step_fn(scn, cfg, reduce="pallas", use_kernels="mega",
-                     interpret=True)
-
-
-def test_kernel_tier_rejects_unknown_string():
-    from repro.core.fluid import kernel_tier
-    assert kernel_tier(False) == "off"
-    assert kernel_tier(True) == "flow"
-    assert kernel_tier("mega") == "mega"
-    with pytest.raises(ValueError, match="use_kernels"):
-        kernel_tier("turbo")
-
-
-@pytest.mark.parametrize("tier", [True, "mega"])
-def test_soft_gates_refused_under_kernels_at_both_entry_points(tier):
-    """temperature > 0 + any kernel tier must raise at *both* entry
-    points (``make_step_fn`` and ``fluid_step``), not silently run the
-    hard dynamics (the kernels implement the hard model only)."""
-    from repro.core.fluid import fluid_step, step_params
-    cfg = PAPER_CONFIG
-    scn = ScenarioSpec.paper_incast(roll=0).build(cfg)
-    with pytest.raises(ValueError, match="temperature"):
-        make_step_fn(scn, cfg, use_kernels=tier, interpret=True,
-                     temperature=0.1)
-    st = init_state(scn, cfg)
-    sd = scenario_device(scn)
-    par = step_params(cfg, temperature=0.1)
-    with pytest.raises(ValueError, match="temperature"):
-        fluid_step(st, sd, par, dt=float(cfg.sim.dt),
-                   n_switches=int(scn.n_switches), use_kernels=tier,
-                   interpret=True)
-    # temperature=0 through the same entry points is fine
-    make_step_fn(scn, cfg, use_kernels=tier, interpret=True,
-                 temperature=0.0)
-
-
-def test_pallas_reduce_matches_fused_single_point():
-    """The fluid_reduce kernel inside a real stepping loop."""
-    cfg = PAPER_CONFIG.replace(routing="ugal")
-    scn = ScenarioSpec.permutation(
-        16, seed=2, fabric=FabricSpec.fat_tree(4, taper=2), n_paths=4,
-        route_seed=0, t_start=0.0, t_stop=0.5e-3).build(cfg)
-    outs = []
-    for kw in (dict(reduce="fused"),
-               dict(reduce="pallas", interpret=True)):
-        step = jax.jit(make_step_fn(scn, cfg, **kw))
-        st = init_state(scn, cfg)
-        for _ in range(100):
-            st, _ = step(st)
-        outs.append(st)
-    _assert_final_equal(outs[0], outs[1], ("pallas-vs-fused",))
-
-
 # ---------------------------------------------------------------------------
 # legacy-scheme shim parity: CCConfig == hand-written CCSpec, bit for bit
 # ---------------------------------------------------------------------------
@@ -230,31 +120,6 @@ def test_fused_matches_scat_at_two_vcs():
     _assert_bitwise(sweep.run(n_steps=150, reduce="fused"),
                     sweep.run(n_steps=150, reduce="scat"),
                     "fused-vs-scat-v2")
-
-
-def test_kernel_flow_block_matches_jnp_at_two_vcs():
-    sweep = _grid_v2()
-    _assert_bitwise(
-        sweep.run(n_steps=60),
-        sweep.run(n_steps=60, use_kernels=True, interpret=True),
-        "kernels-vs-jnp-v2")
-
-
-def test_pallas_reduce_matches_fused_at_two_vcs():
-    from repro.core.params import LinkParams
-    cfg = CCSpec(routing="ugal", link=LinkParams(n_vcs=2))
-    scn = ScenarioSpec.permutation(
-        16, seed=2, fabric=FabricSpec.fat_tree(4, taper=2), n_paths=4,
-        route_seed=0, t_start=0.0, t_stop=0.5e-3).build(cfg)
-    outs = []
-    for kw in (dict(reduce="fused"),
-               dict(reduce="pallas", interpret=True)):
-        step = jax.jit(make_step_fn(scn, cfg, **kw))
-        st = init_state(scn, cfg)
-        for _ in range(100):
-            st, _ = step(st)
-        outs.append(st)
-    _assert_final_equal(outs[0], outs[1], ("pallas-vs-fused-v2",))
 
 
 def test_single_vc_link_params_is_inert():
@@ -313,55 +178,6 @@ def test_legacy_override_shim_bitexact():
     explicit = Sweep([(k, sp, spec_scn) for k, (_, sp) in cases.items()])
     _assert_bitwise(legacy.run(n_steps=1500),
                     explicit.run(n_steps=1500), "override-shim")
-
-
-# ---------------------------------------------------------------------------
-# segment_reduce kernel unit tests
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("n,c,s", [(1, 1, 1), (100, 3, 17), (513, 2, 5),
-                                   (1536, 8, 300), (4096, 5, 1000)])
-def test_segment_reduce_exact(n, c, s):
-    rng = np.random.RandomState(n + c + s)
-    seg = np.sort(rng.randint(0, s, size=n)).astype(np.int32)
-    data = rng.randn(n, c).astype(np.float32)
-    got = segment_reduce(jax.numpy.asarray(data), jax.numpy.asarray(seg),
-                         s, interpret=True)
-    want = jax.ops.segment_sum(jax.numpy.asarray(data),
-                               jax.numpy.asarray(seg), num_segments=s,
-                               indices_are_sorted=True)
-    assert np.array_equal(np.asarray(got), np.asarray(want))
-
-
-def test_segment_reduce_empty_input():
-    """Zero rows must yield exact zeros (the grid never runs, so the
-    wrapper must not hand back uninitialised output)."""
-    out = segment_reduce(jax.numpy.zeros((0, 3), jax.numpy.float32),
-                         jax.numpy.zeros((0,), jax.numpy.int32), 7,
-                         interpret=True)
-    assert np.array_equal(np.asarray(out), np.zeros((7, 3), np.float32))
-
-
-def test_segment_reduce_rejects_oversized_accumulator():
-    """Shapes whose [S, C] accumulator cannot sit in VMEM are refused
-    with a pointer at the segment-sum engine, not silently compiled."""
-    with pytest.raises(ValueError, match="VMEM"):
-        segment_reduce(jax.numpy.zeros((512, 128), jax.numpy.float32),
-                       jax.numpy.zeros((512,), jax.numpy.int32),
-                       1 << 16, interpret=True)
-
-
-def test_segment_reduce_empty_segments():
-    """Links no flow crosses must come back exactly 0."""
-    seg = np.asarray([3, 3, 7], np.int32)
-    data = np.ones((3, 2), np.float32)
-    out = np.asarray(segment_reduce(jax.numpy.asarray(data),
-                                    jax.numpy.asarray(seg), 10,
-                                    interpret=True))
-    want = np.zeros((10, 2), np.float32)
-    want[3] = 2.0
-    want[7] = 1.0
-    assert np.array_equal(out, want)
 
 
 # ---------------------------------------------------------------------------
@@ -461,9 +277,34 @@ def _multipath_grid() -> Sweep:
                       scenarios={"ft_perm": _grid_scenarios()["ft_perm"]})
 
 
-@pytest.mark.parametrize("batch", [_grid, _grid_v2, _multipath_grid,
-                                   _skew_batch],
-                         ids=["golden", "two_vcs", "multipath", "mixed_skew"])
+def _multipath_grid_v2() -> Sweep:
+    """The dragonfly cell's shape: K = 4 candidate paths a flow and two
+    VCs a wire, under Valiant and UGAL routing."""
+    from repro.core.params import LinkParams
+    link = LinkParams(n_vcs=2)
+    cfgs = {r: CCSpec(routing=r, link=link) for r in ("valiant", "ugal")}
+    return Sweep.grid(configs=cfgs,
+                      scenarios={"dfly_adv": _grid_scenarios()["dfly_adv"]})
+
+
+#: the batches every engine is held to the scatter engine on
+BATCHES = {"golden": _grid, "two_vcs": _grid_v2,
+           "multipath": _multipath_grid, "mixed_skew": _skew_batch,
+           "multipath_two_vcs": _multipath_grid_v2}
+
+
+def _longest_list(sweep: Sweep) -> int:
+    """The batch's longest per-queue contributor list: the row count
+    that pins the dense rectangle to this batch."""
+    from repro.core.experiments import batch_dense_rows, stack_scenarios
+    _, padded, _ = stack_scenarios([p.scenario for p in sweep.points],
+                                   n_vcs=sweep.n_vcs)
+    rows = batch_dense_rows(padded, sweep.n_vcs)
+    assert rows > 0
+    return rows
+
+
+@pytest.mark.parametrize("batch", list(BATCHES.values()), ids=list(BATCHES))
 def test_jagged_layout_matches_scat(batch):
     """The derived layout is jagged (several blocks, fewer slots than
     the rectangle of every queue x the longest list) and the sweep it
@@ -478,6 +319,45 @@ def test_jagged_layout_matches_scat(batch):
     _assert_bitwise(sweep.run(n_steps=60), sweep.run(n_steps=60,
                                                      reduce="scat"),
                     "jagged-vs-scat")
+
+
+@pytest.mark.parametrize("batch", list(BATCHES.values()), ids=list(BATCHES))
+@pytest.mark.parametrize("engine", ["segment_sum", "pinned"])
+def test_engine_matches_scat(engine, batch):
+    """The sorted segment sum (``dense_rows=0``, the what-if service's
+    engine) and the rectangle pinned to the batch's longest list (the
+    fleet's) run every batch bitwise as the scatter engine does:
+    every trace field and the final state."""
+    sweep = batch()
+    rows = 0 if engine == "segment_sum" else _longest_list(sweep)
+    static, (_, sd, _), _, _ = sweep._prepare(60, 10, dense_rows=rows)
+    S = (sd.cap_ext.shape[1] - 1) * sweep.n_vcs
+    assert static[5] == (((S, rows),) if rows else ())
+    _assert_bitwise(sweep.run(n_steps=60, dense_rows=rows),
+                    sweep.run(n_steps=60, reduce="scat"),
+                    f"{engine}-vs-scat")
+
+
+def test_skewed_batch_falls_back_to_segment_sum():
+    """A batch whose longest list passes ``DENSE_ROWS_CAP`` gets no
+    dense blocks: it runs the segment sum, bitwise the scatter
+    engine."""
+    from repro.core.fluid import DENSE_ROWS_CAP
+    ft = FabricSpec.fat_tree(4, taper=2)
+    n = DENSE_ROWS_CAP + 76
+    hot = ScenarioSpec.flows([(1 + i % 15, 0) for i in range(n)],
+                             fabric=ft, t_start=0.0, t_stop=0.5e-3)
+    sweep = Sweep.grid(
+        configs={s.name: PAPER_CONFIG.replace(scheme=s) for s in CCScheme},
+        scenarios={"hot": hot})
+    scn = sweep.points[0].scenario
+    _, _, off = link_incidence(scn.routes[:, None, :], scn.capacity.shape[0])
+    assert np.diff(off)[:-1].max() > DENSE_ROWS_CAP
+    static, (_, sd, _), _, _ = sweep._prepare(60, 10)
+    assert static[5] == () and sd.red_idx is None
+    _assert_bitwise(sweep.run(n_steps=60), sweep.run(n_steps=60,
+                                                     reduce="scat"),
+                    "skew-fallback-vs-scat")
 
 
 def test_mixed_skew_pads_each_position_to_the_widest_run():

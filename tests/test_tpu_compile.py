@@ -2,9 +2,9 @@
 
 The TPU compiler is installed even where no chip is attached: it
 compiles for a *described* ``v5e:2x2`` topology.  These tests
-ahead-of-time compile the main path and each Pallas tier at the
+ahead-of-time compile the step on each link-reduction engine at the
 largest width of the ``--perf`` curve (``dfly272_f4096``: 4096 flows on
-a 272-host dragonfly) for one chip of it, so a kernel the chip's
+a 272-host dragonfly) for one chip of it, so a program the chip's
 compiler refuses fails here, before any chip time is spent.  Nothing
 runs; the compiled program is only inspected.
 
@@ -17,9 +17,9 @@ import jax
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import PAPER_CONFIG, ScenarioSpec
-from repro.core.fluid import init_state, make_step_fn
-from repro.core.simulator import decimating_scan, make_block_fn
+from repro.core import CCSpec, LinkParams, PAPER_CONFIG, ScenarioSpec
+from repro.core.fluid import dense_reduce_rows, init_state, make_step_fn
+from repro.core.simulator import decimating_scan
 from repro.net import FabricSpec
 
 N_SAMPLES = 4
@@ -47,12 +47,16 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-@pytest.fixture(scope="module")
-def dfly4096():
+#: the K = 4, V = 2 multipath step (the dragonfly cell's shape)
+MULTIPATH = CCSpec(routing="ugal", link=LinkParams(n_vcs=2))
+
+
+def _dfly4096(cfg, **kw):
     spec = ScenarioSpec.permutation(4096, seed=0,
-                                    fabric=FabricSpec.dragonfly(4, 4, 4))
-    scn = spec.build(PAPER_CONFIG)
-    return scn, init_state(scn, PAPER_CONFIG)
+                                    fabric=FabricSpec.dragonfly(4, 4, 4),
+                                    **kw)
+    scn = spec.build(cfg)
+    return scn, init_state(scn, cfg)
 
 
 def _compile(scan_fn, st0, sharding) -> str:
@@ -64,29 +68,22 @@ def _compile(scan_fn, st0, sharding) -> str:
     return jax.jit(scan_fn).lower(shapes).compile().as_text()
 
 
-@pytest.mark.parametrize("tier,kw,n_kernels", [
-    ("default", {}, 0),
-    ("pallas_reduce", {"reduce": "pallas"}, 3),
-    ("flow", {"use_kernels": True}, 2),
-])
-def test_step_tier_compiles(one_chip, dfly4096, tier, kw, n_kernels):
-    """The decimating scan of each step tier compiles for the chip, with
-    one ``tpu_custom_call`` per Pallas kernel of the tier (none on the
-    jnp default)."""
-    scn, st0 = dfly4096
-    step = make_step_fn(scn, PAPER_CONFIG, **kw)
+@pytest.mark.parametrize("engine", ["default", "segment_sum", "pinned",
+                                    "multipath_k4_v2"])
+def test_step_tier_compiles(one_chip, engine):
+    """The decimating scan of the step compiles for the chip on each
+    link-reduction engine (the jagged layout by default, the segment
+    sum, the rectangle pinned to the longest list) and on the K = 4,
+    V = 2 multipath step, as one XLA program: no custom kernel call."""
+    cfg = MULTIPATH if engine == "multipath_k4_v2" else PAPER_CONFIG
+    kw = dict(n_paths=4, route_seed=0) if engine == "multipath_k4_v2" \
+        else {}
+    scn, st0 = _dfly4096(cfg, **kw)
+    rows = {"segment_sum": 0,
+            "pinned": dense_reduce_rows(scn)}.get(engine)
+    assert rows != 0 or engine == "segment_sum"
+    step = make_step_fn(scn, cfg, dense_rows=rows)
     text = _compile(lambda st: decimating_scan(
-        step, st, N_SAMPLES, TRACE_EVERY, PAPER_CONFIG.sim.dt), st0, one_chip)
-    assert text.count("tpu_custom_call") == n_kernels, tier
-
-
-def test_megakernel_refused(one_chip, dfly4096):
-    """The whole-step megakernel gathers inside its body, which the
-    chip's kernel compiler refuses today.  A change that makes it
-    compile must turn this into a compile check like the others."""
-    scn, st0 = dfly4096
-    block = make_block_fn(scn, PAPER_CONFIG, TRACE_EVERY)
-    with pytest.raises(NotImplementedError, match="gather"):
-        _compile(lambda st: decimating_scan(
-            None, st, N_SAMPLES, TRACE_EVERY, PAPER_CONFIG.sim.dt,
-            block_fn=block), st0, one_chip)
+        step, st, N_SAMPLES, TRACE_EVERY, cfg.sim.dt,
+        int(getattr(cfg.link, "n_vcs", 1))), st0, one_chip)
+    assert "tpu_custom_call" not in text, engine

@@ -105,9 +105,22 @@ def test_annealing_converges_on_golden_grid():
     assert errs[0.08] <= errs[0.2] * 1.10 + 1e-12
 
 
-def test_sweep_rejects_soft_kernels():
-    with pytest.raises(ValueError, match="hard dynamics only"):
-        _small_sweep().run(n_steps=64, temperature=0.1, use_kernels=True)
+@pytest.mark.parametrize("engine", ["segment_sum", "pinned", "scat"])
+def test_soft_dynamics_agree_on_every_engine(engine):
+    """The soft gates ride every link-reduction engine alike: a
+    ``temperature > 0`` sweep on the segment sum, the pinned rectangle
+    or the scatter engine is bitwise the default jagged layout's."""
+    from repro.core import dense_reduce_rows
+    sweep = _small_sweep()
+    kw = {"segment_sum": dict(dense_rows=0),
+          "pinned": dict(dense_rows=max(dense_reduce_rows(p.scenario)
+                                        for p in sweep.points)),
+          "scat": dict(reduce="scat")}[engine]
+    a = sweep.run(n_steps=64, temperature=0.1)
+    b = sweep.run(n_steps=64, temperature=0.1, **kw)
+    for x, y in zip(jax.tree.leaves((a.traces, a.final)),
+                    jax.tree.leaves((b.traces, b.final))):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 # ---------------------------------------------------------------------------
